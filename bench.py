@@ -2682,9 +2682,11 @@ def main() -> None:
     parser.add_argument(
         "--multichip", nargs="?", const="MULTICHIP_churn.json",
         default=None, metavar="PATH",
-        help="run the sharded-wave-loop churn ledger (ISSUE 18): the "
-        "churn preset at 1/2/4/8 forced CPU devices (one subprocess "
-        "each), gating per-wave oracle parity (incl. the rr tie "
+        help="a CPU correctness record, never a chip run: the parent "
+        "forks children forced onto 1/2/4/8 virtual CPU devices, so "
+        "its pods/s are not device numbers.  Runs the sharded-wave-loop "
+        "churn ledger (ISSUE 18): the churn preset at each device count "
+        "(one subprocess each), gating per-wave oracle parity (incl. the rr tie "
         "counter), the O(compactions+1) host-sync budget, and per-shard "
         "upload attribution at every shard count; writes the ledger "
         "JSON to PATH (default MULTICHIP_churn.json) — verdicts are "

@@ -155,8 +155,11 @@ def _write_control_plane_manifests(cluster_dir: str, port: int,
     os.makedirs(manifests, exist_ok=True)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     inherited = os.environ.get("PYTHONPATH", "")
-    env = {"PYTHONPATH": (root + os.pathsep + inherited) if inherited else root,
-           "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu")}
+    env = {"PYTHONPATH": (root + os.pathsep + inherited) if inherited else root}
+    if "JAX_PLATFORMS" in os.environ:
+        # inherited when set, never defaulted: writing "cpu" here pinned a
+        # --backend tpu scheduler to the CPU without a word
+        env["JAX_PLATFORMS"] = os.environ["JAX_PLATFORMS"]
 
     def manifest(name: str, argv: list[str]) -> None:
         doc = {

@@ -1,29 +1,8 @@
 """TPU kernels: feasibility masks, scoring, batched assignment."""
 
-import logging
-import os
-import sys
+from ..utils.platform import configure_compile_cache
 
-# XLA's CPU thunk runtime pays a per-op dispatch cost that dominates the
-# scan step at scheduler shapes (~150 small [N] ops per pod): the legacy
-# runtime runs the same step in ~half the time (353 vs 656 us/pod at
-# N=5120).  Opt the CPU client into it unless the operator already chose
-# — harmless for TPU execution (CPU-only flag), and it must be set
-# before the first JAX computation initializes the CPU client.
-if "xla_cpu_use_thunk_runtime" not in os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "") + " --xla_cpu_use_thunk_runtime=false"
-    ).strip()
-    if "jax" in sys.modules:
-        # the flag is read when the CPU client initializes; an embedding
-        # app that already ran a JAX computation keeps the default
-        # runtime — the scan then runs ~2x slower per step, so say so
-        # instead of silently missing the bench floor
-        logging.getLogger("kubernetes_tpu.ops").info(
-            "jax was imported before kubernetes_tpu.ops: the legacy CPU "
-            "runtime flag may not apply if the CPU client is already "
-            "initialized (scan steps ~2x slower; set XLA_FLAGS="
-            "--xla_cpu_use_thunk_runtime=false yourself to be sure)")
+configure_compile_cache()
 
 from .backend import TPUBatchBackend
 from .batch_kernel import ScanState, StaticArrays, schedule_batch_arrays

@@ -57,12 +57,13 @@ logger = logging.getLogger("kubernetes_tpu.backend")
 
 
 def _device_platform() -> str:
+    """The platform JAX runs on.  A device that cannot be read is an
+    error, not an "unknown" platform: swallowing it would let a chip
+    that failed to initialize degrade every segment to the XLA rung
+    without a word."""
     import jax
 
-    try:
-        return jax.devices()[0].platform
-    except Exception:
-        return "unknown"
+    return jax.devices()[0].platform
 
 # The oracle priorities the kernel scoring path reproduces bit-for-bit —
 # a configured priority outside this table forces the all-oracle path

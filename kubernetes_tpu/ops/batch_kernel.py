@@ -916,19 +916,17 @@ def _sharded_loop_runner(num_zones: int, weights: tuple, use_terms: bool,
     Donation carries through shard_map unchanged (state and chosen
     buffer are reused in place across loop runs), which is what lets
     DC601's use-after-donate tracking extend through the sharded
-    dispatch chain.  ``check_rep=False``: the replicated scalar outputs
+    dispatch chain.  ``check_vma=False``: the replicated scalar outputs
     (cursor, stop flag, alive count) are provably identical on every
     shard — they are pure functions of psum/pmax results — but shard_map
     cannot prove it through ``lax.while_loop``."""
-    from jax.experimental.shard_map import shard_map
-
     from ..parallel.mesh import NODE_AXIS, loop_in_specs, loop_out_specs
 
     w = dict(zip(WEIGHT_KEYS, weights))
     run = _make_loop_run(num_zones, w, use_terms, use_vols, use_ports,
                          chunk_len, axis_name=NODE_AXIS)
-    sharded = shard_map(run, mesh=mesh, in_specs=loop_in_specs(),
-                        out_specs=loop_out_specs(), check_rep=False)
+    sharded = jax.shard_map(run, mesh=mesh, in_specs=loop_in_specs(),
+                            out_specs=loop_out_specs(), check_vma=False)
     return jax.jit(sharded, donate_argnums=(2, 3))
 
 

@@ -120,9 +120,17 @@ def main(argv=None) -> int:
             algo = load_policy_file(args.policy_config_file)
         backend = None
         if args.backend == "tpu":
+            import jax
+
             from ..ops import TPUBatchBackend
 
             backend = TPUBatchBackend(algorithm=algo)
+            # the first JAX touch of this process: a standby never gets
+            # here, so only the leader holds the accelerator
+            devices = jax.devices()
+            logging.info("backend tpu: platform=%s device_kind=%s devices=%d",
+                         devices[0].platform, devices[0].device_kind,
+                         len(devices))
         sched = Scheduler(cs, algorithm=algo, backend=backend,
                           scheduler_name=args.scheduler_name)
         metrics_holder["registry"] = sched.metrics.registry
@@ -163,7 +171,18 @@ def main(argv=None) -> int:
 
             timeseries.disable()
             telemetry.disable()  # final drain before exit
+        if backend is not None and not stop.is_set():
+            # stopped by a lost lease, not a signal: an accelerator belongs
+            # to one process at a time, so a deposed leader that re-entered
+            # the acquire loop would keep the chip its successor needs —
+            # exit instead, as the reference does (server.go:133
+            # OnStoppedLeading)
+            logging.error("lost the lease while holding the accelerator; "
+                          "exiting so the next leader can take it")
+            lost_with_device.set()
+            stop.set()
 
+    lost_with_device = threading.Event()
     stop = install_signal_stop()
     try:
         run_with_leader_election(
@@ -173,7 +192,7 @@ def main(argv=None) -> int:
     finally:
         if health is not None:
             health.stop()
-    return 0
+    return 1 if lost_with_device.is_set() else 0
 
 
 if __name__ == "__main__":

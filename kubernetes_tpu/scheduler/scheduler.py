@@ -44,7 +44,7 @@ logger = logging.getLogger("kubernetes_tpu.scheduler")
 
 DEFAULT_SCHEDULER_NAME = "default-scheduler"
 
-# memoized accelerator platform ("tpu" / "gpu" / "cpu" / "unknown"):
+# memoized accelerator platform ("tpu" / "gpu" / "cpu"):
 # _pipeline_idle's full-window polling gate reads it once per process
 _ACCEL_PLATFORM: Optional[str] = None
 
@@ -52,12 +52,10 @@ _ACCEL_PLATFORM: Optional[str] = None
 def _accel_platform() -> str:
     global _ACCEL_PLATFORM
     if _ACCEL_PLATFORM is None:
-        try:
-            import jax
+        import jax
 
-            _ACCEL_PLATFORM = jax.devices()[0].platform
-        except Exception:
-            _ACCEL_PLATFORM = "unknown"
+        # a device that cannot be read raises (see ops.backend._device_platform)
+        _ACCEL_PLATFORM = jax.devices()[0].platform
     return _ACCEL_PLATFORM
 
 
@@ -66,14 +64,12 @@ def _poll_full_device_window() -> bool:
     window?  A real accelerator (TPU/GPU) executes off the host CPU, so
     polling always hides in its shadow — poll unconditionally (ROADMAP
     open item: the old ``cpu_count > 1`` gate wrongly throttled 1-CPU
-    TPU hosts).  On the XLA *CPU* "device" (or when the platform is
-    unknown) the computation shares the host cores, and on a 1-core box
-    every poll cycle stretches the scan 1:1 (measured 2x) — keep the
-    spare-core requirement there."""
+    TPU hosts).  On the XLA *CPU* "device" the computation shares the
+    host cores, and on a 1-core box every poll cycle stretches the scan
+    1:1 (measured 2x) — keep the spare-core requirement there."""
     import os
 
-    platform = _accel_platform()
-    if platform not in ("cpu", "unknown"):
+    if _accel_platform() != "cpu":
         return True
     return (os.cpu_count() or 1) > 1
 

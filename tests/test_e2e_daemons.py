@@ -348,3 +348,58 @@ def test_conftest_timeout_watchdog_enforces(monkeypatch):
     with pytest.raises(TimeoutError, match="deadline"):
         # the watchdog fires mid-sleep; 10s would otherwise blow the mark
         _time.sleep(10)
+
+
+@pytest.mark.timeout(120)
+def test_deposed_tpu_scheduler_exits_instead_of_keeping_the_device(tmp_path):
+    """An accelerator belongs to one process at a time: a --backend tpu
+    scheduler that loses its lease must exit (reference server.go:133
+    OnStoppedLeading), not re-enter the acquire loop holding the chip
+    its successor needs.  Until it leads it must not touch JAX at all."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    from kubernetes_tpu.client.leaderelection import LEASE_ANNOTATION
+
+    server = APIServer(Store())
+    server.start()
+    proc = None
+    log_path = tmp_path / "scheduler.log"
+    try:
+        cs = Clientset(RemoteStore(server.url))
+        env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+        with open(log_path, "w") as log:
+            proc = subprocess.Popen(
+                [sys.executable, "-m", "kubernetes_tpu.scheduler",
+                 "--apiserver", server.url, "--backend", "tpu",
+                 "--leader-elect"],
+                env=env, stdout=log, stderr=subprocess.STDOUT)
+        deadline = time.time() + 60
+        while time.time() < deadline:
+            if "backend tpu: platform=cpu" in log_path.read_text():
+                break
+            assert proc.poll() is None, log_path.read_text()
+            time.sleep(0.2)
+        else:
+            raise AssertionError("scheduler never led: " + log_path.read_text())
+
+        def steal(ev):
+            ev.meta.annotations[LEASE_ANNOTATION] = json.dumps({
+                "holderIdentity": "successor",
+                "renewTime": time.time() + 3_600,
+                "leaseDurationSeconds": 15.0})
+            return ev
+
+        cs.events.guaranteed_update("kube-scheduler", steal, "kube-system")
+        assert proc.wait(timeout=40) == 1
+        log = log_path.read_text()
+        assert "lost the lease while holding the accelerator" in log
+        # the device line comes after "became leader": a standby stays off JAX
+        assert log.index("became leader") < log.index("backend tpu: platform")
+    finally:
+        if proc is not None and proc.poll() is None:
+            proc.kill()
+            proc.wait()
+        server.stop()

@@ -170,7 +170,41 @@ def test_supports_pallas_budget_guard():
     tz = Tensorizer(pad_multiple=128)
     static = tz.build_static(pods, m, pctx)
     assert pk.supports_pallas(static)
-    assert pk.pallas_vmem_bytes(static) < pk.VMEM_BUDGET_BYTES
+    assert pk.pallas_vmem_bytes(static) < pk.VMEM_LIMIT_BYTES
+
+
+def test_vmem_estimate_counts_padded_tiles():
+    """The guard's estimate is what the compiled program holds, not the
+    element count: minor dimensions pad to 128 lanes, major ones to the
+    dtype's sublane tile.  Checked against the north shape (N=5,120,
+    G=32, T=4, P=65,536), whose declared scratch an ahead-of-time compile
+    for a v5e (libtpu 0.0.34) puts at 2,889,728 B scoped, ~0.9 MiB of it
+    Mosaic's own."""
+    import types
+
+    assert pk._tiled_bytes(1, 5120) == 8 * 5120 * 4  # a [1, N] row is 8 sublanes
+    assert pk._tiled_bytes(4, 1) == 8 * 128 * 4  # a [T, 1] column is one tile
+    assert pk._tiled_bytes(32, 5120, itemsize=1) == 32 * 5120  # int8: 32 sublanes
+    assert pk._tiled_bytes(33, 5120, itemsize=1) == 64 * 5120
+    # pod_vol [P, W]: W pads to 128 lanes whatever it is
+    assert pk._tiled_bytes(65536, 1) == pk._tiled_bytes(65536, 9) == 32 * 2**20
+
+    def static(g, p, n=5120):
+        return types.SimpleNamespace(
+            n_pad=n, static_ok=np.zeros((g, 1)),
+            term_matches_sig=np.zeros((4, 1)), g_ports=np.zeros((g, 8)),
+            v_state=32, node_alloc=np.zeros((1, 4)),
+            pod_vol_ids=np.zeros((1, 1)), group_of_pod=np.zeros(p),
+            num_zones=3)
+
+    north = static(32, 65536)
+    assert pk.pallas_vmem_bytes(north) == 40_112_128
+    assert pk.supports_pallas(north)
+    # the [G, N] planes are what exhausts the limit — the number handed
+    # to the compiler as vmem_limit_bytes: max_groups=512 fits at 5,120
+    # nodes (compiles ahead of time, 78 MB scoped) and not at 10,240
+    assert pk.supports_pallas(static(512, 65536))
+    assert not pk.supports_pallas(static(512, 65536, n=10240))
 
 
 def test_pallas_dispatch_failure_falls_back_to_xla(monkeypatch):

@@ -493,7 +493,22 @@ def test_full_window_poll_gate_is_platform_checked(monkeypatch):
     assert sched_mod._poll_full_device_window() is False
     monkeypatch.setattr(os, "cpu_count", lambda: 4)
     assert sched_mod._poll_full_device_window() is True
-    # unknown platform (jax unavailable/failed): conservative core gate
-    monkeypatch.setattr(sched_mod, "_ACCEL_PLATFORM", "unknown")
-    monkeypatch.setattr(os, "cpu_count", lambda: 1)
-    assert sched_mod._poll_full_device_window() is False
+
+
+def test_unreadable_device_is_an_error_not_an_unknown_platform(monkeypatch):
+    """A chip that fails to initialize must not quietly become the XLA
+    rung: both platform probes let ``jax.devices()`` raise."""
+    import jax
+
+    import kubernetes_tpu.ops.backend as backend_mod
+    import kubernetes_tpu.scheduler.scheduler as sched_mod
+
+    def no_devices():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(jax, "devices", no_devices)
+    monkeypatch.setattr(sched_mod, "_ACCEL_PLATFORM", None)
+    with pytest.raises(RuntimeError, match="Unable to initialize"):
+        backend_mod._device_platform()
+    with pytest.raises(RuntimeError, match="Unable to initialize"):
+        sched_mod._accel_platform()

@@ -70,6 +70,31 @@ def test_pki_phase(tmp_path):
     assert os.path.isabs(doc["client-certificate"])
 
 
+def test_manifests_inherit_jax_platforms_and_never_default_it(tmp_path, monkeypatch):
+    """A --backend tpu scheduler started from these manifests must land on
+    whatever platform JAX finds: the variable is inherited when set and
+    absent when not (a written default of "cpu" pinned it to the CPU
+    without a word)."""
+    import yaml
+
+    from kubernetes_tpu.cluster import _write_control_plane_manifests
+
+    paths = {k: f"/pki/{k}" for k in (
+        "apiserver", "apiserver_key", "ca", "kubeconfig_kube-scheduler",
+        "kubeconfig_kube-controller-manager")}
+
+    def scheduler_env(cluster_dir):
+        manifests = _write_control_plane_manifests(
+            str(cluster_dir), 6443, paths, "tpu")
+        with open(os.path.join(manifests, "kube-scheduler.yaml")) as f:
+            return yaml.safe_load(f)["spec"]["containers"][0]["env"]
+
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    assert "JAX_PLATFORMS" not in scheduler_env(tmp_path / "unset")
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert scheduler_env(tmp_path / "set")["JAX_PLATFORMS"] == "cpu"
+
+
 @pytest.mark.timeout(240)
 def test_selfhosted_control_plane_e2e(tmp_path):
     """THE capstone: init --self-hosted → mirror pods Running over TLS →
