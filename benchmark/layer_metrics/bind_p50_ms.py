@@ -1,0 +1,7 @@
+"""served path, client's side: median of create due -> binding seen
+by the client's watch, over every pod of the window."""
+from benchmark.layer_metrics._common import bind_percentile
+
+
+def read(facts):
+    return bind_percentile(facts, 50)
