@@ -57,6 +57,7 @@ HOT_PATH_MODULES = [
     "kubernetes_tpu/scheduler/scheduler.py",
     "kubernetes_tpu/ops/backend.py",
     "kubernetes_tpu/ops/batch_kernel.py",
+    "kubernetes_tpu/ops/pallas_kernel.py",
     "kubernetes_tpu/utils/overload.py",
     "kubernetes_tpu/parallel/mesh.py",
 ]

@@ -34,6 +34,7 @@ import logging
 import math
 import sys
 import threading
+import time
 from collections import deque
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
@@ -264,8 +265,26 @@ def _make_handler(server: APIServer):
             for k, v in getattr(self, "_extra_headers", ()) or ():
                 self.send_header(k, v)
             self._extra_headers = ()
+            # this server's own account of the request, for the client's
+            # remote.request span: from the moment its headers were in (the
+            # body is read inside) to this write, and within that the store
+            # call, where the verb made one
+            timing = (f"handle;dur="
+                      f"{(time.perf_counter() - self._t_request) * 1e3:.3f}")
+            if self._store_s is not None:
+                timing += f", store;dur={self._store_s * 1e3:.3f}"
+            self.send_header("Server-Timing", timing)
             self.end_headers()
             self.wfile.write(data)
+
+        def _store(self, call, *args, **kwargs):
+            """One store call of this request, timed for ``Server-Timing``."""
+            t0 = time.perf_counter()
+            try:
+                return call(*args, **kwargs)
+            finally:
+                self._store_s = ((self._store_s or 0.0)
+                                 + time.perf_counter() - t0)
 
         def _error(self, code: int, reason: str, message: str,
                    retry_after: Optional[float] = None) -> None:
@@ -507,9 +526,8 @@ def _make_handler(server: APIServer):
 
         # -- dispatch ------------------------------------------------------
         def _route(self, method: str) -> None:
-            import time
-
-            start = time.perf_counter()
+            start = self._t_request = time.perf_counter()
+            self._store_s = None
             server.request_count.inc()
             self._last_code = 0
             acquired = False
@@ -706,7 +724,8 @@ def _make_handler(server: APIServer):
                 return apply_patch(cur, patch_doc, patch_type)
 
             try:
-                out = server.store.guaranteed_update(kind, ns, name, _mutate)
+                out = self._store(server.store.guaranteed_update,
+                                  kind, ns, name, _mutate)
             except NotFoundError:
                 raise
             except (KeyError, IndexError, ValueError, TypeError) as e:
@@ -1183,9 +1202,9 @@ def _make_handler(server: APIServer):
                 return self._send(200, {"version": __version__})
             if url.path == "/api/v1/bindings:batch" and method == "POST":
                 items = self._body().get("bindings", [])
-                errors = server.store.bind_many(
-                    [(b.get("podNamespace", "default"), b["podName"], b["nodeName"]) for b in items]
-                )
+                errors = self._store(server.store.bind_many, [
+                    (b.get("podNamespace", "default"), b["podName"], b["nodeName"])
+                    for b in items])
                 return self._send(200, {"errors": errors})
             # batch create: POST /api/v1/{resource}:batch {"items": [...]}
             # — one store txn (Store.create_many: one lock/WAL/fanout
@@ -1206,7 +1225,7 @@ def _make_handler(server: APIServer):
                 if kind in CLUSTER_SCOPED:
                     for d in items:
                         d.setdefault("metadata", {})["namespace"] = ""
-                created = server.store.create_many(kind, items)
+                created = self._store(server.store.create_many, kind, items)
                 return self._send(201, {"items": created})
 
             if url.path == SSAR_PATH and method == "POST":
@@ -1245,7 +1264,7 @@ def _make_handler(server: APIServer):
                         batch = lc(kind, ns) if lc is not None else None
                         if batch is not None:
                             return self._send(200, batch.to_wire())
-                    items, rev = server.store.list(kind, ns)
+                    items, rev = self._store(server.store.list, kind, ns)
                     items = self._apply_list_selectors(items, q)
                     if items is None:
                         return  # error already written
@@ -1258,7 +1277,8 @@ def _make_handler(server: APIServer):
                     body = convert_to_internal(self._body())
                     if kind in CLUSTER_SCOPED:
                         body.setdefault("metadata", {})["namespace"] = ""
-                    return self._send(201, server.store.create(kind, body))
+                    return self._send(
+                        201, self._store(server.store.create, kind, body))
                 return self._error(405, "MethodNotAllowed", method)
 
             # namespaced collection: /api/v1/namespaces/{ns}/{resource}
@@ -1272,7 +1292,7 @@ def _make_handler(server: APIServer):
                 if method == "GET":
                     if q.get("watch", ["false"])[0] == "true":
                         return self._serve_watch(kind, q)
-                    items, rev = server.store.list(kind, ns)
+                    items, rev = self._store(server.store.list, kind, ns)
                     items = self._apply_list_selectors(items, q)
                     if items is None:
                         return  # error already written
@@ -1285,7 +1305,8 @@ def _make_handler(server: APIServer):
                     body = convert_to_internal(self._body())
                     meta = body.setdefault("metadata", {})
                     meta["namespace"] = "" if kind in CLUSTER_SCOPED else ns
-                    return self._send(201, server.store.create(kind, body))
+                    return self._send(
+                        201, self._store(server.store.create, kind, body))
                 return self._error(405, "MethodNotAllowed", method)
 
             # object routes: /api/v1/namespaces/{ns}/{resource}/{name}[/binding]
@@ -1298,7 +1319,8 @@ def _make_handler(server: APIServer):
                 if len(parts) == 5:
                     if parts[4] == "binding" and kind == "Pod" and method == "POST":
                         body = self._body()
-                        errors = server.store.bind_many([(ns, name, body["nodeName"])])
+                        errors = self._store(server.store.bind_many,
+                                             [(ns, name, body["nodeName"])])
                         if errors[0] is not None:
                             return self._error(409, "Conflict", errors[0])
                         return self._send(201, {"status": "bound"})
@@ -1321,19 +1343,22 @@ def _make_handler(server: APIServer):
                         return self._send(201, {"status": "evicted"})
                     return self._error(404, "NotFound", f"unknown subresource {parts[4]}")
                 if method == "GET":
-                    return self._send(200, server.store.get(kind, ns, name))
+                    return self._send(
+                        200, self._store(server.store.get, kind, ns, name))
                 if method == "PUT":
                     from ..api.scheme import convert_to_internal
 
                     obj = convert_to_internal(self._body())
                     cas = q.get("cas", ["true"])[0] == "true"
                     expect = None if cas else 0
-                    out = server.store.update(kind, obj, expect_rev=expect or None)
+                    out = self._store(server.store.update, kind, obj,
+                                      expect_rev=expect or None)
                     return self._send(200, out)
                 if method == "PATCH":
                     return self._serve_patch(kind, ns, name)
                 if method == "DELETE":
-                    return self._send(200, server.store.delete(kind, ns, name))
+                    return self._send(
+                        200, self._store(server.store.delete, kind, ns, name))
                 return self._error(405, "MethodNotAllowed", method)
 
             return self._error(404, "NotFound", f"no route for {url.path}")

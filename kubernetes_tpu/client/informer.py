@@ -361,18 +361,22 @@ class SharedInformer:
             # no payload to apply; rebuild from a fresh LIST
             self._try_relist()
             return
-        tr = tracing.current()
-        with (tr.span("informer.event.apply", cat="ingest", kind=self.kind,
-                      key=ev.key, type=ev.type)
-              if tr is not None and tr.verbose else tracing.NULL_SPAN):
-            t_apply = time.perf_counter()
-            try:
-                self._apply_event(ev)
-            finally:
-                # the scheduler deltas this per wave (pump APPLICATION time)
-                dt = time.perf_counter() - t_apply
-                with self._mu:
-                    self.stats["apply_s"] += dt
+        t_apply = time.perf_counter()
+        try:
+            self._apply_event(ev)
+        except Exception as e:
+            # the per-event path opens no span (it would be one per pod);
+            # a failed apply leaves a point event, whichever thread drives
+            tr = tracing.current()
+            if tr is not None:
+                tr.instant("informer.event.error", kind=self.kind, key=ev.key,
+                           type=ev.type, error=f"{type(e).__name__}: {e}")
+            raise
+        finally:
+            # the scheduler deltas this per wave (pump APPLICATION time)
+            dt = time.perf_counter() - t_apply
+            with self._mu:
+                self.stats["apply_s"] += dt
 
     def _apply_event(self, ev: WatchEvent) -> None:
         if ev.revision <= self.last_revision:

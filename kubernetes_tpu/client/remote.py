@@ -109,6 +109,24 @@ def _parse_retry_after(headers) -> Optional[float]:
         return None
 
 
+def _server_timing(value: Optional[str]) -> dict:
+    """``Server-Timing: handle;dur=<ms>, store;dur=<ms>`` (the apiserver's
+    own account of one request) as the ``remote.request`` span's
+    ``server_s`` / ``store_s``.  A part the server did not send — an
+    older server, a verb that makes no store call — stays absent, never
+    0."""
+    out = {}
+    for part in (value or "").split(","):
+        name, _, dur = part.strip().partition(";dur=")
+        attr = {"handle": "server_s", "store": "store_s"}.get(name)
+        if attr is not None:
+            try:
+                out[attr] = float(dur) / 1e3
+            except ValueError:
+                pass
+    return out
+
+
 class RemoteWatch:
     """Chunked-stream consumer with auto-reconnect from the last revision.
 
@@ -499,52 +517,81 @@ class RemoteStore:
             f"{type(last_err).__name__}: {last_err}")
 
     def _call(self, method: str, path: str, body=None,
-              content_type: Optional[str] = None) -> dict:
-        if content_type is not None:
-            # explicit content type (PATCH negotiation) always sends JSON
-            # bodies; binary Accept still applies to the response
-            data = json.dumps(body).encode() if body is not None else None
-            headers = {"Content-Type": content_type}
-            if self.binary:
+              content_type: Optional[str] = None,
+              items: Optional[int] = None) -> dict:
+        """One resource request.  With tracing on it is one
+        ``remote.request`` span: what went out and came back, how long
+        this side spent encoding and decoding, and — from the server's
+        ``Server-Timing`` header — how long the apiserver (``server_s``)
+        and the store inside it (``store_s``) took, so a child process's
+        time reaches this trace.  ``items``: how many objects a batch
+        verb carries."""
+        tr = tracing.current()
+        with (tr.span("remote.request", cat="client", method=method,
+                      path=path)
+              if tr is not None else tracing.NULL_SPAN) as sp:
+            if items is not None:
+                sp.set(items=items)
+            t_encode = tr.clock() if tr is not None else 0.0
+            if content_type is not None:
+                # explicit content type (PATCH negotiation) always sends
+                # JSON bodies; binary Accept still applies to the response
+                data = json.dumps(body).encode() if body is not None else None
+                headers = {"Content-Type": content_type}
+                if self.binary:
+                    from ..api import wire as binwire
+
+                    headers["Accept"] = binwire.CONTENT_TYPE
+            elif self.binary:
                 from ..api import wire as binwire
 
-                headers["Accept"] = binwire.CONTENT_TYPE
-        elif self.binary:
-            from ..api import wire as binwire
+                data = binwire.encode(body) if body is not None else None
+                headers = {"Content-Type": binwire.CONTENT_TYPE,
+                           "Accept": binwire.CONTENT_TYPE}
+            else:
+                data = json.dumps(body).encode() if body is not None else None
+                headers = {"Content-Type": "application/json"}
+            if tr is not None:
+                sp.set(encode_s=tr.clock() - t_encode,
+                       bytes_out=len(data) if data is not None else 0)
+            attempts = 0
 
-            data = binwire.encode(body) if body is not None else None
-            headers = {"Content-Type": binwire.CONTENT_TYPE,
-                       "Accept": binwire.CONTENT_TYPE}
-        else:
-            data = json.dumps(body).encode() if body is not None else None
-            headers = {"Content-Type": "application/json"}
+            def send():
+                nonlocal attempts
+                attempts += 1
+                sp.set(attempts=attempts)
+                req = urllib.request.Request(
+                    f"{self.base_url}{path}", data=data, method=method,
+                    headers=dict(headers),
+                )
+                if self.token:
+                    req.add_header("Authorization", f"Bearer {self.token}")
+                return urllib.request.urlopen(req, timeout=self.timeout,
+                                              context=self._ssl_ctx)
 
-        def send():
-            req = urllib.request.Request(
-                f"{self.base_url}{path}", data=data, method=method,
-                headers=dict(headers),
-            )
-            if self.token:
-                req.add_header("Authorization", f"Bearer {self.token}")
-            return urllib.request.urlopen(req, timeout=self.timeout,
-                                          context=self._ssl_ctx)
-
-        try:
-            with self._request_with_retries(send, method, path) as resp:
-                out = self._decode(resp)
-        except urllib.error.HTTPError as e:
-            out = self._decode(e)
-        _raise_for_status(out)
-        return out
+            try:
+                with self._request_with_retries(send, method, path) as resp:
+                    out = self._decode(resp, tr, sp)
+            except urllib.error.HTTPError as e:
+                out = self._decode(e, tr, sp)
+            _raise_for_status(out)
+            return out
 
     @staticmethod
-    def _decode(resp) -> dict:
+    def _decode(resp, tr=None, sp=None) -> dict:
         from ..api import wire as binwire
 
         raw = resp.read()
+        t_decode = tr.clock() if tr is not None else 0.0
         if binwire.CONTENT_TYPE in (resp.headers.get("Content-Type") or ""):
-            return binwire.decode(raw)
-        return json.loads(raw.decode())
+            out = binwire.decode(raw)
+        else:
+            out = json.loads(raw.decode())
+        if tr is not None:
+            sp.set(status=resp.status, bytes_in=len(raw),
+                   decode_s=tr.clock() - t_decode,
+                   **_server_timing(resp.headers.get("Server-Timing")))
+        return out
 
     def raw(self, method: str, path: str, body=None,
             timeout: Optional[float] = None) -> bytes:
@@ -603,7 +650,7 @@ class RemoteStore:
         try:
             out = self._call(
                 "POST", f"/api/v1/{self._resource(kind)}:batch",
-                {"items": objs})
+                {"items": objs}, items=len(objs))
             return out.get("items", [])
         except NotFoundError:
             results = []
@@ -716,6 +763,7 @@ class RemoteStore:
                     for ns, name, node in items
                 ]
             },
+            items=len(items),
         )
         return out["errors"]
 

@@ -681,21 +681,27 @@ class TPUBatchBackend:
         # disks out of the occupancy vocab.  Only the kernel path needs
         # it — the oracle-only fallback must not pay the corpus build.
         host_state = None
+        tr = tracing.current()
         if weights is not None:
-            if not self.reuse_host_state:
-                # benchmark seam: the pre-incremental behavior (fresh
-                # O(cluster) build per batch) for honest A/B runs
-                if self._host_state is not None:
-                    self._host_state.close()
-                self._host_state = None
-            if self._host_state is None:
-                self._host_state = HostBatchState(work_map)
-                self.stats["host_state_rebuilds"] += 1
-            else:
-                self._host_state.reconcile(work_map)
-                self.stats["host_state_reconciles"] += 1
-                self.stats["host_state_dirty_nodes"] += len(
-                    self._host_state.last_dirty)
+            with (tr.span("host_state", cat="phase", nodes=len(work_map))
+                  if tr is not None else tracing.NULL_SPAN) as sp:
+                if not self.reuse_host_state:
+                    # benchmark seam: the pre-incremental behavior (fresh
+                    # O(cluster) build per batch) for honest A/B runs
+                    if self._host_state is not None:
+                        self._host_state.close()
+                    self._host_state = None
+                if self._host_state is None:
+                    self._host_state = HostBatchState(work_map)
+                    self.stats["host_state_rebuilds"] += 1
+                    sp.set(mode="rebuild", dirty_nodes=len(work_map))
+                else:
+                    self._host_state.reconcile(work_map)
+                    self.stats["host_state_reconciles"] += 1
+                    self.stats["host_state_dirty_nodes"] += len(
+                        self._host_state.last_dirty)
+                    sp.set(mode="reconcile",
+                           dirty_nodes=len(self._host_state.last_dirty))
             host_state = self._host_state
         mounted_disks = host_state.mounted_disks if host_state is not None else set()
 
@@ -746,21 +752,23 @@ class TPUBatchBackend:
             seg_pods = [p for _, p in segment]
             tr = tracing.current()
             t_tensorize = self._clock_wall()
-            static = self.tensorizer.build_static(
-                seg_pods,
-                work_map,
-                work_pctx,
-                least_requested_weight=weights["least"],
-                most_requested_weight=weights["most"],
-                balanced_weight=weights["balanced"],
-                spread_weight=weights["spread"],
-                node_affinity_weight=weights["node_affinity"],
-                taint_weight=weights["taint"],
-                prefer_avoid_weight=weights["prefer_avoid"],
-                image_weight=weights["image"],
-                interpod_weight=weights["interpod"],
-                mounted_disks=mounted_disks,
-            )
+            with (tr.span("tensorize.build_static", cat="phase")
+                  if tr is not None else tracing.NULL_SPAN):
+                static = self.tensorizer.build_static(
+                    seg_pods,
+                    work_map,
+                    work_pctx,
+                    least_requested_weight=weights["least"],
+                    most_requested_weight=weights["most"],
+                    balanced_weight=weights["balanced"],
+                    spread_weight=weights["spread"],
+                    node_affinity_weight=weights["node_affinity"],
+                    taint_weight=weights["taint"],
+                    prefer_avoid_weight=weights["prefer_avoid"],
+                    image_weight=weights["image"],
+                    interpod_weight=weights["interpod"],
+                    mounted_disks=mounted_disks,
+                )
             if static is None:
                 t_end = self._clock_wall()
                 self.stats["tensorize_s"] += t_end - t_tensorize
@@ -768,15 +776,19 @@ class TPUBatchBackend:
                     tr.complete("tensorize", t_tensorize, t_end, cat="phase",
                                 pods=len(seg_pods), rejected=True)
                 return None
-            init = self.tensorizer.initial_state(
-                static, work_map, work_pctx, seg_pods,
-                round_robin=self.algorithm._round_robin, host_state=host_state,
-            )
+            with (tr.span("tensorize.initial_state", cat="phase")
+                  if tr is not None else tracing.NULL_SPAN):
+                init = self.tensorizer.initial_state(
+                    static, work_map, work_pctx, seg_pods,
+                    round_robin=self.algorithm._round_robin,
+                    host_state=host_state,
+                )
             t_end = self._clock_wall()
             self.stats["tensorize_s"] += t_end - t_tensorize
             if tr is not None:
                 # same clock reads as the stats timer: the trace-derived
-                # tensorize_s IS this measurement
+                # tensorize_s IS this measurement (it adopts the two
+                # children recorded between them)
                 tr.complete("tensorize", t_tensorize, t_end, cat="phase",
                             pods=len(seg_pods), groups=len(static.g_request),
                             n_pad=int(static.n_pad))
@@ -826,10 +838,18 @@ class TPUBatchBackend:
             self.stats["dispatch_s"] += t_end - t_dispatch
             if tr is not None:
                 # the breaker's chosen ladder rung rides on the span —
-                # "this wave quietly ran on the slow path" is trace-visible
-                tr.complete("dispatch", t_dispatch, t_end, cat="phase",
-                            rung=LEVELS[level], shape=str(key),
-                            frontier=bool(self.frontier and level == 1))
+                # "this wave quietly ran on the slow path" is trace-visible.
+                # The Pallas rung's dispatch.pack / dispatch.launch are
+                # adopted as children; what launch learned (a shape that
+                # had to be traced and compiled, the bytes it uploaded)
+                # is stated on the phase itself
+                sp = tr.complete("dispatch", t_dispatch, t_end, cat="phase",
+                                 rung=LEVELS[level], shape=str(key),
+                                 frontier=bool(self.frontier and level == 1))
+                for child in sp.children:
+                    if child.name == "dispatch.launch":
+                        sp.set(**{k: v for k, v in child.attrs.items()
+                                  if k in ("new_shape", "upload_bytes")})
 
             device_probe = None
             if fut is not None:
@@ -977,6 +997,7 @@ class TPUBatchBackend:
                                 cat="phase", rung=LEVELS[level],
                                 pods=len(segment))
                 self.algorithm._round_robin = final_rr
+                cloned_before = len(_cloned)
                 req_vecs, nz_vecs = _segment_vecs(static)
                 group_of_pod = static.group_of_pod
                 entries = []
@@ -989,6 +1010,12 @@ class TPUBatchBackend:
                     entries.append((pod, node_name, req_vecs[g], nz_vecs[g]))
                 self.stats["kernel_pods"] += len(segment)
                 self.stats["segments"] += 1
+                if tr is not None:
+                    # the results onto the working snapshot: it begins
+                    # where device_wait ended (one clock read, no gap)
+                    tr.complete("place", t_wait_end, self._clock_wall(),
+                                cat="phase", pods=len(segment),
+                                cloned_nodes=len(_cloned) - cloned_before)
                 return entries
 
             finish.device_probe = device_probe
@@ -1015,7 +1042,10 @@ class TPUBatchBackend:
             pending = []
 
         try:
-            segments = self._segments(pods, mounted_disks=mounted_disks)
+            with (tr.span("segment_plan", cat="phase", pods=len(pods))
+                  if tr is not None else tracing.NULL_SPAN) as sp:
+                segments = self._segments(pods, mounted_disks=mounted_disks)
+                sp.set(segments=len(segments))
             for si, (kind, segment) in enumerate(segments):
                 if kind == "oracle":
                     for i, pod in segment:

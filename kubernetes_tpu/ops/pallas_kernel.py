@@ -42,6 +42,7 @@ import numpy as np
 from ..models.snapshot import BatchStatic, InitialState
 from ..scheduler.predicates import VOLUME_COUNT_LIMITS
 from ..scheduler.units import FIXED_POINT_ONE, MAX_PRIORITY
+from ..utils import tracing
 from .batch_kernel import WEIGHT_KEYS
 
 INT32_MIN = -(2**31)
@@ -705,25 +706,41 @@ def shape_key(static: BatchStatic) -> tuple:
 def dispatch_batch_pallas(static: BatchStatic, init: InitialState):
     """Async half of ``schedule_batch_pallas``: dispatch and return the
     unmaterialized device arrays (see dispatch_batch_arrays)."""
-    scalars, ins, p_pad = _pack(static, init)
+    tr = tracing.current()
+    with (tr.span("dispatch.pack", cat="phase")
+          if tr is not None else tracing.NULL_SPAN):
+        scalars, ins, p_pad = _pack(static, init)
     weights = tuple(int(static.weights.get(kk, 0)) for kk in WEIGHT_KEYS)
-    # device: static — grid/shape keys are BatchStatic fields, frozen per segment build
-    run = _pallas_runner(
-        static.n_pad,
-        static.static_ok.shape[0],
-        static.term_matches_sig.shape[0],
-        static.g_ports.shape[1],
-        static.v_state,
-        static.node_alloc.shape[1],
-        static.pod_vol_ids.shape[1],
-        p_pad,
-        int(static.num_zones),
-        weights,
-        bool(static.terms),
-        bool(static.use_vols),
-        _superstep_k(),
-    )
-    out = run(*scalars, *ins)
+    with (tr.span("dispatch.launch", cat="phase")
+          if tr is not None else tracing.NULL_SPAN) as sp:
+        # device: static — grid/shape keys are BatchStatic fields, frozen per segment build
+        run = _pallas_runner(
+            static.n_pad,
+            static.static_ok.shape[0],
+            static.term_matches_sig.shape[0],
+            static.g_ports.shape[1],
+            static.v_state,
+            static.node_alloc.shape[1],
+            static.pod_vol_ids.shape[1],
+            p_pad,
+            int(static.num_zones),
+            weights,
+            bool(static.terms),
+            bool(static.use_vols),
+            _superstep_k(),
+        )
+        if tr is not None:
+            sp.set(upload_bytes=sum(a.nbytes for a in (*scalars, *ins)))
+            compiled = getattr(run, "_cache_size", None)
+            if compiled is not None:
+                # a runner that has compiled nothing yet: this launch
+                # traces, lowers and compiles (or loads from the
+                # persistent cache)
+                sp.set(new_shape=compiled() == 0)
+        # XLA-profiler attribution, the twin of ``ktpu.wave_scan``: the
+        # upload and the enqueue of this dispatch on the profiler's clock
+        with jax.profiler.TraceAnnotation("ktpu.pallas_scan"):
+            out = run(*scalars, *ins)
     # enqueue the D2H transfer behind the kernel NOW: by finalize time the
     # chosen indices are already host-side (the copy rides the device's
     # shadow with the commit work instead of serializing after it)

@@ -837,12 +837,15 @@ class Scheduler:
         assume+bind each result in pod order.  Returns (bound, failed)."""
         if self.backend is None:
             raise RuntimeError("no batch backend configured")
+        tr = tracing.current()
+        # the wave begins at its drain (an empty drain records nothing)
+        t_drain = tr.clock() if tr is not None else 0.0
         pods = self.queue.drain(max_batch)
         if not pods:
             return (0, 0)
+        t_drained = tr.clock() if tr is not None else 0.0
         self._apply_overload_knobs()
         self.metrics.batch_size.observe(len(pods))
-        tr = tracing.current()
         # Cyclic GC is paused for the whole batch (tensorize + kernel +
         # commit): at 150k pods a collection pass walks millions of live
         # objects and costs more than everything it frees (the Go
@@ -893,18 +896,24 @@ class Scheduler:
                         ),
                     )
                 )
-            self.cache.assume_many(to_assume)
+            with (tr.span("commit.assume", cat="phase", pods=len(to_assume))
+                  if tr is not None else tracing.NULL_SPAN):
+                self.cache.assume_many(to_assume)
             bind_start = self._clock()
-            try:
-                errors = self.clientset.pods.bind_many([b for _, b in to_bind])
-            except Exception as e:
-                # the whole segment's commit failed before any CAS applied
-                # (store overload / transport outage / injected fault):
-                # nothing bound — every entry takes the per-item failure
-                # path below, which forgets the assumption and requeues
-                logger.warning("bind_many failed for %d pods: %s: %s",
-                               len(to_bind), type(e).__name__, e)
-                errors = [f"transient: {e}"] * len(to_bind)
+            with (tr.span("commit.bind", cat="phase", pods=len(to_bind))
+                  if tr is not None else tracing.NULL_SPAN):
+                try:
+                    errors = self.clientset.pods.bind_many(
+                        [b for _, b in to_bind])
+                except Exception as e:
+                    # the whole segment's commit failed before any CAS
+                    # applied (store overload / transport outage / injected
+                    # fault): nothing bound — every entry takes the per-item
+                    # failure path below, which forgets the assumption and
+                    # requeues
+                    logger.warning("bind_many failed for %d pods: %s: %s",
+                                   len(to_bind), type(e).__name__, e)
+                    errors = [f"transient: {e}"] * len(to_bind)
             self.metrics.binding_latency.observe((self._clock() - bind_start) * 1e6)
             finished: list[str] = []
             emit = self.emit_events
@@ -930,7 +939,9 @@ class Scheduler:
                     # conflict (bound elsewhere) is NOT retried
                     self._requeue_after_bind_failure(pod)
                     totals["failed"] += 1
-            self.cache.finish_binding_many(finished)
+            with (tr.span("commit.finish", cat="phase", pods=len(finished))
+                  if tr is not None else tracing.NULL_SPAN):
+                self.cache.finish_binding_many(finished)
             totals["committed"] += len(finished)
             totals["attempted_binds"] += len(to_bind)
             # per-segment e2e SLI: pods committed in segment s of S were
@@ -944,7 +955,9 @@ class Scheduler:
             totals["commit_s"] += t_commit_end - t_commit
             if tr is not None:
                 # same two clock reads feed the stats timer and the span:
-                # the trace-derived commit_s below IS this measurement
+                # the trace-derived commit_s below IS this measurement.
+                # It adopts commit.assume / .bind / .finish; the two loops
+                # over the entries stay as its self time
                 tr.complete("commit", t_commit, t_commit_end, cat="phase",
                             pods=len(entries), bound=len(finished))
 
@@ -987,21 +1000,30 @@ class Scheduler:
                 pass
 
         # one span tree per wave (ISSUE 7): everything this thread does
-        # for the batch — tensorize, segment dispatch/finalize, frontier
-        # chunks, commits, overlapped prep, ingest pumps — nests under
-        # this root; closed (and pushed into the flight-recorder ring)
-        # in the finally below.  Entered immediately before the try so
-        # no exception path can leak an open root on the span stack
-        # (a leaked root would adopt every later wave as a child).
+        # for the batch — the drain, snapshot, tensorize, segment
+        # dispatch/finalize, frontier chunks, commits, overlapped prep,
+        # ingest pumps — nests under this root; closed (and pushed into
+        # the flight-recorder ring) in the finally below.  Entered
+        # immediately before the try so no exception path can leak an
+        # open root on the span stack (a leaked root would adopt every
+        # later wave as a child).
         wave_cm = wave_span = None
         if tr is not None:
-            wave_cm = tr.wave(pods=len(pods), **self._wave_attrs_pending)
+            wave_cm = tr.wave(t0=t_drain, pods=len(pods),
+                              **self._wave_attrs_pending)
             self._wave_attrs_pending = {}
             wave_span = wave_cm.__enter__()
+            tr.complete("queue.drain", t_drain, t_drained, cat="phase",
+                        pods=len(pods))
         try:
             start = self._clock()
-            snapshot = self.snapshot()
-            pctx = self.priority_context(snapshot)
+            with (tr.span("snapshot", cat="phase")
+                  if tr is not None else tracing.NULL_SPAN) as sp:
+                snapshot = self.snapshot()
+                sp.set(nodes=len(snapshot))
+            with (tr.span("priority_context", cat="phase")
+                  if tr is not None else tracing.NULL_SPAN):
+                pctx = self.priority_context(snapshot)
             algo_start = self._clock()
             self.backend.schedule_batch(pods, snapshot, pctx,
                                         on_segment=commit_segment, **extra)

@@ -705,27 +705,32 @@ class Store:
         # compare, exactly the plain-update CAS semantics); the fence
         # (frame.revision = last entry) is exact as ever
         frames_by_kind: dict[str, object] = {}
-        try:
-            faults.hit("store.coalesce", n=len(events), folded=p.folded)
-            if frames_mod.ENABLED:
-                for kind, evs in by_kind.items():
-                    if len(evs) > 1:
-                        frames_by_kind[kind] = frames_mod.WatchFrame(
-                            kind,
-                            [e.type for e in evs],
-                            [e.key for e in evs],
-                            [e.revision for e in evs],
-                            [e.object for e in evs],
-                            prev_revisions=None,
-                            txn=p.txn,
-                        )
-        except Exception:  # noqa: BLE001 - degrade, never drop state
-            # flush-path failure (injected or real): this window falls
-            # back to per-event delivery of the SAME folded events — the
-            # state every consumer converges to is identical, only the
-            # packing is lost
-            frames_by_kind = {}
-            m.coalesce_fallbacks.inc()
+        tr = tracing.current()
+        with (tr.span("store.txn", cat="store", op="coalesce_flush",
+                      txn=p.txn, events=len(events), folded=p.folded)
+              if tr is not None else tracing.NULL_SPAN) as sp:
+            try:
+                faults.hit("store.coalesce", n=len(events), folded=p.folded)
+                if frames_mod.ENABLED:
+                    for kind, evs in by_kind.items():
+                        if len(evs) > 1:
+                            frames_by_kind[kind] = frames_mod.WatchFrame(
+                                kind,
+                                [e.type for e in evs],
+                                [e.key for e in evs],
+                                [e.revision for e in evs],
+                                [e.object for e in evs],
+                                prev_revisions=None,
+                                txn=p.txn,
+                            )
+            except Exception:  # noqa: BLE001 - degrade, never drop state
+                # flush-path failure (injected or real): this window falls
+                # back to per-event delivery of the SAME folded events — the
+                # state every consumer converges to is identical, only the
+                # packing is lost
+                frames_by_kind = {}
+                m.coalesce_fallbacks.inc()
+                sp.set(fallback=True)
         for wkind, q, wants_frames in self._watchers:
             for kind, evs in by_kind.items():
                 if wkind is not None and wkind != kind:

@@ -33,12 +33,19 @@ Disabled (the default, and the only production state until enabled) the
 instrumented sites cost one module-global load and a ``None`` check —
 the same discipline as ``faults.hit``.  Enabled, every tracer operation
 takes one lock; the enabled path is a debugging/benchmarking mode and is
-priced by the ``--ab-trace`` bench leg, not assumed free.
+priced by ``python3 -m benchmark.run --trace 1`` against ``--trace 0``
+on the chip (PERF.md), not assumed free.
+
+Spans are per wave, per segment or per request — never per pod.  A wave's
+tree covers the whole loop iteration: ``queue.drain``, ``snapshot``,
+``priority_context``, the backend's ``host_state`` / ``segment_plan`` /
+``tensorize`` / ``dispatch`` / ``device_wait`` / ``place`` and the
+scheduler's ``commit`` with its parts (PERF.md section 3 lists every
+span and attr beside the metric that reads it).
 
 ``utils/trace.py``'s :class:`Trace` (the reference's ``utiltrace.Trace``)
-is folded onto this layer: its slow-operation logging and the tracer's
-slow-wave logging share :func:`format_slow`, so there is one code path
-for "this took too long, show me the steps".
+is folded onto this layer: its step bookkeeping lives in a :class:`Span`
+and its slow-operation logging renders through :func:`format_slow`.
 """
 
 from __future__ import annotations
@@ -63,9 +70,7 @@ def current() -> Optional["Tracer"]:
 
 
 def enable(clock: Optional[Callable[[], float]] = None, ring_waves: int = 16,
-           max_dumps: int = 32, dump_dir: Optional[str] = None,
-           slow_wave_s: Optional[float] = None,
-           verbose: bool = False) -> "Tracer":
+           max_dumps: int = 32, dump_dir: Optional[str] = None) -> "Tracer":
     """Install a fresh process-wide tracer and return it.  ``clock`` is
     injectable for deterministic tests (defaults to ``time.perf_counter``
     — the same clock the backend's phase timers use, so trace-derived
@@ -73,8 +78,7 @@ def enable(clock: Optional[Callable[[], float]] = None, ring_waves: int = 16,
     writes each flight-recorder dump as a JSON file."""
     global _ACTIVE
     tracer = Tracer(clock=clock, ring_waves=ring_waves, max_dumps=max_dumps,
-                    dump_dir=dump_dir, slow_wave_s=slow_wave_s,
-                    verbose=verbose)
+                    dump_dir=dump_dir)
     _ACTIVE = tracer
     return tracer
 
@@ -177,9 +181,8 @@ def _jsonable(v):
 
 def format_slow(name: str, t0: float, steps: list[tuple[float, str]],
                 t_end: float) -> str:
-    """The shared slow-trace rendering: total + per-step deltas.  Both
-    ``utils.trace.Trace.log_if_long`` and the tracer's slow-wave logging
-    go through here — one code path for slow-operation logging."""
+    """The slow-trace rendering: total + per-step deltas
+    (``utils.trace.Trace.log_if_long``)."""
     lines = [f'Trace "{name}" (total {(t_end - t0) * 1e3:.1f}ms):']
     prev = t0
     for t, msg in steps:
@@ -272,9 +275,7 @@ class Tracer:
 
     def __init__(self, clock: Optional[Callable[[], float]] = None,
                  ring_waves: int = 16, max_dumps: int = 32,
-                 dump_dir: Optional[str] = None,
-                 slow_wave_s: Optional[float] = None,
-                 verbose: bool = False):
+                 dump_dir: Optional[str] = None):
         self.clock = clock or time.perf_counter
         self._mu = threading.RLock()
         self._tls = threading.local()
@@ -283,10 +284,6 @@ class Tracer:
         self.instants: deque[dict] = deque(maxlen=512)
         self.dumps: deque[dict] = deque(maxlen=max_dumps)
         self.dump_dir = dump_dir
-        self.slow_wave_s = slow_wave_s
-        # verbose=True additionally opens a span per WATCH EVENT on the
-        # per-event informer path (frames always get one span per frame)
-        self.verbose = verbose
         self._t0 = self.clock()
         self._wave_seq = itertools.count(1)
         self._dump_seq = itertools.count(1)
@@ -318,10 +315,15 @@ class Tracer:
         return _SpanCM(self, Span(name, cat=cat, t0=self.clock(),
                                   tid=self._tid(), attrs=attrs, mu=self._mu))
 
-    def wave(self, **attrs) -> _SpanCM:
+    def wave(self, t0: Optional[float] = None, **attrs) -> _SpanCM:
+        """A wave root.  ``t0``: a reading of :attr:`clock` the caller
+        took earlier (the scheduler's wave begins at its queue drain,
+        which it only records once it knows the drain was not empty)."""
         wid = next(self._wave_seq)
         cm = self.span(f"wave-{wid}", cat="wave", **attrs)
         cm._span.attrs["wave"] = wid
+        if t0 is not None:
+            cm._span.t0 = t0
         return cm
 
     def _push(self, span: Span) -> None:
@@ -350,12 +352,6 @@ class Tracer:
             if root is not None:
                 (self.ring if span.cat == "wave"
                  else self.background).append(span)
-        if (span.cat == "wave" and self.slow_wave_s is not None
-                and span.duration >= self.slow_wave_s):
-            import logging
-
-            logging.getLogger("kubernetes_tpu.tracing").info(
-                format_slow(span.name, span.t0, span.steps, span.t1))
 
     def complete(self, name: str, t0: float, t1: float, cat: str = "",
                  **attrs) -> Span:
@@ -363,14 +359,30 @@ class Tracer:
         backend's phase timers measure once and feed BOTH their stats
         counters and the trace from the same two clock reads — that
         identity is what lets ``last_batch_phases`` derive from the
-        trace without a second measurement that could disagree)."""
+        trace without a second measurement that could disagree).
+
+        The span never sat on the thread's stack, so what the thread
+        recorded between its two timestamps was filed beside it, under
+        the enclosing open span.  It adopts those spans here — they are
+        the tail of the enclosing span's children, the thread runs one
+        thing at a time — so ``commit`` holds ``commit.bind`` and that
+        the ``remote.request`` in the finished tree.  (A flight dump
+        taken before this call shows them one level up.)  Outside any
+        open span nothing is adopted: the background ring is shared by
+        every thread."""
         span = Span(name, cat=cat, t0=t0, tid=self._tid(), attrs=attrs,
                     mu=self._mu)
         span.t1 = t1
         stack = self._stack()
         with self._mu:
             if stack:
-                stack[-1].children.append(span)
+                siblings = stack[-1].children
+                cut = len(siblings)
+                while cut and siblings[cut - 1].t0 >= t0:
+                    cut -= 1
+                span.children = siblings[cut:]
+                del siblings[cut:]
+                siblings.append(span)
             else:
                 self.background.append(span)
         return span

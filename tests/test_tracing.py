@@ -303,6 +303,163 @@ def test_chrome_export_validates_and_phases_derive_from_trace():
     assert any(n.startswith("wave-") for n in names)
 
 
+WAVE_CHILDREN = ["queue.drain", "snapshot", "priority_context", "host_state",
+                 "segment_plan", "tensorize", "dispatch", "device_wait",
+                 "place", "commit"]
+
+
+@pytest.mark.timeout(120)
+def test_the_wave_covers_the_loop_iteration_in_named_children():
+    """The wave root starts at the drain and its children name every
+    stretch of the iteration, in order and without overlap; the commit's
+    and the tensorize's parts are children IN THE TREE (adopted by the
+    after-the-fact ``complete``), and the new phase names leave the
+    pump's ``apply_s`` alone."""
+    from benchmark import trace_reduce
+    from benchmark.layer_metrics import _gaps
+
+    tr = tracing.enable()
+    cs, sched, _ = _mini_world()
+
+    def wave(tag):
+        cs.pods.create_many([make_pod(f"{tag}{i}", cpu="100m")
+                             for i in range(12)])
+        sched.pump()
+        assert sched.schedule_pending_batch() == (12, 0)
+        return tr.ring[-1]
+
+    first = wave("a")
+    # an empty drain records no root
+    assert sched.schedule_pending_batch() == (0, 0) and len(tr.ring) == 1
+    second = wave("b")
+    for root in (first, second):
+        # the overlapped ingest ("prep") rides in the device's shadow
+        kids = [c for c in root.children if c.name != "prep"]
+        assert [c.name for c in kids] == WAVE_CHILDREN
+        assert all(c.cat == "phase" for c in kids)
+        assert root.t0 == kids[0].t0, "the root starts at the drain"
+        for a, b in zip(root.children, root.children[1:]):
+            assert a.t1 <= b.t0, (a.name, b.name)
+        assert root.children[-1].t1 <= root.t1
+        by = {c.name: c for c in kids}
+        assert [c.name for c in by["commit"].children] == [
+            "commit.assume", "commit.bind", "commit.finish"]
+        assert [c.name for c in by["tensorize"].children] == [
+            "tensorize.build_static", "tensorize.initial_state"]
+        for parent in (by["commit"], by["tensorize"]):
+            assert parent.t0 <= parent.children[0].t0
+            assert parent.children[-1].t1 <= parent.t1
+        assert all(c.attrs["pods"] == 12 for c in by["commit"].children)
+        assert by["queue.drain"].attrs == {"pods": 12}
+        assert by["snapshot"].attrs == {"nodes": 4}
+        assert by["segment_plan"].attrs == {"pods": 12, "segments": 1}
+        assert by["place"].attrs["pods"] == 12
+        assert by["device_wait"].t1 == by["place"].t0
+    by_first = {c.name: c for c in first.children}
+    by_second = {c.name: c for c in second.children}
+    assert by_first["host_state"].attrs == {"nodes": 4, "mode": "rebuild",
+                                            "dirty_nodes": 4}
+    assert by_second["host_state"].attrs["mode"] == "reconcile"
+    assert by_first["place"].attrs["cloned_nodes"] == 4
+
+    # the phase dict gains the new names and keeps the pump's apply_s
+    totals = second.phase_totals()
+    assert "apply_s" not in totals
+    assert sched.last_batch_phases["apply_s"] == pytest.approx(
+        second.attrs["apply_s"], abs=1e-6)
+    for key in ("queue.drain_s", "host_state_s", "place_s", "commit.bind_s"):
+        assert sched.last_batch_phases[key] == totals[key]
+
+    # what no child names, of a warm wave: the set-up between the spans
+    spans: list = []
+    trace_reduce.flatten(second, second.attrs["wave"], spans, None)
+    assert {s["parent"] for s in spans if s["name"].startswith("commit.")} \
+        == {"commit"}
+    booked = _gaps.book(spans)
+    assert booked["waves"] == 1
+    assert booked["idle_ns"] == pytest.approx(second.duration * 1e9, abs=2)
+    assert booked["unnamed_ns"] / booked["idle_ns"] < 0.25
+
+
+@pytest.mark.timeout(120)
+def test_remote_request_span_carries_the_servers_own_time():
+    """Every JSON response of the apiserver carries ``Server-Timing``,
+    tracing on or off; with tracing on a ``bind_many`` leaves ONE
+    ``remote.request`` span holding the server's and the store's own
+    time inside the round trip; tracing off leaves no span and the same
+    bindings."""
+    import urllib.error
+    import urllib.request
+
+    from kubernetes_tpu.apiserver import APIServer
+    from kubernetes_tpu.client.remote import RemoteStore
+    from benchmark import run, trace_reduce
+
+    server = APIServer(Store())
+    server.start()
+    try:
+        remote = RemoteStore(server.url)
+        cs = Clientset(remote)
+        cs.nodes.create(make_node("n0", cpu="8", memory="16Gi"))
+        cs.pods.create_many([make_pod(f"p{i}", cpu="100m") for i in range(6)])
+
+        def timing(path):
+            try:
+                with urllib.request.urlopen(f"{server.url}{path}") as r:
+                    return r.headers["Server-Timing"]
+            except urllib.error.HTTPError as e:
+                return e.headers["Server-Timing"]
+
+        # /healthz makes no store call; a LIST and a failed GET do
+        assert timing("/healthz").startswith("handle;dur=")
+        assert "store" not in timing("/healthz")
+        for path in ("/api/v1/pods", "/api/v1/namespaces/default/pods/none"):
+            handle, store = timing(path).split(", ")
+            assert handle.startswith("handle;dur=") and store.startswith("store;dur=")
+            assert 0 < float(store[10:]) <= float(handle[11:])
+
+        assert tracing.current() is None
+        assert remote.bind_many([("default", f"p{i}", "n0")
+                                 for i in range(3)]) == [None] * 3
+        tr = tracing.enable()
+        assert remote.bind_many([("default", f"p{i}", "n0")
+                                 for i in range(3, 6)]) == [None] * 3
+        pods, _ = cs.pods.list()
+        assert {p.meta.name: p.spec.node_name for p in pods} == {
+            f"p{i}": "n0" for i in range(6)}
+
+        binds = [sp for sp in tr.background if sp.name == "remote.request"
+                 and sp.attrs["path"] == "/api/v1/bindings:batch"]
+        assert len(binds) == 1, "one span per request, none with tracing off"
+        sp = binds[0]
+        a = sp.attrs
+        assert (a["method"], a["items"], a["status"], a["attempts"]) == (
+            "POST", 3, 200, 1)
+        assert a["bytes_out"] > 0 and a["bytes_in"] > 0
+        assert 0 < a["store_s"] <= a["server_s"] <= sp.duration
+        assert 0 <= a["encode_s"] + a["decode_s"] <= sp.duration
+
+        # a refused request: the span says so, and still has the header
+        with pytest.raises(Exception):
+            cs.pods.get("none")
+        missed = [s for s in tr.background if s.name == "remote.request"
+                  and s.attrs.get("status") == 404]
+        assert len(missed) == 1 and "NotFound" in missed[0].attrs["error"]
+        assert missed[0].attrs["server_s"] > 0
+
+        # the readers of the bind round trip, on the program's own span
+        # under the commit's bind (as the wave tree holds it)
+        spans: list = []
+        trace_reduce.flatten(sp, None, spans, "commit.bind")
+        facts = {"spans": spans}
+        rtt = run.read_layer_metric("bind_rtt_us_per_pod", facts)
+        srv = run.read_layer_metric("bind_server_us_per_pod", facts)
+        assert rtt == pytest.approx(sp.duration * 1e6 / 3)
+        assert 0 < srv <= rtt
+    finally:
+        server.stop()
+
+
 @pytest.mark.timeout(60)
 def test_debug_endpoints_serve_traces_and_flightrecorder():
     """The daemon health server's ``/debug/traces`` (Chrome export) and
@@ -468,6 +625,30 @@ def _admit_fire(point):
         server.stop()
 
 
+def _coalesce_fire(point):
+    """store.coalesce fires in the store's window flush, off every wave
+    path: warm waves on a coalescing store fill the recorder ring, then
+    creates open a window and an armed ``flush_coalesced()`` closes it."""
+    w = World(store=Store(coalesce_window_s=30.0))
+    try:
+        for i in range(8):
+            w.cs.pods.create(make_pod(f"warm-{i:03d}", cpu="200m",
+                                      memory="256Mi"))
+        w.store.flush_coalesced()
+        w.drive(rounds=4, relist_every=0)
+        assert len(tracing.current().ring) >= 1, "warm phase completed no wave"
+        w.store.flush_coalesced()     # the binds' own window, unarmed
+        plan = FaultPlan(seed=5).on(point, mode="error", nth=1)
+        with plan.armed():
+            for i in range(4):
+                w.cs.pods.create(make_pod(f"work-{i:03d}", cpu="200m",
+                                          memory="256Mi"))
+            w.store.flush_coalesced()
+        assert plan.fired[point] == 1, f"{point}: fault never fired"
+    finally:
+        w.store.close()
+
+
 @pytest.mark.timeout(180)
 @pytest.mark.parametrize("point", sorted(MATRIX))
 def test_every_fault_point_dumps_the_firing_waves_trace(point, tmp_path):
@@ -486,6 +667,8 @@ def test_every_fault_point_dumps_the_firing_waves_trace(point, tmp_path):
         _telemetry_fire(point)
     elif scenario["world"] == "admit":
         _admit_fire(point)
+    elif scenario["world"] == "coalesce":
+        _coalesce_fire(point)
     else:
         _warm_then_fire(point, scenario, tmp_path)
 
@@ -502,6 +685,14 @@ def test_every_fault_point_dumps_the_firing_waves_trace(point, tmp_path):
     if point == "scheduler.bind":
         # the dropped bind also requeues: that is its own trigger
         assert any(x["reason"] == "bind.requeue" for x in tr.dumps)
+    if point == "store.coalesce":
+        # the seam fires inside the flush's own span: live in the dump
+        # (the store's thread has no wave open), folded count and all
+        flush = [x for x in d["live"] if x["name"] == "store.txn"
+                 and x["attrs"].get("op") == "coalesce_flush"]
+        assert flush and flush[0]["t1"] is None
+        assert flush[0]["attrs"]["events"] == 4
+        assert flush[0]["attrs"]["folded"] == 0
 
 
 @pytest.mark.timeout(180)
@@ -610,23 +801,10 @@ def test_trace_lands_in_active_tracer_with_steps():
     assert len([s for s in tr.background if s.name == "schedule_one"]) == 1
 
 
-def test_format_slow_is_the_shared_rendering():
+def test_format_slow_renders_total_and_step_deltas():
     out = tracing.format_slow("op", 1.0, [(1.2, "a"), (1.5, "b")], 1.6)
     assert out.splitlines() == [
         'Trace "op" (total 600.0ms):',
         "  +200.0ms a",
         "  +300.0ms b",
     ]
-
-
-def test_slow_wave_logging_uses_format_slow(caplog):
-    clk = FakeClock()
-    tr = tracing.enable(clock=clk, slow_wave_s=1.0)
-    with caplog.at_level("INFO", logger="kubernetes_tpu.tracing"):
-        with tr.wave() as w:
-            clk.advance(0.2)
-            w.step(clk(), "tensorized")
-            clk.advance(1.0)
-    assert len(caplog.records) == 1
-    assert 'Trace "wave-1" (total 1200.0ms):' in caplog.records[0].message
-    assert "+200.0ms tensorized" in caplog.records[0].message
