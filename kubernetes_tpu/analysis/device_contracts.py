@@ -45,10 +45,10 @@ Rules
   receives the scheduler snapshot (a ``node_info_map`` parameter, or a
   ``dict(node_info_map)`` working copy), mutating a ``NodeInfo``
   obtained from that map (``.add_pod`` / ``.add_pod_counted`` /
-  ``.remove_pod`` / ``.replace_pod`` / ``.set_node`` / ``.remove_node``,
-  or an attribute store) without flowing through ``mutable_info`` is an
-  error — the ROADMAP's "must route through mutable_info" caveat,
-  gated.
+  ``.add_pods_counted`` / ``.remove_pod`` / ``.replace_pod`` /
+  ``.set_node`` / ``.remove_node``, or an attribute store) without
+  flowing through ``mutable_info`` is an error — the ROADMAP's "must
+  route through mutable_info" caveat, gated.
 - **DC605** — a stale or reasonless device annotation: a
   ``# device: sync`` with no materialization-shaped call on its line or
   the next (the check is LEXICAL so an annotation stays valid even
@@ -82,8 +82,8 @@ DEFAULT_PATHS = [
 #: NodeInfo's mutating surface (scheduler/nodeinfo.py); ``clone()`` is
 #: deliberately absent — cloning IS the sanctioned CoW step.
 NODEINFO_MUTATORS = {
-    "add_pod", "add_pod_counted", "remove_pod", "replace_pod",
-    "set_node", "remove_node",
+    "add_pod", "add_pod_counted", "add_pods_counted", "remove_pod",
+    "replace_pod", "set_node", "remove_node",
 }
 
 #: array metadata — reading these never materializes device memory

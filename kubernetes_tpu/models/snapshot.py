@@ -526,6 +526,60 @@ class HostBatchState:
         if key not in self.node_pods[j]:
             self._ingest(pod, j, key)
 
+    def add_pods(self, pods, keys, node_names, groups, has_disks) -> None:
+        """``add_pod`` for a kernel segment's results at once: one pass in
+        pod order, so every index comes out as the per-pod calls give it,
+        and the parallel arrays are extended once.  ``keys[k]`` is pod k's
+        ``meta.key`` (``BatchStatic.pod_names`` holds them already) and
+        ``groups[k]`` its scheduling-signature group: namespace and labels
+        are facts of the signature (``pod_signature_key``), so the content
+        key and the label-map id are taken once per group.  Direct disks
+        are NOT in the signature; ``has_disks[k]`` says which pods
+        reference any, and those keep their own content key and the
+        per-pod ``_disk_add``.  Skips what ``add_pod`` skips: unplaced pods
+        (node ``None``), nodes absent from ``node_index``, pods already
+        present under their key."""
+        node_index, node_pods = self.node_index, self.node_pods
+        idx = len(self.pod_lids)
+        lids, js, new_keys, contents, disks = [], [], [], [], []
+        shared: dict[int, list] = {}  # group -> [content, lid, pods]
+        for pod, key, name, g, own_disks in zip(pods, keys, node_names,
+                                                groups, has_disks):
+            j = node_index.get(name)
+            if j is None:
+                continue
+            mine = node_pods[j]
+            if key in mine:
+                continue
+            if own_disks:
+                content = _pod_content_key(pod)
+                lid = self._labelmap_id(pod, content)
+                self._count_live(content, 1)
+                mounted = self._mount_disks(pod, j)
+            else:
+                hit = shared.get(g)
+                if hit is None:
+                    content = _pod_content_key(pod)
+                    hit = shared[g] = [
+                        content, self._labelmap_id(pod, content), 0]
+                hit[2] += 1
+                content, lid, mounted = hit[0], hit[1], None
+            mine[key] = idx
+            idx += 1
+            lids.append(lid)
+            js.append(j)
+            new_keys.append(key)
+            contents.append(content)
+            disks.append(mounted)
+        for content, _, n in shared.values():
+            self._count_live(content, n)
+        self.pod_lids.extend(lids)
+        self.pod_node_j.extend(js)
+        self.pod_keys.extend(new_keys)
+        self.pod_content.extend(contents)
+        self.pod_disks.extend(disks)
+        self._node_j_cache = None
+
     def selector_id(self, reqs: list[tuple]) -> int:
         """Content-interned ``eng.add_selector``: per-segment spread and
         term selectors repeat across segments and batches (same services/
@@ -541,33 +595,46 @@ class HostBatchState:
         if key is None:
             key = pod.meta.key
         content = _pod_content_key(pod)
+        self.pod_lids.append(self._labelmap_id(pod, content))
+        self._count_live(content, 1)
+        self.pod_node_j.append(j)
+        self.pod_keys.append(key)
+        self.pod_content.append(content)
+        self.node_pods[j][key] = len(self.pod_lids) - 1
+        self._node_j_cache = None
+        self.pod_disks.append(self._mount_disks(pod, j))
+
+    def _labelmap_id(self, pod: api.Pod, content: tuple) -> int:
+        """The interned native label-map id of the pod's (namespace,
+        labels) content."""
         lid = self._lid_memo.get(content[:2])
         if lid is None:
             labels, ns = lazy_mod.labels_ns_of(pod)
             lid = self.eng.add_labelmap({**labels, _NS_KEY: ns})
             self._lid_memo[content[:2]] = lid
-        self._content_rc[content[:2]] = self._content_rc.get(content[:2], 0) + 1
-        idx = len(self.pod_lids)
-        self.pod_lids.append(lid)
-        self.pod_node_j.append(j)
-        self.pod_keys.append(key)
-        self.pod_content.append(content)
-        self.node_pods[j][key] = idx
-        self._node_j_cache = None
-        disks = None
+        return lid
+
+    def _count_live(self, content: tuple, n: int) -> None:
+        """``n`` more live pods carry this (namespace, labels) content."""
+        content2 = content[:2]
+        self._content_rc[content2] = self._content_rc.get(content2, 0) + n
+
+    def _mount_disks(self, pod: api.Pod, j: int) -> Optional[list]:
+        """Record the pod's direct-disk references on node ``j``; returns
+        its ``pod_disks`` entry (``None`` for a pod without any)."""
         vol_refs = _disk_refs(pod)
-        if vol_refs:
-            per_pod: dict[tuple, bool] = {}  # all-refs-read-only per disk
-            for kind, disk_id, read_only in vol_refs:
-                key = (kind, disk_id)
-                per_pod[key] = per_pod.get(key, True) and read_only
-            if per_pod:
-                disks = []
-                for key, all_ro in per_pod.items():
-                    ns = not (key[0] in _READONLY_SHARED_KINDS and all_ro)
-                    disks.append((key, ns))
-                    self._disk_add(key, j, ns)
-        self.pod_disks.append(disks)
+        if not vol_refs:
+            return None
+        per_pod: dict[tuple, bool] = {}  # all-refs-read-only per disk
+        for kind, disk_id, read_only in vol_refs:
+            key = (kind, disk_id)
+            per_pod[key] = per_pod.get(key, True) and read_only
+        disks = []
+        for key, all_ro in per_pod.items():
+            ns = not (key[0] in _READONLY_SHARED_KINDS and all_ro)
+            disks.append((key, ns))
+            self._disk_add(key, j, ns)
+        return disks
 
     def _disk_add(self, key: tuple, j: int, ns: bool) -> None:
         locs = self.disk_locations.setdefault(key, {})

@@ -24,11 +24,13 @@ import logging
 import time
 from typing import Optional
 
+import numpy as np
+
 from .. import faults
 from ..api import types as api
 from ..utils import tracing
 from ..scheduler.generic_scheduler import FitError, GenericScheduler
-from ..scheduler.nodeinfo import NodeInfo
+from ..scheduler.nodeinfo import NodeInfo, pod_has_affinity
 from ..scheduler.predicates import DEFAULT_PREDICATES
 from ..scheduler.priorities import (
     BalancedResourceAllocation,
@@ -43,6 +45,7 @@ from ..scheduler.priorities import (
     SelectorSpreadPriority,
     TaintTolerationPriority,
 )
+from ..scheduler.units import CPU_MILLI, MEM_MIB, ResourceVec
 from ..models.snapshot import (
     HostBatchState,
     Tensorizer,
@@ -84,22 +87,36 @@ _PRIORITY_WEIGHT_KEY = {
 }
 
 
-def _segment_vecs(static):
-    """Per-signature ResourceVecs for the commit path (once per segment,
-    G <= max_groups): the full request vector, and the nonzero variant
-    (cpu/mem replaced by the per-container-defaulted values; other slots
-    are identical by construction — see units.pod_nonzero_request_vec)."""
-    from ..scheduler.units import CPU_MILLI, MEM_MIB, ResourceVec
+def _segment_units(static, n_groups: int):
+    """Per-signature request units for the commit path (once per segment,
+    G <= max_groups), as ``[G, R]`` int64 rows: the full request vector,
+    and the nonzero variant (cpu/mem replaced by the per-container-
+    defaulted values; other slots are identical by construction — see
+    units.pod_nonzero_request_vec).  int64, so a node's sum over its pods
+    is exact."""
+    req = np.asarray(static.g_request[:n_groups], dtype=np.int64)
+    nz = req.copy()
+    nz[:, CPU_MILLI] = static.g_nonzero[:n_groups, 0]
+    nz[:, MEM_MIB] = static.g_nonzero[:n_groups, 1]
+    return req, nz
 
-    req_vecs, nz_vecs = [], []
-    for g in range(len(static.g_request)):
-        units = [int(x) for x in static.g_request[g]]
-        req_vecs.append(ResourceVec(units))
-        nz_units = list(units)
-        nz_units[CPU_MILLI] = int(static.g_nonzero[g][0])
-        nz_units[MEM_MIB] = int(static.g_nonzero[g][1])
-        nz_vecs.append(ResourceVec(nz_units))
-    return req_vecs, nz_vecs
+
+def _by_node(chosen: np.ndarray, groups: np.ndarray, n_groups: int):
+    """A segment's placed pods grouped by chosen node.  The sort is
+    stable, so each node's pods stay in pod order; the unplaced (-1) sort
+    first and are cut off.  Returns the touched node indices, the placed
+    pod positions ordered by node, the T + 1 bounds of each node's run in
+    that order, and the ``[T, G]`` count of each group's pods per node."""
+    order = np.argsort(chosen, kind="stable")
+    order = order[np.searchsorted(chosen[order], 0):]
+    touched, starts, sizes = np.unique(
+        chosen[order], return_index=True, return_counts=True)
+    rows = np.repeat(np.arange(len(touched)), sizes)
+    counts = np.bincount(rows * n_groups + groups[order],
+                         minlength=len(touched) * n_groups)
+    return (touched.tolist(), order.tolist(),
+            starts.tolist() + [len(order)],
+            counts.reshape(len(touched), n_groups))
 
 
 class _PrefilteredScan:
@@ -228,7 +245,8 @@ class TPUBatchBackend:
         # per-batch frontier trajectory: one entry per frontier segment
         # ({"widths": [...], "alive_frac": [...], ...}); bench snapshots it
         self.last_frontier: list = []
-        self.stats = {"kernel_pods": 0, "oracle_pods": 0, "segments": 0,
+        self.stats = {"kernel_pods": 0, "place_batched_pods": 0,
+                      "oracle_pods": 0, "segments": 0,
                       "pallas_segments": 0, "pallas_fallbacks": 0,
                       "interpret_fallbacks": 0, "oracle_segments": 0,
                       "breaker_transitions": 0,
@@ -413,8 +431,6 @@ class TPUBatchBackend:
         too few pods to chunk) or when any frontier step fails — the
         caller then dispatches the plain full-width scan, so a frontier
         bug can cost time, never parity."""
-        import numpy as np
-
         from ..models.snapshot import compact_segment, frontier_seed
         from .batch_kernel import FrontierRun, _pow2_width
 
@@ -705,20 +721,69 @@ class TPUBatchBackend:
             host_state = self._host_state
         mounted_disks = host_state.mounted_disks if host_state is not None else set()
 
-        def apply(pod: api.Pod, node_name: Optional[str], i: int,
-                  req_vec=None, nz_vec=None) -> None:
+        def apply(pod: api.Pod, node_name: Optional[str], i: int) -> None:
+            # the oracle's results, one at a time: it reads the working
+            # map between pods
             assignments[i] = node_name
             if node_name is not None:
                 info = mutable_info(node_name)
                 if info is not None:
-                    if req_vec is not None:
-                        # kernel path: the segment's per-signature vectors
-                        # spare a quantity re-parse per placed pod
-                        info.add_pod_counted(pod, req_vec, nz_vec)
-                    else:
-                        info.add_pod(pod)
+                    info.add_pod(pod)
                 if host_state is not None:
                     host_state.add_pod(pod, node_name)
+
+        def place(segment, static, chosen, node_names) -> tuple:
+            """A finished kernel segment's results, all at once, onto the
+            working snapshot BY NODE: each touched node is cloned once and
+            takes one aggregate add, the host state one ingest.  What the
+            per-pod calls read from every pod (requests, the affinity
+            flag, host ports) is a fact of its scheduling signature and is
+            read once per group, from the group's first pod.  Returns the
+            commit entries and the counts of nodes written and groups."""
+            seg_pods = [pod for _, pod in segment]
+            groups = static.group_of_pod
+            group_of = groups.tolist()
+            names = [node_names[c] if c >= 0 else None
+                     for c in chosen.tolist()]
+            for (i, _), name in zip(segment, names):
+                assignments[i] = name
+            reps = [seg_pods[k] for k in
+                    np.unique(groups, return_index=True)[1].tolist()]
+            req_units, nz_units = _segment_units(static, len(reps))
+            req_vecs = [ResourceVec(u) for u in req_units.tolist()]
+            nz_vecs = [ResourceVec(u) for u in nz_units.tolist()]
+            # the segment's per-signature vectors ride along so the
+            # caller's cache assume can skip its own quantity parse
+            entries = [(pod, name, req_vecs[g], nz_vecs[g])
+                       for pod, name, g in zip(seg_pods, names, group_of)]
+
+            touched, order, bounds, counts = _by_node(chosen, groups, len(reps))
+            req_sums = (counts @ req_units).tolist()
+            nz_sums = (counts @ nz_units).tolist()
+            has_affinity = [pod_has_affinity(rep) for rep in reps]
+            any_affinity = any(has_affinity)
+            port_groups = [(g, ports) for g, rep in enumerate(reps)
+                           if (ports := rep.host_ports())]
+            n_nodes = n_pods = 0
+            for t, c in enumerate(touched):
+                node_info = mutable_info(node_names[c])
+                if node_info is None:
+                    continue
+                ks = order[bounds[t]:bounds[t + 1]]
+                node_info.add_pods_counted(
+                    [seg_pods[k] for k in ks],
+                    ResourceVec(req_sums[t]), ResourceVec(nz_sums[t]),
+                    [seg_pods[k] for k in ks if has_affinity[group_of[k]]]
+                    if any_affinity else (),
+                    [port for g, ports in port_groups if counts[t, g]
+                     for port in ports])
+                n_nodes += 1
+                n_pods += len(ks)
+            self.stats["place_batched_pods"] += n_pods
+            # the kernel path always has the host state (weights is not None)
+            host_state.add_pods(seg_pods, static.pod_names, names, group_of,
+                                static.pod_vol_valid.any(axis=1).tolist())
+            return entries, n_nodes, len(reps)
 
         def run_oracle(pod: api.Pod, i: int) -> None:
             try:
@@ -998,16 +1063,8 @@ class TPUBatchBackend:
                                 pods=len(segment))
                 self.algorithm._round_robin = final_rr
                 cloned_before = len(_cloned)
-                req_vecs, nz_vecs = _segment_vecs(static)
-                group_of_pod = static.group_of_pod
-                entries = []
-                for k, ((i, pod), idx) in enumerate(zip(segment, chosen)):
-                    node_name = names_static.node_names[int(idx)] if int(idx) >= 0 else None
-                    g = int(group_of_pod[k])
-                    apply(pod, node_name, i, req_vecs[g], nz_vecs[g])
-                    # the segment's per-signature vectors ride along so the
-                    # caller's cache assume can skip its own quantity parse
-                    entries.append((pod, node_name, req_vecs[g], nz_vecs[g]))
+                entries, n_nodes, n_groups = place(
+                    segment, static, chosen, names_static.node_names)
                 self.stats["kernel_pods"] += len(segment)
                 self.stats["segments"] += 1
                 if tr is not None:
@@ -1015,7 +1072,8 @@ class TPUBatchBackend:
                     # where device_wait ended (one clock read, no gap)
                     tr.complete("place", t_wait_end, self._clock_wall(),
                                 cat="phase", pods=len(segment),
-                                cloned_nodes=len(_cloned) - cloned_before)
+                                cloned_nodes=len(_cloned) - cloned_before,
+                                nodes=n_nodes, groups=n_groups)
                 return entries
 
             finish.device_probe = device_probe

@@ -123,6 +123,22 @@ class NodeInfo:
             self.used_ports.add(port)
         self.generation += 1
 
+    def add_pods_counted(self, pods: list, req_sum, nz_sum,
+                         affinity_pods, ports) -> None:
+        """``add_pod_counted`` for every pod of ``pods`` in one call: the
+        state afterwards equals the per-pod calls in list order, field
+        for field.  ``req_sum`` / ``nz_sum`` MUST be the exact sums of
+        the pods' request vectors, ``affinity_pods`` the pods for which
+        ``pod_has_affinity`` holds (in list order) and ``ports`` the
+        union of their ``host_ports()`` — the batch backend derives all
+        four per scheduling signature, not per pod."""
+        self.pods.extend(pods)
+        self.pods_with_affinity.extend(affinity_pods)
+        self.requested.add(req_sum)
+        self.nonzero_requested.add(nz_sum)
+        self.used_ports.update(ports)
+        self.generation += len(pods)
+
     def replace_pod(self, old_pod: api.Pod, new_pod: api.Pod) -> bool:
         """Swap one resident pod object for a content-equivalent newer
         version WITHOUT re-aggregating (same requests/ports/affinity —

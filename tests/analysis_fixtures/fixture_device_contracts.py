@@ -136,9 +136,19 @@ def fixture_schedule(node_info_map, pods):
         work_map[name].remove_pod(pod)
         raw.node = None
 
+    def place_ok(name, pods):
+        fresh = mutable_info(name)
+        fresh.add_pods_counted(pods, None, None, (), ())
+
+    def place_bad(name, pods):
+        stale = work_map.get(name)
+        stale.add_pods_counted(pods, None, None, (), ())
+
     for pod in pods:
         apply_ok(pod, pod)
         apply_bad(pod, pod)
+    place_ok(pods[0], pods)
+    place_bad(pods[0], pods)
     return work_map
 
 

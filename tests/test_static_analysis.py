@@ -693,6 +693,9 @@ def test_device_fixture_codes_and_locations(device_findings):
             DC_PATH, "work_map[name].remove_pod(pod)"),
         ("DC604", "fixture_schedule.apply_bad.raw.node"): _fixture_line(
             DC_PATH, "raw.node = None"),
+        # ... and a by-node batched write to an uncloned NodeInfo
+        ("DC604", "fixture_schedule.place_bad.stale.add_pods_counted"):
+            _fixture_line(DC_PATH, "stale.add_pods_counted(pods"),
         # DC605: stale sync, reasonless sync, unused static
         ("DC605", f"stale_sync_annotation.L{ann_stale}"): ann_stale,
         ("DC605", f"reasonless_sync.L{ann_reasonless}"): ann_reasonless,
@@ -722,6 +725,7 @@ def test_device_fixture_exemptions_stay_clean(device_findings):
         "width_ok",                  # width under a # device: static
         "factory_call_ok",           # int()-normalized compile key
         "fixture_schedule.apply_ok",  # mutation through mutable_info
+        "fixture_schedule.place_ok",  # batched write through mutable_info
     ):
         assert not any(s.startswith(clean) for s in symbols), sorted(symbols)
 
@@ -747,10 +751,20 @@ def test_device_pass_catches_seeded_donation_bug(tmp_path):
     assert ("DC601", "FrontierRun._dispatch_loop._state") in got, got
 
 
-def test_device_pass_catches_seeded_cow_bypass(tmp_path):
-    """Replacing backend.schedule_batch's `mutable_info(...)` with a raw
-    `work_map.get(...)` — the exact regression the ROADMAP caveat warned
-    about — is caught at both mutation sites; the untouched copy is
+@pytest.mark.parametrize("sanctioned, bypass, mutator", [
+    # the oracle path's per-pod write
+    ("info = mutable_info(node_name)", "info = work_map.get(node_name)",
+     "info.add_pod"),
+    # the kernel path's by-node batched write
+    ("node_info = mutable_info(node_names[c])",
+     "node_info = work_map.get(node_names[c])",
+     "node_info.add_pods_counted"),
+], ids=["per-pod", "by-node"])
+def test_device_pass_catches_seeded_cow_bypass(tmp_path, sanctioned, bypass,
+                                               mutator):
+    """Replacing one of backend.schedule_batch's `mutable_info(...)` with a
+    raw `work_map.get(...)` — the exact regression the ROADMAP caveat
+    warned about — is caught at that mutation site; the untouched copy is
     clean."""
     from kubernetes_tpu.analysis import device_contracts as dc
 
@@ -759,15 +773,12 @@ def test_device_pass_catches_seeded_cow_bypass(tmp_path):
         src = f.read()
     (tmp_path / "be_clean.py").write_text(src)
     assert dc.run(str(tmp_path), paths=["be_clean.py"]) == []
-    sanctioned = "info = mutable_info(node_name)"
-    assert sanctioned in src
-    (tmp_path / "be_bug.py").write_text(src.replace(
-        sanctioned, "info = work_map.get(node_name)", 1))
+    assert src.count(sanctioned) == 1
+    (tmp_path / "be_bug.py").write_text(src.replace(sanctioned, bypass))
     got = {(f.code, f.symbol)
            for f in dc.run(str(tmp_path), paths=["be_bug.py"])}
     symbols = {s for c, s in got if c == "DC604"}
-    assert any(s.endswith("info.add_pod_counted") for s in symbols), got
-    assert any(s.endswith("info.add_pod") for s in symbols), got
+    assert any(s.endswith(mutator) for s in symbols), got
 
 
 def test_sanctioned_sync_sites_counts():

@@ -360,7 +360,12 @@ def test_the_wave_covers_the_loop_iteration_in_named_children():
     assert by_first["host_state"].attrs == {"nodes": 4, "mode": "rebuild",
                                             "dirty_nodes": 4}
     assert by_second["host_state"].attrs["mode"] == "reconcile"
-    assert by_first["place"].attrs["cloned_nodes"] == 4
+    # the results went on by node: each of the 4 touched nodes was cloned
+    # once and took one batched call; 12 pods of one signature
+    assert by_first["place"].attrs == {"pods": 12, "cloned_nodes": 4,
+                                       "nodes": 4, "groups": 1}
+    assert by_second["place"].attrs == by_first["place"].attrs
+    assert sched.backend.stats["place_batched_pods"] == 24
 
     # the phase dict gains the new names and keeps the pump's apply_s
     totals = second.phase_totals()
