@@ -1377,8 +1377,9 @@ def _make_handler(server: APIServer):
             pred, sel_err = self._compile_selectors(q)
             if sel_err is not None:
                 return self._error(400, "BadRequest", sel_err)
-            # column-packed frame delivery (?frames=1): one JSON line
-            # per correlated batch txn instead of N.  Selector watches
+            # column-packed frame delivery (?frames=1): a correlated
+            # batch txn as JSON lines of at most frames.FRAME_MAX_ROWS
+            # events each instead of N lines.  Selector watches
             # get frames too (ISSUE 19): the predicate filters at the
             # COLUMN level and a matching sub-frame is re-packed before
             # encoding — per-event JSON lines only for clients that
@@ -1395,11 +1396,28 @@ def _make_handler(server: APIServer):
                 import time as _t
 
                 deadline = _t.monotonic() + timeout
-                while _t.monotonic() < deadline:
-                    ev = watch.get(timeout=min(0.5, max(0.0, deadline - _t.monotonic())))
+                # the txn whose frame went out last.  Its other pieces
+                # are on the queue already (the store puts them in one
+                # lock hold), and the stream does not end between two of
+                # them: the resume would replay the rest of the txn from
+                # the log per event, tens of thousands of lines for a
+                # large wave, where the pieces are a few chunk writes
+                txn = None
+                while True:
+                    left = deadline - _t.monotonic()
+                    if left <= 0 and txn is None:
+                        break
+                    ev = watch.get(timeout=min(0.5, max(0.0, left)))
+                    if left <= 0 and not (ev is not None and ev.type == FRAME
+                                          and ev.txn == txn):
+                        # (anything else taken off the queue here is
+                        # replayed from the log when the client resumes)
+                        break
                     if ev is None:
+                        txn = None
                         continue
                     if ev.type == FRAME:
+                        txn = ev.txn
                         frame = ev
                         if pred is not None:
                             # the LIST-then-WATCH contract at the column
@@ -1417,6 +1435,7 @@ def _make_handler(server: APIServer):
                         # shared-immutable across watcher queues)
                         self._write_chunk(frame.wire_bytes())
                         continue
+                    txn = None
                     if pred is not None and not pred(ev.object):
                         # a selector silently ignored on watch would
                         # re-create the full-cluster fan-out the

@@ -197,8 +197,9 @@ class TypedClient:
 
     def watch(self, from_revision: Optional[int] = None,
               frames: bool = False) -> Watch:
-        """``frames=True`` requests column-packed batch delivery (one
-        WatchFrame per correlated store txn) when the transport supports
+        """``frames=True`` requests column-packed batch delivery (a
+        correlated store txn as WatchFrames of at most
+        ``frames.FRAME_MAX_ROWS`` rows) when the transport supports
         it; per-event otherwise.  Only frame-aware consumers (the
         informer's batch apply) should opt in."""
         if frames and self._watch_frames:

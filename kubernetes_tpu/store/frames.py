@@ -10,8 +10,9 @@ pump APPLICATION (cache apply + bind confirm) was the largest remaining
 host cost in the profile (~0.3-0.8s spikes per wave).
 
 A :class:`WatchFrame` packs one correlated store batch — everything a
-``create_many``/``bind_many`` txn committed under one store lock hold —
-into parallel columns:
+``create_many``/``bind_many`` txn committed under one store lock hold
+or, past :data:`FRAME_MAX_ROWS` rows, one piece of it in revision order
+(:func:`pack_frames`) — into parallel columns:
 
 - **op/kind/identity columns**: ``types`` (ADDED/MODIFIED/DELETED),
   ``keys``, ``revisions`` as flat lists (one ``kind`` per frame — a
@@ -55,6 +56,16 @@ SHARED_ENCODE = True
 # transition (like WATCH_GAP).  Consumers that dispatch on event type
 # must expand the frame (``events()``) or apply it as a batch.
 FRAME = "FRAME"
+
+# The most rows one frame carries.  A frame is encoded (and decoded) by
+# one C-level json call that holds its process's interpreter lock from
+# start to end: at ~845 wire bytes per bound pod a 60,000-row frame is a
+# 50 MB, 0.45 s critical section on a watcher's thread of the apiserver,
+# ahead of the handler thread that has the answer to the ``bind_many``
+# that emitted it.  Nobody needs the txn to be ONE delivery — the fence
+# is per frame, the txn id rides every piece — so a batch leaves in
+# pieces and the answer waits behind at most one piece's encode.
+FRAME_MAX_ROWS = 2048
 
 
 class FrameDecodeError(Exception):
@@ -217,6 +228,32 @@ class WatchFrame:
             raise FrameDecodeError("frame txn id must be a string")
         return cls(kind, list(types), list(keys), revisions, list(objects),
                    prev_revisions=prev, txn=txn)
+
+
+def pack_frames(kind: str, events: list,
+                prev_revisions: Optional[list] = None,
+                txn: Optional[str] = None) -> list:
+    """One correlated batch (revision order, single kind) as a list of
+    :class:`WatchFrame` pieces of at most :data:`FRAME_MAX_ROWS` rows
+    each, in order, ``prev_revisions`` sliced alike, every piece carrying
+    the txn's id.  A batch at or under the bound is one frame; a one-row
+    remainder is still a frame (its prev-revision fence column is kept).
+    The pieces' fences (``frame.revision``) increase strictly, so a
+    consumer that applied some of them resumes after its last one."""
+    out = []
+    for lo in range(0, len(events), FRAME_MAX_ROWS):
+        evs = events[lo:lo + FRAME_MAX_ROWS]
+        out.append(WatchFrame(
+            kind,
+            [e.type for e in evs],
+            [e.key for e in evs],
+            [e.revision for e in evs],
+            [e.object for e in evs],
+            prev_revisions=(None if prev_revisions is None
+                            else prev_revisions[lo:lo + FRAME_MAX_ROWS]),
+            txn=txn,
+        ))
+    return out
 
 
 def event_wire_bytes(ev) -> bytes:

@@ -177,13 +177,15 @@ class Store:
         self._log: collections.deque[WatchEvent] = collections.deque(maxlen=event_log_window)
         self._log_window = event_log_window
         # (kind filter, queue, wants_frames): frame-aware watchers opted
-        # in via watch(frames=True) receive one WatchFrame per correlated
-        # batch txn; everyone else gets the per-event expansion
+        # in via watch(frames=True) receive a correlated batch txn as
+        # WatchFrames (one when it fits frames.FRAME_MAX_ROWS); everyone
+        # else gets the per-event expansion
         self._watchers: list[tuple[Optional[str], "queue.Queue[Optional[WatchEvent]]", bool]] = []
         # time-window update coalescing (the serving-tier broadcaster
         # seam): 0.0 (default) = off, every event fans out at commit;
         # > 0 = single-event update/delete churn is folded per key
-        # (latest wins) and flushed as ONE synthetic WatchFrame per kind
+        # (latest wins) and flushed as synthetic WatchFrames per kind
+        # (one while the kind's keys fit frames.FRAME_MAX_ROWS)
         # when the window closes.  Batch txns (_emit_many), new watcher
         # registration, and snapshot installs are ordering barriers that
         # flush the open window first.
@@ -330,10 +332,10 @@ class Store:
                         results.append(ev_copy)
                     except Exception:  # noqa: BLE001 - one bad item, not the batch
                         results.append(None)
-                # the whole txn fans out as ONE column-packed frame per
+                # the whole txn fans out column-packed to every
                 # frame-aware watcher (per-event to everyone else)
-                self._emit_many(events, txn=txn)
-            sp.set(committed=len(events))
+                n_frames = self._emit_many(events, txn=txn)
+            sp.set(committed=len(events), frames=n_frames)
             return results
 
     def update(
@@ -442,8 +444,9 @@ class Store:
                 # exactly this revision knows nothing else changed
                 prev_revs.append(prev_rev)
                 results.append(None)
-            self._emit_many(events, prev_revisions=prev_revs, txn=txn)
-        sp.set(committed=len(events),
+            n_frames = self._emit_many(events, prev_revisions=prev_revs,
+                                       txn=txn)
+        sp.set(committed=len(events), frames=n_frames,
                errors=sum(1 for r in results if r is not None))
         return results
 
@@ -590,8 +593,10 @@ class Store:
 
         ``frames=True`` opts this watcher into column-packed delivery:
         a correlated batch txn (``create_many``/``bind_many``) arrives as
-        ONE :class:`~.frames.WatchFrame` instead of N events (the log
-        replay below stays per-event — only live batches frame)."""
+        :class:`~.frames.WatchFrame` pieces of at most
+        ``frames.FRAME_MAX_ROWS`` rows — one frame when it fits — instead
+        of N events (the log replay below stays per-event — only live
+        batches frame)."""
         with self._mu:
             # ordering barrier: flush the open coalescing window before
             # the log replay below — otherwise the replay (which reads
@@ -704,7 +709,7 @@ class Store:
         # honestly unknown — consumers take the per-object fallback
         # compare, exactly the plain-update CAS semantics); the fence
         # (frame.revision = last entry) is exact as ever
-        frames_by_kind: dict[str, object] = {}
+        frames_by_kind: dict[str, list] = {}
         tr = tracing.current()
         with (tr.span("store.txn", cat="store", op="coalesce_flush",
                       txn=p.txn, events=len(events), folded=p.folded)
@@ -714,15 +719,8 @@ class Store:
                 if frames_mod.ENABLED:
                     for kind, evs in by_kind.items():
                         if len(evs) > 1:
-                            frames_by_kind[kind] = frames_mod.WatchFrame(
-                                kind,
-                                [e.type for e in evs],
-                                [e.key for e in evs],
-                                [e.revision for e in evs],
-                                [e.object for e in evs],
-                                prev_revisions=None,
-                                txn=p.txn,
-                            )
+                            frames_by_kind[kind] = frames_mod.pack_frames(
+                                kind, evs, txn=p.txn)
             except Exception:  # noqa: BLE001 - degrade, never drop state
                 # flush-path failure (injected or real): this window falls
                 # back to per-event delivery of the SAME folded events — the
@@ -731,16 +729,18 @@ class Store:
                 frames_by_kind = {}
                 m.coalesce_fallbacks.inc()
                 sp.set(fallback=True)
+            n_frames = sum(len(fs) for fs in frames_by_kind.values())
+            m.watch_frames.inc(n_frames)
+            sp.set(frames=n_frames)
         for wkind, q, wants_frames in self._watchers:
             for kind, evs in by_kind.items():
                 if wkind is not None and wkind != kind:
                     continue
-                frame = frames_by_kind.get(kind) if wants_frames else None
-                if frame is not None:
-                    q.put(frame)
-                else:
-                    for ev in evs:
-                        q.put(ev)
+                # a kind's window leaves as frames of at most
+                # FRAME_MAX_ROWS rows, in order (one frame when it fits)
+                pieces = frames_by_kind.get(kind) if wants_frames else None
+                for item in pieces or evs:
+                    q.put(item)
 
     def _coalesce_loop(self) -> None:
         """Daemon flusher: parked until a window opens, then sleeps out
@@ -764,16 +764,19 @@ class Store:
 
     def _emit_many(self, events: list[WatchEvent],
                    prev_revisions: Optional[list[int]] = None,
-                   txn: Optional[str] = None) -> None:
+                   txn: Optional[str] = None) -> int:
         """Fan one correlated batch out: WAL + log stay per-event (the
         replay window and durability framing are unchanged), but every
-        frame-aware watcher receives ONE column-packed
-        :class:`~.frames.WatchFrame` — one queue put, one informer lock
-        hold, one handler fan-out for the whole txn.  Per-event watchers
-        (kubectl -w, controllers, pre-frame clients) see the identical
-        event sequence they always did."""
+        frame-aware watcher receives the txn column-packed — as
+        :class:`~.frames.WatchFrame` pieces of at most
+        ``frames.FRAME_MAX_ROWS`` rows, in revision order, all enqueued
+        here under the caller's store-lock hold (one frame, one queue
+        put, one informer lock hold when the txn fits the bound).
+        Per-event watchers (kubectl -w, controllers, pre-frame clients)
+        see the identical event sequence they always did.  Returns the
+        number of frames packed (0 when nobody wanted one)."""
         if not events:
-            return
+            return 0
         # ordering barrier: a batch txn fans out at commit, so anything
         # buffered in an open coalescing window must reach the queues
         # first — watchers see revisions in order, no fence violations
@@ -781,7 +784,7 @@ class Store:
         for ev in events:
             self._append_log(ev)
             self._replicate(ev)
-        frame = None
+        pieces: list = []
         from . import frames as frames_mod
 
         want_frame = len(events) > 1 and frames_mod.ENABLED
@@ -790,20 +793,16 @@ class Store:
             if wkind is not None and wkind != kind:
                 continue
             if wants_frames and want_frame:
-                if frame is None:  # built once, shared-immutable
-                    frame = frames_mod.WatchFrame(
-                        kind,
-                        [ev.type for ev in events],
-                        [ev.key for ev in events],
-                        [ev.revision for ev in events],
-                        [ev.object for ev in events],
-                        prev_revisions=prev_revisions,
-                        txn=txn,
-                    )
-                q.put(frame)
+                if not pieces:  # packed once, shared-immutable
+                    pieces = frames_mod.pack_frames(
+                        kind, events, prev_revisions=prev_revisions, txn=txn)
+                    DEFAULT_STORE_METRICS.watch_frames.inc(len(pieces))
+                for frame in pieces:
+                    q.put(frame)
             else:
                 for ev in events:
                     q.put(ev)
+        return len(pieces)
 
 
 class ExpiredRevisionError(Exception):

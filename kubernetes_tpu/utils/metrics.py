@@ -283,8 +283,9 @@ DEFAULT_CLIENT_METRICS = ClientMetrics()
 
 
 class StoreMetrics:
-    """Broadcaster-side observability (the serving tier): the
-    time-window coalescer's flushes, folds, and flush-path fallbacks.
+    """Broadcaster-side observability (the serving tier): the watch
+    frames packed, and the time-window coalescer's flushes, folds, and
+    flush-path fallbacks.
     The fault matrix asserts recovery through
     ``store_coalesce_fallbacks_total`` — a degraded window that is
     invisible here fails the test."""
@@ -304,6 +305,11 @@ class StoreMetrics:
             "store_coalesce_fallbacks_total",
             "coalescing windows degraded to per-event delivery after a "
             "flush-path failure (state preserved, packing lost)"))
+        self.watch_frames = r.register(Counter(
+            "store_watch_frames_total",
+            "watch frames packed by batch txns and coalescing flushes "
+            "(one per piece of at most frames.FRAME_MAX_ROWS rows, "
+            "however many watchers share it)"))
 
 
 # stores aggregate here (one broadcaster seam per process in practice);
@@ -400,7 +406,8 @@ class SchedulerMetrics:
         self.watch_frames = r.register(Counter(
             "scheduler_watch_frames_total",
             "column-packed watch frames applied by this scheduler's "
-            "informers (one per correlated store batch txn)",
+            "informers (a correlated store batch txn arrives as "
+            "ceil(events / frames.FRAME_MAX_ROWS) of them)",
         ))
         self.watch_frame_events = r.register(Counter(
             "scheduler_watch_frame_events_total",

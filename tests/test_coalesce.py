@@ -329,6 +329,51 @@ def test_synthetic_frames_honor_wire_and_cas_contract():
         store.close()
 
 
+@pytest.mark.parametrize("n_keys,want_lens", [(4, [4]), (9, [4, 4, 1]),
+                                              (12, [4, 4, 4])])
+def test_flush_over_the_frame_bound_leaves_as_pieces(monkeypatch, n_keys,
+                                                     want_lens):
+    """A window that closes over more keys than ``FRAME_MAX_ROWS``
+    flushes as pieces of at most that many rows (ISSUE 28), in revision
+    order under one txn id, ``prev_revisions`` honestly absent on every
+    piece — and an informer fed the pieces holds the per-event state."""
+    monkeypatch.setattr(frames_mod, "FRAME_MAX_ROWS", 4)
+    store = Store(coalesce_window_s=10.0)
+    ref = Store()
+    try:
+        w = store.watch("Pod", frames=True)
+        inf = SharedInformer(Clientset(store).pods)
+        ref_inf = SharedInformer(Clientset(ref).pods)
+        inf.start_manual()
+        ref_inf.start_manual()
+        script = [("create", i, 0) for i in range(n_keys)]
+        script += [("update", i, 1) for i in range(0, n_keys, 3)]  # folds
+        _apply_script(store, script)
+        _apply_script(ref, script)
+        c0 = DEFAULT_STORE_METRICS.watch_frames.value
+        store.flush_coalesced()
+        got = []
+        while (fr := w.get(timeout=0)) is not None:
+            got.append(fr)
+        assert [fr.type for fr in got] == ["FRAME"] * len(want_lens)
+        assert [len(fr) for fr in got] == want_lens
+        assert DEFAULT_STORE_METRICS.watch_frames.value - c0 == len(want_lens)
+        assert all(fr.prev_revisions is None for fr in got)
+        assert len({fr.txn for fr in got}) == 1
+        revs = [r for fr in got for r in fr.revisions]
+        assert revs == sorted(set(revs)) and len(revs) == n_keys
+        for fr in got:  # every piece is a first-class wire frame
+            WatchFrame.from_wire(json.loads(fr.wire_bytes()))
+        _drain(store, inf)
+        _drain(ref, ref_inf)
+        assert inf.stats["frames"] == len(want_lens)
+        assert _cache_view(inf) == _cache_view(ref_inf)
+        w.stop()
+    finally:
+        store.close()
+        ref.close()
+
+
 def test_shared_encode_one_encoding_per_revision():
     """The single-encode seam: with SHARED_ENCODE on, a frame (or
     event) serializes once and every watcher shares the SAME bytes

@@ -262,6 +262,45 @@ def test_end_to_end_txn_correlation():
                for t in create_txns)
 
 
+@pytest.mark.parametrize("op,n,frames", [
+    ("create_many", 9, 3), ("bind_many", 9, 3), ("coalesce_flush", 9, 3),
+    ("bind_many", 4, 1), ("bind_many", 1, 0)])
+def test_store_txn_span_counts_its_frames(monkeypatch, op, n, frames):
+    """``store.txn`` of a batch txn or a coalescing flush carries
+    ``frames``: the pieces of at most ``FRAME_MAX_ROWS`` rows it packed
+    (0 where nothing was framed: a one-event txn goes out as the event),
+    and ``store_watch_frames_total`` moves by the same number."""
+    from kubernetes_tpu.api import Binding
+    from kubernetes_tpu.store import frames as frames_mod
+    from kubernetes_tpu.utils.metrics import DEFAULT_STORE_METRICS
+
+    monkeypatch.setattr(frames_mod, "FRAME_MAX_ROWS", 4)
+    store = Store(coalesce_window_s=10.0 if op == "coalesce_flush" else 0.0)
+    cs = Clientset(store)
+    watch = store.watch("Pod", frames=True)
+    pods = [make_pod(f"p{i}", cpu="100m") for i in range(n)]
+    if op == "bind_many":
+        cs.pods.create_many(pods)
+    tr = tracing.enable()
+    c0 = DEFAULT_STORE_METRICS.watch_frames.value
+    if op == "create_many":
+        cs.pods.create_many(pods)
+    elif op == "bind_many":
+        cs.pods.bind_many([Binding(pod_namespace="default",
+                                   pod_name=p.meta.name, node_name="n0")
+                           for p in pods])
+    else:
+        for p in pods:
+            cs.pods.create(p)
+        store.flush_coalesced()
+    spans = [sp for sp in tr.background if sp.name == "store.txn"
+             and sp.attrs.get("op") == op]
+    assert len(spans) == 1 and spans[0].attrs["frames"] == frames
+    assert DEFAULT_STORE_METRICS.watch_frames.value - c0 == frames
+    watch.stop()
+    store.close()
+
+
 @pytest.mark.timeout(120)
 def test_chrome_export_validates_and_phases_derive_from_trace():
     tr = tracing.enable()

@@ -542,6 +542,232 @@ def test_event_batch_overflow_frames_exactly_the_admitted_events():
 
 
 # ---------------------------------------------------------------------------
+# bounded frames (ISSUE 28): a txn leaves in pieces of <= FRAME_MAX_ROWS
+# ---------------------------------------------------------------------------
+
+_BOUND = 4
+
+
+@pytest.fixture
+def small_bound(monkeypatch):
+    monkeypatch.setattr(frames_mod, "FRAME_MAX_ROWS", _BOUND)
+
+
+def _piece_lens(n, bound=_BOUND):
+    """Row counts of the pieces an n-row txn leaves in."""
+    return [min(bound, n - lo) for lo in range(0, n, bound)]
+
+
+def _take_now(watch):
+    """What the watcher's queue holds NOW — no waiting: a piece put
+    after the txn's call returned would be missing."""
+    out = []
+    while True:
+        ev = watch.get(timeout=0)
+        if ev is None:
+            return out
+        out.append(ev)
+
+
+@pytest.mark.parametrize("op", ["create_many", "bind_many"])
+@pytest.mark.parametrize("n", [2, 4, 5, 9, 12])
+def test_txn_leaves_in_pieces_of_at_most_the_bound(small_bound, op, n):
+    from kubernetes_tpu.utils.metrics import DEFAULT_STORE_METRICS
+
+    cs = Clientset(Store())
+    pods = [make_pod(f"p{i:03d}", cpu="100m") for i in range(n)]
+    pre = None
+    if op == "bind_many":  # the bind txn is under test: watch after these
+        pre = [c.meta.resource_version for c in cs.pods.create_many(pods)]
+    framed = cs.store.watch("Pod", frames=True)
+    second = cs.store.watch("Pod", frames=True)
+    plain = cs.store.watch("Pod")
+    c0 = DEFAULT_STORE_METRICS.watch_frames.value
+    if op == "create_many":
+        cs.pods.create_many(pods)
+    else:
+        assert cs.pods.bind_many([
+            Binding(pod_namespace="default", pod_name=p.meta.name,
+                    node_name=f"n{i % 2}")
+            for i, p in enumerate(pods)]) == [None] * n
+    # every piece is on the queue when the txn's call has returned
+    got = _take_now(framed)
+    assert all(g.type == FRAME for g in got)
+    assert [len(g) for g in got] == _piece_lens(n)
+    # one shared txn id, fences strictly increasing across the pieces
+    assert len({g.txn for g in got}) == 1 and got[0].txn.startswith(op)
+    fences = [g.revision for g in got]
+    assert fences == sorted(set(fences))
+    # concatenated, the pieces are the per-event sequence: order, content
+    assert _flatten(got) == _flatten(_take_now(plain))
+    # the prev-revision fence column is sliced like the others
+    if op == "bind_many":
+        assert [r for g in got for r in g.prev_revisions] == pre
+        assert all(len(g.prev_revisions) == len(g) for g in got)
+    else:
+        assert all(g.prev_revisions is None for g in got)
+    # the pieces are packed once and shared by every frames watcher, and
+    # the counter moves once per piece, not per watcher
+    shared = _take_now(second)
+    assert len(shared) == len(got) and all(a is b for a, b in zip(got, shared))
+    assert DEFAULT_STORE_METRICS.watch_frames.value - c0 == len(got)
+    if n <= _BOUND:
+        # at or under the bound: one frame, byte for byte the frame the
+        # txn has always been
+        evs = list(got[0].events())
+        whole = WatchFrame(
+            "Pod", [e.type for e in evs], [e.key for e in evs],
+            [e.revision for e in evs], [e.object for e in evs],
+            prev_revisions=pre,
+            txn=got[0].txn)
+        assert got[0].wire_bytes() == whole.wire_bytes()
+    for w in (framed, second, plain):
+        w.stop()
+
+
+def _queue_keys(sched):
+    return sorted(p.meta.key for p in sched.queue.snapshot_pending())
+
+
+@pytest.mark.parametrize("bound", [7, 49])
+def test_cut_confirm_wave_equals_uncut_wave(monkeypatch, bound):
+    """Informer → ``_on_pod_frame`` → ``confirm_many`` take a piece as
+    the frame it is: three 50-pod waves cut at ``bound`` (49: a one-row
+    remainder) leave the scheduler's cache and queue as the uncut waves
+    do, every entry confirmed by the columnar fence."""
+    cs_a, sched_a = _world()  # uncut: 50 <= FRAME_MAX_ROWS
+    for w in range(3):
+        assert _churn_wave(cs_a, sched_a, 50, f"w{w}") == (50, 0)
+    monkeypatch.setattr(frames_mod, "FRAME_MAX_ROWS", bound)
+    cs_b, sched_b = _world()
+    for w in range(3):
+        assert _churn_wave(cs_b, sched_b, 50, f"w{w}") == (50, 0)
+
+    bind_b = {p.meta.key: p.spec.node_name for p in cs_b.pods.list()[0]}
+    bind_a = {p.meta.key: p.spec.node_name for p in cs_a.pods.list()[0]}
+    assert bind_b == bind_a and all(bind_b.values())
+    assert _cache_fingerprint(sched_b.cache) == _cache_fingerprint(sched_a.cache)
+    assert _queue_keys(sched_b) == _queue_keys(sched_a) == []
+    assert sched_b.metrics.confirm_fallbacks.value == 0
+    # a create frame and a confirm frame per piece per wave; every event
+    assert sched_a.metrics.watch_frames.value == 3 * 2
+    assert (sched_b.metrics.watch_frames.value
+            == 3 * 2 * len(_piece_lens(50, bound)))
+    assert (sched_b.metrics.watch_frame_events.value
+            == sched_a.metrics.watch_frame_events.value == 3 * 2 * 50)
+
+
+@pytest.mark.parametrize("field_selector", [None, "spec.nodeName=n1"])
+def test_remote_pieces_with_and_without_a_field_selector(
+        api_server, small_bound, field_selector):
+    """Over HTTP a cut txn is ceil(n / bound) frame lines; behind a
+    field selector each piece is re-packed (``select``) as a frame was,
+    and the stream equals the per-event stream under the same selector."""
+    from kubernetes_tpu.client.remote import RemoteStore
+
+    rs = RemoteStore(api_server.url, retry_backoff=0.005)
+    cs = Clientset(api_server.store)
+    n = 10
+    cs.pods.create_many([make_pod(f"p{i:03d}", cpu="100m") for i in range(n)])
+    rev = api_server.store.list("Pod")[1]
+    wf = rs.watch("Pod", from_revision=rev, frames=True,
+                  field_selector=field_selector)
+    we = rs.watch("Pod", from_revision=rev, field_selector=field_selector)
+    assert _wait(lambda: wf._resp is not None and we._resp is not None)
+    cs.pods.bind_many([Binding(pod_namespace="default", pod_name=f"p{i:03d}",
+                               node_name=f"n{i % 2}") for i in range(n)])
+    # all ten rows, or the five on n1: pieces of 4, 4, 2 re-packed 2, 2, 1
+    want_rows = n if field_selector is None else n // 2
+    want_lens = [4, 4, 2] if field_selector is None else [2, 2, 1]
+    got = _drain(wf, len(want_lens), timeout=10.0)
+    plain = _drain(we, want_rows, timeout=10.0)
+    assert [g.type for g in got] == [FRAME] * len(want_lens)
+    assert [len(g) for g in got] == want_lens
+    assert len({g.txn for g in got}) == 1
+    assert all(len(g.prev_revisions) == len(g) for g in got)
+    assert len(plain) == want_rows and _flatten(got) == _flatten(plain)
+    if field_selector is not None:
+        assert {nn for g in got for nn in g.node_names} == {"n1"}
+    assert wf.get(timeout=0.2) is None  # nothing repeated, nothing more
+    wf.stop()
+    we.stop()
+
+
+@pytest.mark.parametrize("transport", ["store", "http"])
+def test_watch_resumed_between_two_pieces_loses_and_repeats_nothing(
+        api_server, small_bound, transport):
+    """A watch that ends after piece k resumes from piece k's fence
+    (``frame.revision``): the rest of the txn replays from the log, per
+    event, with no row lost and none repeated."""
+    from kubernetes_tpu.client.remote import RemoteStore
+
+    store = api_server.store
+    cs = Clientset(store)
+    rs = RemoteStore(api_server.url, retry_backoff=0.005)
+
+    def open_watch(from_rev):
+        if transport == "store":
+            return store.watch("Pod", from_revision=from_rev, frames=True)
+        w = rs.watch("Pod", from_revision=from_rev, frames=True)
+        assert _wait(lambda: w._resp is not None)
+        return w
+
+    n = 10
+    rev0 = store.list("Pod")[1]
+    first = open_watch(rev0)
+    plain = store.watch("Pod", from_revision=rev0)
+    cs.pods.create_many([make_pod(f"p{i:03d}", cpu="100m") for i in range(n)])
+    head = _drain(first, 2, timeout=10.0)[:2]  # pieces 1 and 2 of 3 ...
+    first.stop()                               # ... and the watch ends
+    assert [len(h) for h in head] == [4, 4]
+    resumed = open_watch(head[-1].revision)
+    tail = _drain(resumed, n - 8, timeout=10.0)
+    assert _flatten(head + tail) == _flatten(_drain(plain, n))
+    assert resumed.get(timeout=0.2) is None
+    resumed.stop()
+    plain.stop()
+
+
+def test_stream_timeout_does_not_fall_between_two_queued_pieces(
+        api_server, small_bound, monkeypatch):
+    """``timeoutSeconds`` runs out while a txn's pieces are going out: the
+    stream ends after the txn's last piece, not between two of them (the
+    resume would replay the rest per event), and takes nothing that
+    comes after the txn with it."""
+    import urllib.request
+
+    encode = WatchFrame.wire_bytes
+
+    def slow_encode(self):
+        _time.sleep(0.5)
+        return encode(self)
+
+    monkeypatch.setattr(WatchFrame, "wire_bytes", slow_encode)
+    cs = Clientset(api_server.store)
+    n = 12
+    cs.pods.create_many([make_pod(f"p{i:03d}", cpu="100m") for i in range(n)])
+    rev = api_server.store.list("Pod")[1]
+    watchers = len(api_server.store._watchers)
+    resp = urllib.request.urlopen(
+        f"{api_server.url}/api/v1/pods?watch=true&frames=1"
+        f"&timeoutSeconds=1&resourceVersion={rev}", timeout=10.0)
+    assert _wait(lambda: len(api_server.store._watchers) > watchers)
+    t0 = _time.monotonic()
+    cs.pods.bind_many([Binding(pod_namespace="default", pod_name=f"p{i:03d}",
+                               node_name=f"n{i % 2}") for i in range(n)])
+    # a second txn, queued behind the first before the deadline: past the
+    # deadline the stream takes the first txn's pieces and nothing else
+    cs.pods.create_many([make_pod(f"q{i}", cpu="100m") for i in range(2)])
+    lines = [json.loads(raw) for raw in resp if raw.strip()]  # to a clean end
+    assert _time.monotonic() - t0 >= 1.0
+    got = [WatchFrame.from_wire(d) for d in lines]
+    assert [len(g) for g in got] == _piece_lens(n)
+    assert len({g.txn for g in got}) == 1 and got[0].txn.startswith("bind_many")
+    assert [k for g in got for k in g.keys] == [
+        f"default/p{i:03d}" for i in range(n)]
+
+
+# ---------------------------------------------------------------------------
 # compaction: promote-and-drop-raw
 # ---------------------------------------------------------------------------
 
