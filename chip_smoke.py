@@ -8,11 +8,11 @@ The parent process NEVER imports jax: an accelerator belongs to one
 process at a time, so every leg runs as a child process, one after the
 other, each the only holder of the chip while it lives.
 
-- **drain**  — in process, the path ``bench.py run_once`` drives: Store →
+- **drain**  — in process: Store →
   Clientset → Scheduler + ``TPUBatchBackend()`` (default arguments) →
   ``schedule_pending_batch()`` over the north-star cluster (5,000 nodes ×
   150,000 mixed pods, ``BASELINE.json``), then the oracle replay of the
-  first 2,000 drain-order pods (``bench.run_prefix_parity``).
+  first 2,000 drain-order pods (``run_prefix_parity``).
 - **rungs**  — the fused Pallas rung and the first fallback rung (the XLA
   device loop) schedule the same 5,000 × 20,000 cluster in one child;
   bindings and the round-robin tie counter must be equal pod for pod.
@@ -209,14 +209,14 @@ def _check_rung(stats: dict, rung: str, what: str) -> None:
 
 
 def _mixed_workload(n_nodes: int, n_pods: int, seed: int) -> tuple:
-    """bench.py's mixed workload: (nodes, services, pods) from ``seed``."""
+    """``testutil``'s mixed workload: (nodes, services, pods) from ``seed``."""
     import random
 
-    import bench
+    from kubernetes_tpu.testutil import make_nodes, make_pods, make_services
 
     rng = random.Random(seed)
-    return (bench.make_nodes(n_nodes, rng, "mixed"), bench.make_services(),
-            bench.make_pods(n_pods, rng, "mixed"))
+    return (make_nodes(n_nodes, rng, "mixed"), make_services(),
+            make_pods(n_pods, rng, "mixed"))
 
 
 def _odd_request_workload(n_nodes: int, n_pods: int, seed: int) -> tuple:
@@ -244,8 +244,8 @@ def _odd_request_workload(n_nodes: int, n_pods: int, seed: int) -> tuple:
 
 
 def _schedule_cluster(workload: tuple, **backend_kw) -> dict:
-    """One batch drain through the normal entry points (the path
-    ``bench.run_once`` drives), with the backend's own account of it."""
+    """One batch drain through the normal entry points, with the
+    backend's own account of it."""
     from kubernetes_tpu.client import Clientset
     from kubernetes_tpu.ops import TPUBatchBackend
     from kubernetes_tpu.scheduler import GenericScheduler, Scheduler
@@ -290,9 +290,53 @@ def _schedule_cluster(workload: tuple, **backend_kw) -> dict:
     }
 
 
-def leg_drain(n_nodes: int, n_pods: int, seed: int, expect: Expect) -> dict:
-    import bench
+PREFIX_PARITY_K = 2_000
 
+
+def run_prefix_parity(backend_res: dict, n_nodes: int, n_pods: int,
+                      seed: int, k: int = PREFIX_PARITY_K) -> dict:
+    """At-scale parity certification without at-scale oracle cost.
+
+    Sequential-greedy is prefix-closed: pod i's placement depends only on
+    the initial cluster and the pods scheduled before it (pending pods
+    never influence predicates or priorities — only scheduled pods do).
+    So the oracle replayed over just the FIRST ``k`` pods of the batch,
+    in batch order, must match the kernel's first ``k`` assignments
+    binding-for-binding.  This is exact, not statistical.
+
+    ``backend_res`` is ``_schedule_cluster``'s result over
+    ``_mixed_workload(n_nodes, n_pods, seed)``.  Batch order is its
+    RECORDED queue-drain order, not creation order (the queue is fed from
+    the store's name-sorted LIST).  A replay cluster holding exactly
+    those ``k`` pods queues them in the same relative order — a
+    restriction of a sorted sequence is sorted — so the oracle's ``k``
+    decisions are directly comparable.
+    """
+    from kubernetes_tpu.client import Clientset
+    from kubernetes_tpu.scheduler import GenericScheduler, Scheduler
+    from kubernetes_tpu.store import Store
+
+    nodes, services, pods = _mixed_workload(n_nodes, n_pods, seed)
+    cs = Clientset(Store(event_log_window=max(200_000, 2 * (n_nodes + k))))
+    for node in nodes:
+        cs.nodes.create(node)
+    for svc in services:
+        cs.services.create(svc)
+    pods_by_key = {p.meta.key: p for p in pods}
+    for key in backend_res["batch_order"][:k]:
+        cs.pods.create(pods_by_key[key])
+    sched = Scheduler(cs, algorithm=GenericScheduler(), backend=None)
+    sched.start()
+    sched.run_pending()
+    replayed, _ = cs.pods.list()
+    o = {p.meta.key: p.spec.node_name or None for p in replayed}
+    b = backend_res["assignments"]
+    mismatches = [(key, o[key], b.get(key)) for key in o if o[key] != b.get(key)]
+    return {"checked": len(o), "mismatches": len(mismatches),
+            "sample": mismatches[:5]}
+
+
+def leg_drain(n_nodes: int, n_pods: int, seed: int, expect: Expect) -> dict:
     report = {"leg": "drain", "nodes": n_nodes, "pods": n_pods, "seed": seed,
               "device": _device_report(expect), "natives": _natives()}
     with _CompileWatch() as watch:
@@ -307,10 +351,9 @@ def leg_drain(n_nodes: int, n_pods: int, seed: int, expect: Expect) -> dict:
              f"!= {n_pods} pods")
     _require(res["bound"] > 0, "drain: nothing was bound")
     _check_rung(res["stats"], expect.rung, "drain")
-    parity = bench.run_prefix_parity(res, n_nodes, n_pods, "mixed", seed)
-    report["prefix_parity"] = {k: parity[k] for k in
-                               ("checked", "mismatches", "sample")}
-    _require(parity["checked"] == min(bench.PREFIX_PARITY_K, n_pods)
+    parity = run_prefix_parity(res, n_nodes, n_pods, seed)
+    report["prefix_parity"] = parity
+    _require(parity["checked"] == min(PREFIX_PARITY_K, n_pods)
              and parity["mismatches"] == 0,
              f"drain: prefix parity {parity['mismatches']} mismatches of "
              f"{parity['checked']}: {parity['sample']}")
@@ -464,9 +507,6 @@ def leg_serve(n_nodes: int, n_pods: int, waves: int, seed: int,
               expect: Expect, log_dir: str, timeout: float = 600.0) -> dict:
     """This process stays off JAX: the scheduler daemon is the only
     process of the leg that touches the accelerator."""
-    import random
-
-    import bench
     from kubernetes_tpu.client import Clientset
     from kubernetes_tpu.client.remote import RemoteStore
     from kubernetes_tpu.utils.platform import compile_cache_dir
@@ -505,13 +545,11 @@ def leg_serve(n_nodes: int, n_pods: int, waves: int, seed: int,
 
         remote = RemoteStore(api_url)
         cs = Clientset(remote)
-        rng = random.Random(seed)
-        nodes = bench.make_nodes(n_nodes, rng, "mixed")
+        nodes, services, pods = _mixed_workload(n_nodes, n_pods, seed)
         for i in range(0, len(nodes), 1_000):
             cs.nodes.create_many_nowait(nodes[i:i + 1_000])
-        for svc in bench.make_services():
+        for svc in services:
             cs.services.create(svc)
-        pods = bench.make_pods(n_pods, rng, "mixed")
         per_wave = -(-n_pods // waves)
 
         def alive() -> None:

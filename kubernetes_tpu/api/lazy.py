@@ -33,9 +33,9 @@ north-preset A/B in ``units.pod_request_vec`` showed per-pod caches cost
 more in GC than they save; everything here memoizes by *content*, whose
 vocabulary is tiny under template-stamped churn).
 
-``ENABLED`` is the A/B seam: ``bench.py --ab-pump`` flips it to measure
-lazy vs eager ingest on the same harness; the eager arm never constructs
-a lazy object, so every fast path degrades to the status quo.
+``ENABLED = False`` is the reference arm of ``tests/test_lazy.py``: the
+eager side never constructs a lazy object, and the lazy side must ingest
+to the same state.
 """
 
 from __future__ import annotations
@@ -46,12 +46,12 @@ from typing import Optional
 from .meta import ObjectMeta, OwnerReference
 from . import types as api
 
-# module seam for the ingest A/B (bench.py --ab-pump): False restores
-# eager per-event from_dict everywhere
+# False restores eager per-event from_dict everywhere (a test's
+# reference arm)
 ENABLED = True
 
-# decode observability (read by the scheduler's per-wave phase accounting
-# and the churn bench).  Plain ints bumped on the toucher's thread: the
+# decode observability (read by the scheduler's per-wave phase
+# accounting).  Plain ints bumped on the toucher's thread: the
 # counters are telemetry, and a lost increment under thread interleaving
 # is acceptable where a per-promotion lock round is not.
 STATS = {"promotions": 0, "sections": 0, "wrapped": 0}

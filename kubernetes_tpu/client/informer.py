@@ -83,7 +83,7 @@ class SharedInformer:
         self.last_revision = 0
         self.metrics = metrics or DEFAULT_CLIENT_METRICS
         # per-instance recovery audit trail (the fault matrix reads this)
-        # + ingest-decode observability (the churn bench deltas decode_s
+        # + ingest-decode observability (the scheduler deltas decode_s
         # per wave; decode_errors is the informer.decode recovery signal)
         self.stats = {"relists": 0, "dropped_events": 0, "handler_errors": 0,
                       "relist_failures": 0, "decode_errors": 0,

@@ -971,7 +971,7 @@ class HollowWatcher:
     event/frame duck types: the in-process ``Store.watch`` queue or a
     ``RemoteWatch`` HTTP stream.  Applies the same revision fence as
     ``SharedInformer`` (stale deliveries skipped), so its final cache is
-    exactly the state-equivalence surface the fleet bench gates on."""
+    exactly the state-equivalence surface the fleet harness gates on."""
 
     __slots__ = ("id", "watch", "cache", "applied_rev", "deliveries",
                  "event_units", "gaps", "tracker")
@@ -1019,7 +1019,7 @@ class HollowWatcher:
                 self.event_units += len(item.keys)
             elif t == WATCH_GAP:
                 # continuity lost (410 analogue): a hollow watcher has no
-                # lister to rebuild from — count it; the fleet bench
+                # lister to rebuild from — count it; the fleet harness
                 # treats any gapped client as dropped-state
                 self.gaps += 1
             else:
@@ -1046,7 +1046,7 @@ class HollowWatcher:
 
 class HollowWatcherFleet:
     """N hollow watchers on one watch source — the many-client axis of
-    the serving-tier bench.  ``source`` is anything with
+    the serving tier.  ``source`` is anything with
     ``watch(kind, frames=...)`` (a ``Store`` or a ``RemoteStore``); the
     caller drives ``pump_all`` from however many threads it wants (the
     watchers are partitionable by slice — no shared mutable state

@@ -178,16 +178,9 @@ class TPUBatchBackend:
         # back to the chunked host loop (same carry plane), then to the
         # full-width scan; the breaker is never involved.
         frontier_device_loop: bool = True,
-        # chunked still_ok mode engages when the prefilter's alive
-        # fraction is at or below this.  Default 1.0 = always chunk when
-        # the segment is big enough: measured on the north churn preset
-        # the chunked scan is FASTER than the single monolithic scan even
-        # with zero compactions (3/3 interleaved runs), so the knob
-        # exists for experiments, not as a cost gate.
-        frontier_engage_frac: float = 1.0,
         # Node-axis mesh (the shard_map wave loop): "auto" engages only
         # on a real multi-device accelerator platform — forced host
-        # devices (tests/bench) opt in with True; False disables.  When
+        # devices (tests) opt in with True; False disables.  When
         # on, the device loop runs under shard_map over a 1-D mesh
         # partitioning the node axis; the in-loop reductions become
         # cross-shard collectives and the host-sync budget stays
@@ -226,7 +219,6 @@ class TPUBatchBackend:
         self.frontier_chunk = frontier_chunk
         self.frontier_compact_frac = frontier_compact_frac
         self.frontier_min_width = frontier_min_width
-        self.frontier_engage_frac = frontier_engage_frac
         self.frontier_device_loop = frontier_device_loop
         self.frontier_mesh = frontier_mesh
         self.mesh_devices = mesh_devices
@@ -243,7 +235,8 @@ class TPUBatchBackend:
         # wired to scheduler_score_plane_sheds_total
         self.shed_counter = None
         # per-batch frontier trajectory: one entry per frontier segment
-        # ({"widths": [...], "alive_frac": [...], ...}); bench snapshots it
+        # ({"widths": [...], "alive_frac": [...], ...}); the wave span and
+        # chip_smoke.py read it
         self.last_frontier: list = []
         self.stats = {"kernel_pods": 0, "place_batched_pods": 0,
                       "oracle_pods": 0, "segments": 0,
@@ -270,7 +263,7 @@ class TPUBatchBackend:
                       "host_syncs": 0,
                       # steady-state phase timers (seconds, cumulative):
                       # host tensorize, device dispatch, device wait
-                      # (finalize block) — bench deltas these per wave
+                      # (finalize block) — the scheduler deltas these per wave
                       "tensorize_s": 0.0, "dispatch_s": 0.0,
                       "device_wait_s": 0.0}
         self._clock_wall = time.perf_counter
@@ -378,7 +371,7 @@ class TPUBatchBackend:
     def _mesh_enabled(self) -> bool:
         if self.frontier_mesh == "auto":
             # auto: only a real accelerator mesh is worth the collectives
-            # (forced host devices are a test/bench construct — those
+            # (forced host devices are a test construct — those
             # callers pass frontier_mesh=True explicitly)
             import jax
 
@@ -445,13 +438,11 @@ class TPUBatchBackend:
                 js = np.nonzero(alive)[0]
                 cstatic, cinit = compact_segment(static, init, js, width)
                 self.stats["frontier_prefilter_cols"] += static.n_pad - width
-            # chunked still_ok mode only when the axis is actually dying
-            # (otherwise the carry plane + per-chunk syncs cost scan time
-            # and no compaction can ever trigger); a mostly-alive fleet
-            # takes the prefilter (if it cut anything) + the plain scan
+            # chunked still_ok mode whenever the segment is big enough to
+            # chunk and wide enough to compact; a smaller one takes the
+            # prefilter (if it cut anything) + the plain scan
             chunked = (len(cstatic.group_of_pod) > self.frontier_chunk
-                       and cstatic.n_pad > self.frontier_min_width
-                       and n_alive <= self.frontier_engage_frac * static.n_pad)
+                       and cstatic.n_pad > self.frontier_min_width)
             if not chunked:
                 if cstatic is static:
                     return None  # nothing to prune, nothing to watch

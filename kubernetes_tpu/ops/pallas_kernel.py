@@ -201,7 +201,7 @@ def _pack(static: BatchStatic, init: InitialState):
         np.array([init.round_robin], dtype=np.int32),
         gids,
     )
-    return scalars, tuple(ins), p_pad
+    return scalars, tuple(ins)
 
 
 @lru_cache(maxsize=64)
@@ -218,7 +218,6 @@ def _pallas_runner(
     weights: tuple,
     use_terms: bool,
     use_vols: bool,
-    k_unroll: int = 1,
 ):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -315,15 +314,7 @@ def _pallas_runner(
                 off *= 2
             return x
 
-        def body(i, rr, step_valid=None):
-            # ``step_valid`` (trace-time None = unconditionally valid):
-            # the super-step loop (k_unroll > 1) runs fixed K sub-steps
-            # per iteration, so tail sub-steps past p_real execute with
-            # step_valid=False — they commit nothing and never bump rr,
-            # keeping the arithmetic stream identical to the K=1 program.
-            # (NB the name: the volume-slot loop below binds a local
-            # ``valid`` — the per-slot validity bit — which must not
-            # shadow this parameter.)
+        def body(i, rr):
             gid = gids_ref[i]
             e_gid = (giota == gid).astype(jnp.float32)  # [G, 1]
 
@@ -536,14 +527,10 @@ def _pallas_runner(
                 jnp.int32(-1),
                 jnp.where(n_feasible == 1, only, pick_among).astype(jnp.int32),
             )
-            if step_valid is None:
-                rr_new = rr + (n_feasible >= 2).astype(jnp.int32)
-            else:
-                rr_new = rr + ((n_feasible >= 2) & step_valid).astype(jnp.int32)
+            rr_new = rr + (n_feasible >= 2).astype(jnp.int32)
 
             # ---- commit ----
-            landed = (chosen >= 0) if step_valid is None \
-                else (chosen >= 0) & step_valid
+            landed = chosen >= 0
             safe = jnp.maximum(chosen, 0)
             oh = ((lane == safe) & landed).astype(jnp.int32)  # [1, N]
             req_s[:] = req_s[:] + g_req_c * oh
@@ -594,27 +581,7 @@ def _pallas_runner(
             chosen_out[pl.ds(row_i, 1), :] = jnp.where(lane128 == col_i, chosen, crow)
             return rr_new
 
-        if k_unroll <= 1:
-            rr_final = jax.lax.fori_loop(0, p_real_ref[0], body, rr0_ref[0])
-        else:
-            # super-steps (SURVEY §7.4.1): K sequential sub-steps per loop
-            # iteration.  Same dependent chain per pod, but Mosaic gets a
-            # K×-larger straightline window to overlap pod i+1's gathers
-            # and static reads with pod i's commit, and pays the loop
-            # bookkeeping once per K pods.  k_unroll divides p_pad (both
-            # powers of two), so sub-step indices never exceed the arrays;
-            # tail sub-steps carry valid=False and are inert.
-            p_real = p_real_ref[0]
-            n_iters = (p_real + (k_unroll - 1)) // k_unroll
-
-            def super_body(io, rr):
-                base = io * k_unroll
-                for kk in range(k_unroll):
-                    i = base + kk
-                    rr = body(i, rr, step_valid=i < p_real)
-                return rr
-
-            rr_final = jax.lax.fori_loop(0, n_iters, super_body, rr0_ref[0])
+        rr_final = jax.lax.fori_loop(0, p_real_ref[0], body, rr0_ref[0])
         rr_out[0, 0] = rr_final
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -656,24 +623,6 @@ def _pallas_runner(
     return jax.jit(fn)
 
 
-def _superstep_k() -> int:
-    """Sub-steps per kernel loop iteration: the PallasSuperSteps gate
-    picks the default (8); ``KTPU_SUPERSTEP_K`` overrides for tuning.
-    Must divide 128 (the p_pad granule) — enforced by rounding down to a
-    power of two."""
-    import os
-
-    from ..utils.features import DEFAULT_FEATURE_GATES
-
-    if not DEFAULT_FEATURE_GATES.enabled("PallasSuperSteps"):
-        return 1
-    k = int(os.environ.get("KTPU_SUPERSTEP_K", "8"))
-    k = max(1, min(128, k))
-    while k & (k - 1):
-        k -= 1
-    return k
-
-
 def schedule_batch_pallas(static: BatchStatic, init: InitialState):
     """Drop-in replacement for ``schedule_batch_arrays`` on TPU."""
     chosen2d, rr = dispatch_batch_pallas(static, init)
@@ -681,7 +630,7 @@ def schedule_batch_pallas(static: BatchStatic, init: InitialState):
 
 
 def shape_key(static: BatchStatic) -> tuple:
-    """The compiled-program identity for ``static`` — the same key
+    """The compiled-program identity for ``static`` — the argument tuple
     ``_pallas_runner`` caches compiles on (dims + weights + structure
     flags), so a fallback-blacklist entry maps 1:1 to one compilation
     unit (backend.py's per-shape fallback: one bad shape must not take
@@ -699,7 +648,6 @@ def shape_key(static: BatchStatic) -> tuple:
         tuple(int(static.weights.get(kk, 0)) for kk in WEIGHT_KEYS),
         bool(static.terms),
         bool(static.use_vols),
-        _superstep_k(),
     )
 
 
@@ -709,26 +657,11 @@ def dispatch_batch_pallas(static: BatchStatic, init: InitialState):
     tr = tracing.current()
     with (tr.span("dispatch.pack", cat="phase")
           if tr is not None else tracing.NULL_SPAN):
-        scalars, ins, p_pad = _pack(static, init)
-    weights = tuple(int(static.weights.get(kk, 0)) for kk in WEIGHT_KEYS)
+        scalars, ins = _pack(static, init)
     with (tr.span("dispatch.launch", cat="phase")
           if tr is not None else tracing.NULL_SPAN) as sp:
         # device: static — grid/shape keys are BatchStatic fields, frozen per segment build
-        run = _pallas_runner(
-            static.n_pad,
-            static.static_ok.shape[0],
-            static.term_matches_sig.shape[0],
-            static.g_ports.shape[1],
-            static.v_state,
-            static.node_alloc.shape[1],
-            static.pod_vol_ids.shape[1],
-            p_pad,
-            int(static.num_zones),
-            weights,
-            bool(static.terms),
-            bool(static.use_vols),
-            _superstep_k(),
-        )
+        run = _pallas_runner(*shape_key(static))
         if tr is not None:
             sp.set(upload_bytes=sum(a.nbytes for a in (*scalars, *ins)))
             compiled = getattr(run, "_cache_size", None)

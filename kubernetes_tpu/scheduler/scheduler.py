@@ -118,15 +118,15 @@ class Scheduler:
         # steady-state pipeline: overlap the next wave's ingest (pump +
         # signature warming) with the current wave's device execution —
         # the cross-wave extension of the per-segment commit overlap.
-        # False restores the lock-step behavior (the A/B seam).
+        # False restores the lock-step behavior (the reference arm of
+        # tests/test_pipeline.py).
         self.overlap_ingest = True
         self._last_prep_s = 0.0
-        # per-wave phase split of the last schedule_pending_batch call
-        # (bench.py's churn preset reports these per wave).  With tracing
-        # enabled the tensorize/dispatch/device_wait/commit/prep keys are
-        # DERIVED from the wave's span tree (same clock reads — the two
-        # cannot disagree); disabled, they come from the backend's stats
-        # deltas as before.
+        # per-wave phase split of the last schedule_pending_batch call.
+        # With tracing enabled the tensorize/dispatch/device_wait/commit/
+        # prep keys are DERIVED from the wave's span tree (same clock
+        # reads — the two cannot disagree); disabled, they come from the
+        # backend's stats deltas.
         self.last_batch_phases: dict = {}
         # attrs the batch loop stamps onto the NEXT wave's root span
         # (queue wait / accumulation window measured before the drain)
@@ -260,7 +260,7 @@ class Scheduler:
                 self._on_pod_delete(old if old is not None else new)
 
     def start(self, manual: bool = True) -> None:
-        """Seed informers.  manual=True (tests, bench) → caller pumps and
+        """Seed informers.  manual=True (tests, benchmark) → caller pumps and
         events drain via ``broadcaster.flush()``; manual=False → informer
         threads run the watch loops and the event sink thread runs."""
         if manual:
@@ -284,7 +284,7 @@ class Scheduler:
     def _ingest_decode_stats(self) -> tuple[float, int]:
         """(cumulative informer decode seconds, cumulative lazy
         promotions) across this scheduler's informers — per-wave deltas
-        feed ``scheduler_ingest_decode_seconds`` and the churn bench."""
+        feed ``scheduler_ingest_decode_seconds``."""
         from ..api import lazy as lazy_mod
 
         decode_s = sum(
@@ -296,8 +296,7 @@ class Scheduler:
     def _pump_apply_stats(self) -> tuple[float, int, int]:
         """(cumulative pump-application seconds, frames, frame events)
         across this scheduler's informers — per-wave deltas feed
-        ``scheduler_pump_apply_seconds`` and the churn bench's
-        pump-apply timers (ISSUE 6)."""
+        ``scheduler_pump_apply_seconds`` (ISSUE 6)."""
         apply_s = frames = frame_events = 0
         for inf in self.informers._informers.values():
             st = inf.stats
@@ -961,8 +960,8 @@ class Scheduler:
                 tr.complete("commit", t_commit, t_commit_end, cat="phase",
                             pods=len(entries), bound=len(finished))
 
-        # phase accounting for the churn bench: deltas of the backend's
-        # cumulative timers bracket this batch's tensorize/device split
+        # phase accounting: deltas of the backend's cumulative timers
+        # bracket this batch's tensorize/device split
         bstats = getattr(self.backend, "stats", None)
         phase_keys = ("tensorize_s", "dispatch_s", "device_wait_s")
         pre_phases = ({k: bstats.get(k, 0.0) for k in phase_keys}
@@ -1057,8 +1056,7 @@ class Scheduler:
                 if wave_span is not None:
                     wave_span.set(host_syncs=wave_syncs)
             # ingest-decode split of the wave (ISSUE 4): informer decode
-            # seconds + lazy promotions since the last snapshot — the
-            # churn bench's pump-phase companion timers
+            # seconds + lazy promotions since the last snapshot
             post_decode = self._ingest_decode_stats()
             decode_s = post_decode[0] - pre_decode[0]
             promos = post_decode[1] - pre_decode[1]
@@ -1096,7 +1094,7 @@ class Scheduler:
                         wave_span.set(dirty_cols=dirty, cols_total=cols,
                                       upload_fraction=round(dirty / cols, 4))
             # frontier trajectory of this wave (per-segment prefilter
-            # widths, alive-union fractions, compactions) for the bench
+            # widths, alive-union fractions, compactions)
             lf = getattr(self.backend, "last_frontier", None)
             if lf:
                 self.last_batch_phases["frontier"] = [dict(seg) for seg in lf]

@@ -26,7 +26,7 @@ cold seed.  ``Store.list_columns`` instead emits ONE batch:
   only needs keys + signatures never pays for them.
 
 The dict path (``Store.list`` + eager ``from_dict``) stays untouched as
-the compatibility oracle; ``bench.py --ab-pump`` A/Bs the two.
+the compatibility oracle.
 """
 
 from __future__ import annotations

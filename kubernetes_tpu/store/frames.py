@@ -32,24 +32,24 @@ watcher** (``Store.watch(..., frames=True)``), the apiserver serves them
 only to ``?frames=1`` clients (per-event JSON lines otherwise), and
 ``events()`` expands a frame back into the exact per-event sequence.
 
-``ENABLED`` is the A/B seam: ``bench.py --ab-watch`` flips it to measure
-framed vs per-event delivery on the same harness.
+``ENABLED = False`` is the reference arm of ``tests/test_watch_frames.py``:
+per-event delivery, which framed delivery must leave every consumer's
+state equal to.
 """
 
 from __future__ import annotations
 
 from typing import Iterator, Optional
 
-# module seam for the watch-frame A/B (bench.py --ab-watch): False
-# restores per-event delivery everywhere (frame-aware consumers stay
-# dormant — they only ever see plain WatchEvents)
+# False restores per-event delivery everywhere (frame-aware consumers
+# stay dormant — they only ever see plain WatchEvents); a test's
+# reference arm
 ENABLED = True
 
-# module seam for the single-encode fan-out A/B (bench.py --watch-fleet):
-# True (default) serializes each frame/event wire payload ONCE and shares
-# the encoded bytes across every HTTP watcher streaming it; False
-# restores the pre-serving-tier shape where every client pays its own
-# json.dumps per delivery.
+# True serializes each frame/event wire payload ONCE and shares the
+# encoded bytes across every HTTP watcher streaming it; False, every
+# client pays its own json.dumps per delivery (arm A of
+# tests/watch_fleet_harness.py)
 SHARED_ENCODE = True
 
 # WatchFrame.type value: a transport framing marker, not a state

@@ -283,7 +283,7 @@ class Store:
             # like update(): the event copy doubles as the caller's return
             # value — both are read-only by contract, and the stored dict
             # never escapes.  One deepcopy per create, not two (the create
-            # flood is the churn bench's arrival path).
+            # flood is the arrival path).
             return ev_copy
 
     def create_many(self, kind: str, objs: list[dict],

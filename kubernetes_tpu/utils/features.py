@@ -33,13 +33,6 @@ KNOWN_FEATURES: dict[str, tuple[bool, str]] = {
     "PodPreset": (True, ALPHA),
     "TPUBatchScheduling": (True, BETA),  # the batch backend itself
     "PallasKernels": (True, BETA),  # fused kernel vs XLA scan
-    # K sequential sub-steps per kernel loop iteration (SURVEY §7.4.1
-    # "small sequential super-steps"): identical arithmetic order, fewer
-    # loop iterations.  Default OFF: measured NEUTRAL-to-negative on
-    # v5e (the step is bound by its dependent VPU chain, not loop
-    # bookkeeping) while costing 10-45s extra compile per shape — see
-    # BENCH_AB_supersteps.json for the recorded K sweep
-    "PallasSuperSteps": (False, ALPHA),
     "DynamicKindRegistration": (True, BETA),  # CRDs
     "ExperimentalCriticalPodAnnotation": (False, ALPHA),
     "DynamicKubeletConfig": (False, ALPHA),  # kubelet config from the API

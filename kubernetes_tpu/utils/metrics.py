@@ -15,10 +15,10 @@ from typing import Optional
 
 # reference metrics.go shape: 1ms .. ~1000s exponential (in microseconds),
 # at 2^(1/4) steps — 80 buckets instead of the reference's 20, so a
-# reported quantile's upper bound is within ~19% of the true value (the
-# bench's SLI block reads these).  At sqrt(2) steps the >8s buckets were
-# ~3.4s wide and adjacent segment commits of a north drain could land in
-# ONE bucket, collapsing p50 and p99 to the same boundary.
+# reported quantile's upper bound is within ~19% of the true value.  At
+# sqrt(2) steps the >8s buckets were ~3.4s wide and adjacent segment
+# commits of a north drain could land in ONE bucket, collapsing p50 and
+# p99 to the same boundary.
 _DEFAULT_BUCKETS = [1e3 * (2 ** (i / 4)) for i in range(80)]
 
 
@@ -313,7 +313,7 @@ class StoreMetrics:
 
 
 # stores aggregate here (one broadcaster seam per process in practice);
-# the fleet bench scrapes this registry alongside the client one
+# tests/watch_fleet_harness.py reads this registry alongside the client one
 DEFAULT_STORE_METRICS = StoreMetrics()
 
 

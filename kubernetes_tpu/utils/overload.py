@@ -180,7 +180,7 @@ class DegradationLadder:
         self._mu = threading.Lock()
         self._breached: set[str] = set()
         self._last_transition_at: Optional[float] = None
-        # (t, rung) per transition — the bench's rung timeline.
+        # (t, rung) per transition — the rung timeline.
         self._history: list[tuple[float, int]] = []
 
     # -- wiring ------------------------------------------------------------

@@ -6,8 +6,8 @@ run in has no accelerator, and a virtual N-device CPU platform
 (``--xla_force_host_platform_device_count``) is what lets the mesh tests
 execute the sharded paths there.  A ``jax_platforms`` config value
 outranks the ``JAX_PLATFORMS`` env var, so forcing must happen before
-jax initializes AND override the config.  Shared by ``tests/conftest.py``,
-``__graft_entry__.dryrun_multichip`` and ``bench.py --multichip``.
+jax initializes AND override the config.  Shared by ``tests/conftest.py``
+and ``__graft_entry__.dryrun_multichip``.
 """
 
 from __future__ import annotations

@@ -133,7 +133,6 @@ class SLO:
 
 #: the pipeline's standing SLOs, over metrics ``SchedulerMetrics`` /
 #: ``ClientMetrics`` register (names resolved statically by MN405).
-#: The latency threshold matches the bench churn gate (5 s e2e p99).
 DEFAULT_SLOS = [
     SLO(name="wave_e2e_latency_p99",
         sli=QuantileSLI(
@@ -158,7 +157,7 @@ def serving_slos(worst_lag_revisions: float = 500.0) -> list[SLO]:
     cluster-wide gap ratio.  A GaugeSLI for the same reason as
     ``mesh_slos()``: it keeps producing samples (and can recover) while
     churn idles; ``worst_lag_revisions`` is the lag the budget is graded
-    against (the bench compresses it along with the windows)."""
+    against (the fleet harness compresses it along with the windows)."""
     return [
         SLO(name="watch_fanout_worst_client_staleness",
             sli=GaugeSLI(

@@ -53,7 +53,7 @@ def enable(registry: Registry, interval_s: float = 1.0, capacity: int = 600,
 
     ``clock`` is injectable for deterministic tests; ``start_thread=False``
     leaves sampling to explicit :meth:`TimeSeriesStore.sample_once` calls
-    (tests, and the bench's synchronous mode)."""
+    (tests)."""
     global _ACTIVE
     disable()
     store = TimeSeriesStore(registry, interval_s=interval_s,

@@ -26,7 +26,7 @@ PAPERS.md):
   the kernel circuit breaker transitions (:func:`notify_breaker`), or a
   bind requeues (:func:`notify_requeue`);
 - **Chrome trace-event export** (:meth:`Tracer.chrome_trace`): load the
-  JSON from ``/debug/traces``, ``bench.py --trace``, or a flight dump
+  JSON from ``/debug/traces`` or a flight dump
   into ``chrome://tracing`` / Perfetto.
 
 Disabled (the default, and the only production state until enabled) the

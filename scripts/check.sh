@@ -2,9 +2,10 @@
 # Local CI gate, in the order CI runs it:
 #   1. ktpu-analyze — all seven passes over the live tree; exits 1 on
 #      any unbaselined finding, 2 on config/baseline errors.
-#   2. check_ledgers — evidence-integrity gate: every BENCH_AB_*.json
-#      cited by README/CHANGES/COVERAGE/ROADMAP or bench.py must exist
-#      in the tree (demote with "never committed" on the citing line).
+#   2. check_ledgers — evidence-integrity gate: every BENCH_*.json /
+#      MULTICHIP_*.json cited by README/CHANGES/COVERAGE/ROADMAP/VERDICT
+#      must exist in the tree (demote with "never committed" on the
+#      citing line).
 #   3. the tier-1 analyzer gate tests (fixture pins + live-tree-clean +
 #      wall-time budget), so a pass regression fails even when the live
 #      tree happens to be clean.

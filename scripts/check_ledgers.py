@@ -1,27 +1,17 @@
 #!/usr/bin/env python3
-"""Evidence-integrity gate: every ``BENCH_AB_*.json`` /
-``MULTICHIP_*.json`` ledger the record cites must exist in the tree.
+"""Evidence-integrity gate: every ``BENCH_*.json`` / ``MULTICHIP_*.json``
+record the prose cites must exist in the tree.
 
 The ROADMAP carried the failure mode for four PRs: README/CHANGES/
 COVERAGE cited worktree ledgers (``BENCH_AB_device_loop.json``,
 ``BENCH_AB_watch_frames.json``) that no commit ever added — the perf
-record overstated its own evidence, and nothing failed.  The bench.py
-guard only refuses to PRINT medians without a ledger on disk; it cannot
-force the file into the commit.  This gate closes the loop: scan the
-prose record and bench.py for ledger names and exit 1, listing every
-offender as ``path:line``, when a cited ledger is absent from the repo
-root.
+record overstated its own evidence, and nothing failed.  This gate scans
+the prose record for record names and exits 1, listing every offender
+as ``path:line``, when a cited record is absent from the repo root.
 
-A mention is NOT a citation when:
-
-- in a prose file, its line also says ``never committed`` or
-  ``missing`` — an honest demotion is the record correcting itself,
-  and must stay expressible;
-- in ``bench.py``, it sits inside an ``add_argument(...)`` call span or
-  a module/function/class docstring — argparse defaults and shape docs
-  name the OUTPUT a flag would write, not evidence the record relies
-  on.  Comments outside those spans DO cite (they quote recorded
-  numbers).
+A mention is NOT a citation when its line also says ``never committed``
+or ``missing`` — an honest demotion is the record correcting itself,
+and must stay expressible.
 
 Run from anywhere: paths resolve against the repo root (this script's
 parent's parent).
@@ -29,55 +19,22 @@ parent's parent).
 
 from __future__ import annotations
 
-import ast
 import os
 import re
 import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-LEDGER_RE = re.compile(r"(?:BENCH_AB|MULTICHIP)_\w+\.json")
+LEDGER_RE = re.compile(r"(?:BENCH|MULTICHIP)_\w+\.json")
 DEMOTION_RE = re.compile(r"never committed|missing", re.I)
 
-PROSE_FILES = ["README.md", "CHANGES.md", "COVERAGE.md", "ROADMAP.md"]
-SOURCE_FILES = ["bench.py"]
-
-
-def _bench_exempt_spans(src: str) -> list[tuple[int, int]]:
-    """(start, end) line spans of add_argument calls and docstrings."""
-    spans: list[tuple[int, int]] = []
-    tree = ast.parse(src)
-    for node in ast.walk(tree):
-        if (isinstance(node, ast.Call)
-                and isinstance(node.func, ast.Attribute)
-                and node.func.attr == "add_argument"):
-            spans.append((node.lineno, node.end_lineno))
-        if isinstance(node, (ast.Module, ast.FunctionDef,
-                             ast.AsyncFunctionDef, ast.ClassDef)):
-            body = node.body
-            if (body and isinstance(body[0], ast.Expr)
-                    and isinstance(body[0].value, ast.Constant)
-                    and isinstance(body[0].value.value, str)):
-                spans.append((body[0].lineno, body[0].end_lineno))
-    return spans
-
-
-def _in_spans(line: int, spans: list[tuple[int, int]]) -> bool:
-    return any(a <= line <= b for a, b in spans)
+PROSE_FILES = ["README.md", "CHANGES.md", "COVERAGE.md", "ROADMAP.md",
+               "VERDICT.md"]
 
 
 def check(root: str = ROOT) -> list[str]:
     """Every violation as ``path:line: <name> cited but absent``."""
     problems: list[str] = []
-
-    def cited_but_absent(rel: str, lineno: int, text: str) -> None:
-        for name in LEDGER_RE.findall(text):
-            if not os.path.exists(os.path.join(root, name)):
-                problems.append(
-                    f"{rel}:{lineno}: {name} cited but absent from the "
-                    f"repo root (commit the ledger, or demote the claim "
-                    f"with 'never committed' on the citing line)")
-
     for rel in PROSE_FILES:
         path = os.path.join(root, rel)
         if not os.path.exists(path):
@@ -86,20 +43,13 @@ def check(root: str = ROOT) -> list[str]:
             for i, line in enumerate(f, start=1):
                 if DEMOTION_RE.search(line):
                     continue
-                cited_but_absent(rel, i, line)
-
-    for rel in SOURCE_FILES:
-        path = os.path.join(root, rel)
-        if not os.path.exists(path):
-            continue
-        with open(path, "r", encoding="utf-8") as f:
-            src = f.read()
-        spans = _bench_exempt_spans(src)
-        for i, line in enumerate(src.splitlines(), start=1):
-            if _in_spans(i, spans):
-                continue
-            cited_but_absent(rel, i, line)
-
+                for name in LEDGER_RE.findall(line):
+                    if not os.path.exists(os.path.join(root, name)):
+                        problems.append(
+                            f"{rel}:{i}: {name} cited but absent from the "
+                            f"repo root (commit the ledger, or demote the "
+                            f"claim with 'never committed' on the citing "
+                            f"line)")
     return problems
 
 
@@ -111,7 +61,7 @@ def main() -> int:
         print(f"check_ledgers: {len(problems)} phantom ledger citation(s) "
               f"— evidence-integrity gate FAILED", file=sys.stderr)
         return 1
-    print("check_ledgers: every cited BENCH_AB_*/MULTICHIP_*.json exists")
+    print("check_ledgers: every cited BENCH_*/MULTICHIP_*.json exists")
     return 0
 
 

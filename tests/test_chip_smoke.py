@@ -33,8 +33,8 @@ def smoke():
 def test_parent_module_imports_without_jax():
     code = ("import sys; import chip_smoke; "
             "assert 'jax' not in sys.modules, 'chip_smoke imported jax'; "
-            "import bench; "
-            "assert 'jax' not in sys.modules, 'bench imported jax'")
+            "import kubernetes_tpu.testutil; "
+            "assert 'jax' not in sys.modules, 'testutil imported jax'")
     proc = subprocess.run([sys.executable, "-c", code], cwd=REPO,
                           capture_output=True, text=True, timeout=60)
     assert proc.returncode == 0, proc.stderr

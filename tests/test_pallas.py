@@ -3,7 +3,7 @@
 CI runs on the forced-CPU platform (conftest), so the kernel executes in
 Pallas interpret mode — same program, interpreter semantics — keeping the
 kernel's logic covered without TPU hardware.  On real TPU the identical
-code path is exercised by ``bench.py`` and the backend's auto mode.
+code path is exercised by ``chip_smoke.py`` and the backend's auto mode.
 """
 
 import random
@@ -135,34 +135,29 @@ def test_pallas_matches_xla_scan_plain(interpret_pallas):
     assert (np.asarray(want) == np.asarray(got)).all()
 
 
-def test_superstep_k_parity(interpret_pallas, monkeypatch):
-    """K=1 (the plain loop), K=4 and K=8 super-step programs must be
-    bit-identical: same chosen vector, same round-robin counter — the
-    super-step is a scheduling-of-instructions change, not an arithmetic
-    one.  60 pods with K=8 leaves 4 inert tail sub-steps, so the
-    valid-masking is exercised too."""
-    from kubernetes_tpu.utils.features import DEFAULT_FEATURE_GATES
+def test_shape_key_is_the_runner_argument_tuple(interpret_pallas, monkeypatch):
+    """The breaker files failures under ``shape_key(static)`` and
+    ``_pallas_runner`` caches one compile per argument tuple: the two must
+    be the same tuple, or one bad shape blacklists another's program."""
+    import inspect
 
     m, pods, pctx = _mixed_problem(seed=7)
     tz = Tensorizer(pad_multiple=128)
     static = tz.build_static(pods, m, pctx)
-    assert static is not None
-    outs = {}
-    # the gate defaults OFF (recorded-negative perf) — force it on, or
-    # _superstep_k() returns 1 regardless of the env and the test
-    # compares three identical K=1 programs
-    with DEFAULT_FEATURE_GATES.override("PallasSuperSteps", True):
-        for k in ("1", "4", "8"):
-            monkeypatch.setenv("KTPU_SUPERSTEP_K", k)
-            assert pk._superstep_k() == int(k)
-            got, rr = pk.schedule_batch_pallas(
-                static, tz.initial_state(static, m, pctx, pods))
-            outs[k] = (np.asarray(got).copy(), rr)
-    monkeypatch.delenv("KTPU_SUPERSTEP_K")
-    base = outs["1"]
-    for k in ("4", "8"):
-        assert outs[k][1] == base[1], f"rr diverged at K={k}"
-        assert (outs[k][0] == base[0]).all(), f"chosen diverged at K={k}"
+    runner, asked = pk._pallas_runner, []
+
+    def noting_runner(*key):
+        asked.append(key)
+        return runner(*key)
+
+    with monkeypatch.context() as mp:
+        mp.setattr(pk, "_pallas_runner", noting_runner)
+        pk.schedule_batch_pallas(static, tz.initial_state(static, m, pctx, pods))
+    assert asked == [pk.shape_key(static)]
+    # one field per parameter of the runner, none defaulted
+    params = inspect.signature(runner.__wrapped__).parameters
+    assert len(asked[0]) == len(params)
+    assert all(q.default is q.empty for q in params.values())
 
 
 def test_supports_pallas_budget_guard():

@@ -588,20 +588,19 @@ def test_parity_large_randomized_with_affinity_and_volumes():
 
 
 def test_prefix_parity_gate_small_scale():
-    """bench.run_prefix_parity: the oracle replaying the first k pods of
-    the batch's recorded drain order matches the kernel's first k
+    """chip_smoke.run_prefix_parity: the oracle replaying the first k pods
+    of the batch's recorded drain order matches the kernel's first k
     assignments exactly (prefix-closure of sequential greedy)."""
     import os
     import sys
 
     sys.path.insert(0, os.path.dirname(os.path.dirname(__file__)))
-    import bench
+    import chip_smoke
 
-    backend_res = bench.run_once(
-        80, 600, use_backend=True, workload="mixed", seed=3)
+    backend_res = chip_smoke._schedule_cluster(
+        chip_smoke._mixed_workload(80, 600, 3))
     assert len(backend_res["batch_order"]) == 600
-    gate = bench.run_prefix_parity(
-        backend_res, 80, 600, workload="mixed", seed=3, k=150)
+    gate = chip_smoke.run_prefix_parity(backend_res, 80, 600, seed=3, k=150)
     assert gate["checked"] == 150
     assert gate["mismatches"] == 0, gate["sample"]
 

@@ -1136,8 +1136,9 @@ def _load_check_ledgers():
 
 
 def test_check_ledgers_live_tree_clean():
-    """Every BENCH_AB_*.json the record cites exists in the tree — the
-    gate that would have caught the PR 6/11 phantom citations."""
+    """Every BENCH_*.json / MULTICHIP_*.json the record cites exists in
+    the tree — the gate that would have caught the PR 6/11 phantom
+    citations."""
     cl = _load_check_ledgers()
     assert cl.check() == []
 
@@ -1157,23 +1158,17 @@ def test_check_ledgers_flags_phantom_citation(tmp_path):
     assert problems[0].startswith("README.md:1: BENCH_AB_ghost.json")
 
 
-def test_check_ledgers_bench_spans_exempt(tmp_path):
-    """In bench.py, docstrings and add_argument() spans name the OUTPUT
-    a flag would write, not evidence — only comments/code outside those
-    spans cite."""
+@pytest.mark.parametrize("prose", ["README.md", "VERDICT.md"])
+def test_check_ledgers_flags_plain_bench_record(tmp_path, prose):
+    """Any ``BENCH_*.json`` is a record, not only the ``BENCH_AB_*`` ones,
+    and VERDICT.md cites records like the other prose files."""
     cl = _load_check_ledgers()
-    (tmp_path / "bench.py").write_text(
-        '"""Writes BENCH_AB_docstring.json when --ab runs."""\n'
-        "import argparse\n"
-        "p = argparse.ArgumentParser()\n"
-        "p.add_argument(\n"
-        "    '--out',\n"
-        "    default='BENCH_AB_flag_default.json')\n"
-        "# recorded medians live in BENCH_AB_cited.json\n"
-        "x = 1\n")
+    (tmp_path / prose).write_text(
+        "shipped evidence: `BENCH_watch_fleet.json`\n"
+        "the harness reads BENCHMARK.json, which is no record\n")
     problems = cl.check(root=str(tmp_path))
     assert len(problems) == 1, problems
-    assert problems[0].startswith("bench.py:7: BENCH_AB_cited.json")
+    assert problems[0].startswith(f"{prose}:1: BENCH_watch_fleet.json")
 
 
 def test_check_ledgers_wired_into_check_sh():

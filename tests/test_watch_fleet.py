@@ -1,29 +1,22 @@
-"""CI smoke for the hollow-watcher fleet bench (ISSUE 19).
+"""CI smoke for the hollow-watcher fleet (ISSUE 19).
 
-A scaled-down ``bench.run_watch_fleet`` — a couple hundred watchers, a
-couple of seconds — gating the properties the committed ledger claims
-at 10k: fan-out liveness on both arms, ZERO dropped-state clients (the
-state-equivalence sweep over every client's final cache), and the
-per-CLIENT staleness SLO evaluator actually sampling (burn on the
-pump stall, recovery after the drain, top-K laggard attribution on the
-breach dump).  The north-preset oracle-parity leg is skipped here (it
-is minutes of churn; the ledger carries it)."""
-
-import os
-import sys
+``watch_fleet_harness.run_watch_fleet`` at a couple hundred watchers for
+a couple of seconds, gating fan-out liveness on both arms, ZERO
+dropped-state clients (the state-equivalence sweep over every client's
+final cache), and the per-CLIENT staleness SLO evaluator actually
+sampling (burn on the pump stall, recovery after the drain, top-K
+laggard attribution on the breach dump)."""
 
 import pytest
-
-sys.path.insert(0, os.path.dirname(os.path.dirname(__file__)))
 
 
 @pytest.fixture(scope="module")
 def fleet_result():
-    import bench
+    from tests.watch_fleet_harness import run_watch_fleet
 
-    return bench.run_watch_fleet(
+    return run_watch_fleet(
         n_watchers=200, seed_pods=80, churn_ops=150, http_watchers=4,
-        selector_watchers=2, n_informers=1, pump_threads=4, parity=False)
+        selector_watchers=2, n_informers=1, pump_threads=4)
 
 
 def test_fleet_fanout_liveness(fleet_result):
