@@ -232,10 +232,14 @@ class PodClient(TypedClient):
             "Pod", binding.pod_namespace, binding.pod_name, _assign
         )
 
-    def bind_many(self, bindings: list[api.Binding]) -> list[Optional[str]]:
-        """Batch placement commit (one store txn); per-item error or None."""
+    def bind_many(self, bindings: list) -> list[Optional[str]]:
+        """Batch placement commit (one store txn); per-item error or None.
+        Items are ``api.Binding`` or the store's own ``(namespace, name,
+        node_name)`` triples, which pass through as they are."""
         return self._store.bind_many(
-            [(b.pod_namespace, b.pod_name, b.node_name) for b in bindings]
+            [b if isinstance(b, tuple)
+             else (b.pod_namespace, b.pod_name, b.node_name)
+             for b in bindings]
         )
 
     def evict(self, name: str, namespace: Optional[str] = None) -> None:

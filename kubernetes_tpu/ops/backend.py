@@ -30,7 +30,7 @@ from .. import faults
 from ..api import types as api
 from ..utils import tracing
 from ..scheduler.generic_scheduler import FitError, GenericScheduler
-from ..scheduler.nodeinfo import NodeInfo, pod_has_affinity
+from ..scheduler.nodeinfo import NodeInfo, PlacedSegment, pod_has_affinity
 from ..scheduler.predicates import DEFAULT_PREDICATES
 from ..scheduler.priorities import (
     BalancedResourceAllocation,
@@ -633,7 +633,10 @@ class TPUBatchBackend:
         time.  Kernel-path entries carry the segment's per-signature
         request vectors (the ``add_pod_counted`` contract) so the caller's
         cache assume can skip its per-pod quantity parse; oracle-path
-        entries carry ``None``.  Entry order across calls equals pod
+        entries carry ``None``.  A kernel segment's entries come as a
+        :class:`PlacedSegment`: the grouping by node that ``place`` made
+        for the working snapshot rides along, so the cache assume can
+        write by node too.  Entry order across calls equals pod
         order, so sequential semantics are unchanged; with
         ``on_segment=None`` behavior is exactly the unpipelined batch.
 
@@ -730,7 +733,8 @@ class TPUBatchBackend:
             per-pod calls read from every pod (requests, the affinity
             flag, host ports) is a fact of its scheduling signature and is
             read once per group, from the group's first pod.  Returns the
-            commit entries and the counts of nodes written and groups."""
+            commit entries, which keep the groups by node for the caller's
+            cache, and the counts of nodes written and groups."""
             seg_pods = [pod for _, pod in segment]
             groups = static.group_of_pod
             group_of = groups.tolist()
@@ -743,11 +747,6 @@ class TPUBatchBackend:
             req_units, nz_units = _segment_units(static, len(reps))
             req_vecs = [ResourceVec(u) for u in req_units.tolist()]
             nz_vecs = [ResourceVec(u) for u in nz_units.tolist()]
-            # the segment's per-signature vectors ride along so the
-            # caller's cache assume can skip its own quantity parse
-            entries = [(pod, name, req_vecs[g], nz_vecs[g])
-                       for pod, name, g in zip(seg_pods, names, group_of)]
-
             touched, order, bounds, counts = _by_node(chosen, groups, len(reps))
             req_sums = (counts @ req_units).tolist()
             nz_sums = (counts @ nz_units).tolist()
@@ -756,24 +755,35 @@ class TPUBatchBackend:
             port_groups = [(g, ports) for g, rep in enumerate(reps)
                            if (ports := rep.host_ports())]
             n_nodes = n_pods = 0
+            by_node = []
             for t, c in enumerate(touched):
-                node_info = mutable_info(node_names[c])
-                if node_info is None:
-                    continue
                 ks = order[bounds[t]:bounds[t + 1]]
-                node_info.add_pods_counted(
+                # add_pods_counted only reads its arguments: the caller's
+                # cache is handed the very objects the working snapshot took
+                group = (
                     [seg_pods[k] for k in ks],
                     ResourceVec(req_sums[t]), ResourceVec(nz_sums[t]),
                     [seg_pods[k] for k in ks if has_affinity[group_of[k]]]
                     if any_affinity else (),
                     [port for g, ports in port_groups if counts[t, g]
                      for port in ports])
+                by_node.append((node_names[c], *group))
+                node_info = mutable_info(node_names[c])
+                if node_info is None:
+                    continue
+                node_info.add_pods_counted(*group)
                 n_nodes += 1
                 n_pods += len(ks)
             self.stats["place_batched_pods"] += n_pods
             # the kernel path always has the host state (weights is not None)
             host_state.add_pods(seg_pods, static.pod_names, names, group_of,
                                 static.pod_vol_valid.any(axis=1).tolist())
+            # the segment's per-signature vectors ride along so the
+            # caller's cache assume can skip its own quantity parse
+            entries = PlacedSegment(
+                [(pod, name, req_vecs[g], nz_vecs[g])
+                 for pod, name, g in zip(seg_pods, names, group_of)],
+                by_node, len(seg_pods))
             return entries, n_nodes, len(reps)
 
         def run_oracle(pod: api.Pod, i: int) -> None:

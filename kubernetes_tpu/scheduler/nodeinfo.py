@@ -209,6 +209,36 @@ class NodeInfo:
         return c is not None and c.status == "True"
 
 
+class PlacedSegment(list):
+    """A segment's commit entries ``(pod, node_name | None, req_vec | None,
+    nz_vec | None)`` in pod order, with the grouping by node that placing
+    a kernel segment computes riding along.
+
+    ``by_node`` holds one ``(node_name, pods, req_sum, nz_sum,
+    affinity_pods, ports)`` per touched node — ``add_pods_counted``'s
+    arguments, each node's pods in pod order — and covers exactly the
+    entries among the first ``grouped`` that have a node.  Entries
+    appended behind those (an oracle segment's) have no group, and a copy
+    (``list(entries)``, a slice) is a plain list that carries none: a
+    caller that rewrites entries cannot keep groups that no longer hold."""
+
+    __slots__ = ("by_node", "grouped")
+
+    def __init__(self, entries=(), by_node=(), grouped: int = 0):
+        super().__init__(entries)
+        self.by_node = by_node
+        self.grouped = grouped
+
+    @classmethod
+    def placed_of(cls, entries: list) -> "PlacedSegment":
+        """The entries of ``entries`` that have a node, under the groups
+        ``entries`` carried (none, for a plain list)."""
+        grouped = getattr(entries, "grouped", 0)
+        head = [e for e in entries[:grouped] if e[1] is not None]
+        return cls(head + [e for e in entries[grouped:] if e[1] is not None],
+                   getattr(entries, "by_node", ()), len(head))
+
+
 class SchedulerCache:
     """Assume/confirm/expire pod cache (``schedulercache/cache.go``)."""
 
@@ -247,7 +277,8 @@ class SchedulerCache:
     def assume_pod(self, pod: api.Pod, node_name: str) -> None:
         self.assume_many([(pod, node_name)])
 
-    def assume_many(self, pairs: list) -> None:
+    def assume_many(self, pairs: list,
+                    keys: Optional[list] = None) -> tuple[int, int]:
         """Batch assume under ONE lock acquisition + deadline read — the
         TPU path lands 150k assumptions at once and per-pod locking is
         measurable at that scale.  Same semantics as assume_pod per pair.
@@ -256,12 +287,46 @@ class SchedulerCache:
         the 4-tuple form carries the batch backend's per-signature request
         vectors so the aggregation skips the per-pod quantity parse (they
         MUST equal ``pod_request_vec(pod)``/``pod_nonzero_request_vec``,
-        the ``add_pod_counted`` contract)."""
+        the ``add_pod_counted`` contract); every entry has a node.
+        ``keys``: the entries' ``pod.meta.key`` where the caller already
+        holds them.
+
+        A :class:`PlacedSegment` brings its first ``grouped`` entries
+        grouped by node: those are written by node — one
+        ``add_pods_counted`` each, the two pod maps in bulk — and leave
+        every field as the per-pod calls in entry order leave it; a key of
+        theirs that is already held is refused before anything is
+        written.  All other entries are written one at a time.  Returns
+        the nodes that took one grouped write and the pods those held."""
+        if keys is None:
+            keys = [entry[0].meta.key for entry in pairs]
+        by_node = getattr(pairs, "by_node", ())
+        n_grouped = getattr(pairs, "grouped", 0)
         deadline = self._clock() + self._ttl
         with self._mu:
-            for entry in pairs:
+            if n_grouped:
+                head = keys[:n_grouped]
+                states = dict(zip(head, [(entry[0], entry[1], "assumed")
+                                         for entry in pairs[:n_grouped]]))
+                if (len(states) != n_grouped
+                        or not self._pod_states.keys().isdisjoint(states)):
+                    seen: set = set(self._pod_states)
+                    for key in head:
+                        if key in seen:
+                            raise ValueError(
+                                f"pod {key} already assumed/added")
+                        seen.add(key)
+                if any(group[0] not in self._nodes for group in by_node):
+                    # a node the cache does not hold comes into being
+                    # where the per-pod calls make it: at its first pod
+                    for entry in pairs[:n_grouped]:
+                        self._node_info(entry[1])
+                for node_name, *group in by_node:
+                    self._nodes[node_name].add_pods_counted(*group)
+                self._pod_states.update(states)
+                self._assume_deadlines.update(dict.fromkeys(head, deadline))
+            for entry, key in zip(pairs[n_grouped:], keys[n_grouped:]):
                 pod, node_name = entry[0], entry[1]
-                key = pod.meta.key
                 if key in self._pod_states:
                     raise ValueError(f"pod {key} already assumed/added")
                 info = self._node_info(node_name)
@@ -271,6 +336,7 @@ class SchedulerCache:
                     info.add_pod(pod)
                 self._pod_states[key] = (pod, node_name, "assumed")
                 self._assume_deadlines[key] = deadline
+        return len(by_node), n_grouped
 
     def finish_binding(self, pod_key: str) -> None:
         """Binding RPC issued; start the expiry clock (``cache.go:130``)."""

@@ -61,6 +61,12 @@ class PodBackoff:
         with self._mu:
             self._entries.pop(pod_key, None)
 
+    def forget_many(self, pod_keys) -> None:
+        """``forget`` for a committed segment's keys under one lock hold."""
+        with self._mu:
+            for key in self._entries.keys() & pod_keys:
+                del self._entries[key]
+
     def gc(self, max_age: float = 600.0) -> None:
         with self._mu:
             now = self._clock()

@@ -36,7 +36,7 @@ from ..utils import tracing
 from ..utils.metrics import SchedulerMetrics
 from ..utils.trace import Trace
 from .generic_scheduler import FitError, GenericScheduler
-from .nodeinfo import NodeInfo, SchedulerCache
+from .nodeinfo import NodeInfo, PlacedSegment, SchedulerCache
 from .priorities import PriorityContext
 from .queue import PodBackoff, SchedulingQueue
 
@@ -872,38 +872,33 @@ class Scheduler:
             this while the device executes the NEXT segment, so the
             commit cost hides in the scan's shadow)."""
             t_commit = time.perf_counter()
-            to_bind: list[tuple[api.Pod, api.Binding]] = []
-            to_assume: list[tuple] = []
-            for pod, node_name, req_vec, nz_vec in entries:
-                if node_name is None:
-                    self.handle_schedule_failure(pod, FitError(pod, {}), ev_batch,
-                                                 preempt_cohort=preempt_cohort)
-                    totals["failed"] += 1
-                    continue
-                # per-signature request vectors from the backend (when the
-                # kernel path produced this entry) spare the cache assume
-                # a per-pod quantity re-parse
-                to_assume.append((pod, node_name, req_vec, nz_vec))
-                self.backoff.forget(pod.meta.key)
-                to_bind.append(
-                    (
-                        pod,
-                        api.Binding(
-                            pod_namespace=pod.meta.namespace,
-                            pod_name=pod.meta.name,
-                            node_name=node_name,
-                        ),
-                    )
-                )
-            with (tr.span("commit.assume", cat="phase", pods=len(to_assume))
-                  if tr is not None else tracing.NULL_SPAN):
-                self.cache.assume_many(to_assume)
+            unplaced = [e[0] for e in entries if e[1] is None]
+            for pod in unplaced:
+                self.handle_schedule_failure(pod, FitError(pod, {}), ev_batch,
+                                             preempt_cohort=preempt_cohort)
+            totals["failed"] += len(unplaced)
+            # the entries that have a node keep what the backend sent with
+            # them: per-signature request vectors, and from a kernel
+            # segment the grouping by node, which spare the cache assume a
+            # per-pod quantity re-parse and a per-pod NodeInfo write
+            placed = PlacedSegment.placed_of(entries) if unplaced else entries
+            # one walk for what every step below needs of a pod: its key,
+            # once, and the store's own (namespace, name, node) triple
+            metas = [e[0].meta for e in placed]
+            keys = [m.key for m in metas]
+            to_bind = [(m.namespace, m.name, e[1])
+                       for m, e in zip(metas, placed)]
+            self.backoff.forget_many(keys)
+            with (tr.span("commit.assume", cat="phase", pods=len(placed))
+                  if tr is not None else tracing.NULL_SPAN) as sp:
+                nodes, batched = self.cache.assume_many(placed, keys)
+                sp.set(nodes=nodes, batched=batched)
+            self.metrics.assume_batched_pods.inc(batched)
             bind_start = self._clock()
             with (tr.span("commit.bind", cat="phase", pods=len(to_bind))
                   if tr is not None else tracing.NULL_SPAN):
                 try:
-                    errors = self.clientset.pods.bind_many(
-                        [b for _, b in to_bind])
+                    errors = self.clientset.pods.bind_many(to_bind)
                 except Exception as e:
                     # the whole segment's commit failed before any CAS
                     # applied (store overload / transport outage / injected
@@ -914,30 +909,28 @@ class Scheduler:
                                    len(to_bind), type(e).__name__, e)
                     errors = [f"transient: {e}"] * len(to_bind)
             self.metrics.binding_latency.observe((self._clock() - bind_start) * 1e6)
-            finished: list[str] = []
-            emit = self.emit_events
-            for (pod, binding), err in zip(to_bind, errors):
-                if err is None:
-                    finished.append(pod.meta.key)
-                    if emit:
-                        ev_batch.append((
-                            pod, "Normal", "Scheduled",
-                            ("Successfully assigned %s to %s",
-                             pod.meta.key, binding.node_name),
-                        ))
-                    totals["bound"] += 1
-                else:
-                    logger.warning("bind failed for %s: %s", pod.meta.key, err)
+            if self.emit_events:
+                ev_batch.extend([
+                    (e[0], "Normal", "Scheduled",
+                     ("Successfully assigned %s to %s", key, e[1]))
+                    if err is None else (e[0], "Warning", "FailedBinding", err)
+                    for e, key, err in zip(placed, keys, errors)])
+            finished = keys
+            if errors.count(None) != len(keys):
+                finished = [key for key, err in zip(keys, errors) if err is None]
+                for e, key, err in zip(placed, keys, errors):
+                    if err is None:
+                        continue
+                    logger.warning("bind failed for %s: %s", key, err)
                     self.metrics.bind_failures.inc()
-                    self.cache.forget_pod(pod)
-                    if emit:
-                        ev_batch.append((pod, "Warning", "FailedBinding", err))
+                    self.cache.forget_pod(e[0])
                     # requeue-with-backoff when the pod is still ours and
                     # unbound (transient CAS/transport failure) — decided
                     # from the informer's latest truth, so a genuine
                     # conflict (bound elsewhere) is NOT retried
-                    self._requeue_after_bind_failure(pod)
+                    self._requeue_after_bind_failure(e[0])
                     totals["failed"] += 1
+            totals["bound"] += len(finished)
             with (tr.span("commit.finish", cat="phase", pods=len(finished))
                   if tr is not None else tracing.NULL_SPAN):
                 self.cache.finish_binding_many(finished)
@@ -955,7 +948,7 @@ class Scheduler:
             if tr is not None:
                 # same two clock reads feed the stats timer and the span:
                 # the trace-derived commit_s below IS this measurement.
-                # It adopts commit.assume / .bind / .finish; the two loops
+                # It adopts commit.assume / .bind / .finish; the walks
                 # over the entries stay as its self time
                 tr.complete("commit", t_commit, t_commit_end, cat="phase",
                             pods=len(entries), bound=len(finished))

@@ -414,6 +414,12 @@ class SchedulerMetrics:
             "events delivered inside watch frames (the per-event path "
             "they replaced)",
         ))
+        self.assume_batched_pods = r.register(Counter(
+            "scheduler_assume_batched_pods_total",
+            "pods the cache assumed by node: one aggregate NodeInfo write "
+            "per touched node of a kernel segment, from the groups the "
+            "backend placed it by (the rest are assumed pod by pod)",
+        ))
         self.confirm_fallbacks = r.register(Counter(
             "scheduler_confirm_fallbacks_total",
             "frame bind-confirm entries the columnar revision fence "

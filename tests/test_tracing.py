@@ -389,6 +389,9 @@ def test_the_wave_covers_the_loop_iteration_in_named_children():
             assert parent.t0 <= parent.children[0].t0
             assert parent.children[-1].t1 <= parent.t1
         assert all(c.attrs["pods"] == 12 for c in by["commit"].children)
+        # the assume went by node: the 4 groups place made, all 12 pods
+        assert by["commit"].children[0].attrs == {
+            "pods": 12, "nodes": 4, "batched": 12}
         assert by["queue.drain"].attrs == {"pods": 12}
         assert by["snapshot"].attrs == {"nodes": 4}
         assert by["segment_plan"].attrs == {"pods": 12, "segments": 1}
@@ -405,6 +408,7 @@ def test_the_wave_covers_the_loop_iteration_in_named_children():
                                        "nodes": 4, "groups": 1}
     assert by_second["place"].attrs == by_first["place"].attrs
     assert sched.backend.stats["place_batched_pods"] == 24
+    assert sched.metrics.assume_batched_pods.value == 24
 
     # the phase dict gains the new names and keeps the pump's apply_s
     totals = second.phase_totals()
