@@ -54,6 +54,7 @@ HOT_PATH_MODULES = [
     "kubernetes_tpu/store/wal.py",
     "kubernetes_tpu/client/informer.py",
     "kubernetes_tpu/client/remote.py",
+    "kubernetes_tpu/apiserver/server.py",
     "kubernetes_tpu/scheduler/scheduler.py",
     "kubernetes_tpu/ops/backend.py",
     "kubernetes_tpu/ops/batch_kernel.py",

@@ -161,6 +161,10 @@ class APIServer:
         self.error_write_failures = self.registry.register(Counter(
             "apiserver_error_write_failures_total",
             "error responses that could not be written (client hung up)"))
+        self.watch_held_frames = self.registry.register(Counter(
+            "apiserver_watch_stream_held_frames_total",
+            "watch frames written past their stream's deadline: they were "
+            "queued when it passed, and the stream ended after them"))
         self.apiservice_status_failures = self.registry.register(Counter(
             "apiserver_apiservice_status_failures_total",
             "best-effort APIService availability updates that failed"))
@@ -1396,28 +1400,28 @@ def _make_handler(server: APIServer):
                 import time as _t
 
                 deadline = _t.monotonic() + timeout
-                # the txn whose frame went out last.  Its other pieces
-                # are on the queue already (the store puts them in one
-                # lock hold), and the stream does not end between two of
-                # them: the resume would replay the rest of the txn from
-                # the log per event, tens of thousands of lines for a
-                # large wave, where the pieces are a few chunk writes
-                txn = None
-                while True:
-                    left = deadline - _t.monotonic()
-                    if left <= 0 and txn is None:
-                        break
-                    ev = watch.get(timeout=min(0.5, max(0.0, left)))
-                    if left <= 0 and not (ev is not None and ev.type == FRAME
-                                          and ev.txn == txn):
-                        # (anything else taken off the queue here is
-                        # replayed from the log when the client resumes)
-                        break
-                    if ev is None:
-                        txn = None
-                        continue
+
+                def queued():
+                    while (left := deadline - _t.monotonic()) > 0:
+                        ev = watch.get(timeout=min(0.5, left))
+                        if ev is not None:
+                            yield ev
+                    # past the deadline the stream still writes what was
+                    # on the queue when it passed, that many items and no
+                    # more (a stream under continuous load ends too): a
+                    # dropped frame costs the client a connection, the
+                    # store a walk of its log under its lock and a
+                    # second encode
+                    for _ in range(watch.qsize()):
+                        ev = watch.get(timeout=0)
+                        if ev is None:
+                            return
+                        if ev.type == FRAME:
+                            server.watch_held_frames.inc()
+                        yield ev
+
+                for ev in queued():
                     if ev.type == FRAME:
-                        txn = ev.txn
                         frame = ev
                         if pred is not None:
                             # the LIST-then-WATCH contract at the column
@@ -1435,7 +1439,6 @@ def _make_handler(server: APIServer):
                         # shared-immutable across watcher queues)
                         self._write_chunk(frame.wire_bytes())
                         continue
-                    txn = None
                     if pred is not None and not pred(ev.object):
                         # a selector silently ignored on watch would
                         # re-create the full-cluster fan-out the

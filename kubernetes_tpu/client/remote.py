@@ -201,7 +201,9 @@ class RemoteWatch:
         # the (re)connect is the slow, failure-prone edge of the stream —
         # one span per dial, nothing per event
         with (tr.span("remote.watch.connect", cat="client",
-                      resource=self._resource)
+                      resource=self._resource,
+                      resume=self._last_rev is not None,
+                      from_revision=self._last_rev)
               if tr is not None else tracing.NULL_SPAN):
             faults.hit("remote.watch.stream", phase="connect",
                        resource=self._resource)

@@ -144,11 +144,21 @@ class Scheduler:
     # -- informer wiring (factory.go:140-520) ------------------------------
     def _wire_informers(self) -> None:
         pods = self.informers.informer("Pod")
+        line = self.metrics.watch_line_events.inc
+
+        def counted(route):
+            # an event the informer hands over one by one came as a line
+            # of its own, not in a frame (on_batch takes those)
+            def on_line(*objs):
+                line()
+                route(*objs)
+            return on_line
+
         pods.add_handler(
             Handler(
-                on_add=self._on_pod_add,
-                on_update=self._on_pod_update,
-                on_delete=self._on_pod_delete,
+                on_add=counted(self._on_pod_add),
+                on_update=counted(self._on_pod_update),
+                on_delete=counted(self._on_pod_delete),
                 on_batch=self._on_pod_frame,
             )
         )

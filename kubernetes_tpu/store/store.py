@@ -31,12 +31,13 @@ import queue
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Callable, Iterator, Optional
+from typing import Callable, Iterator, NamedTuple, Optional
 
 from .. import faults
 from ..api.meta import new_uid
 from ..utils import tracing
 from ..utils.metrics import DEFAULT_STORE_METRICS
+from . import frames as frames_mod
 
 
 def _py_fast_deepcopy(obj):
@@ -137,6 +138,10 @@ class Watch:
         except queue.Empty:
             return None
 
+    def qsize(self) -> int:
+        """Items delivered and not yet taken."""
+        return self._queue.qsize()
+
 
 class _PendingBatch:
     """One open coalescing window at the broadcaster seam: per-key
@@ -149,14 +154,65 @@ class _PendingBatch:
     event log, and replication all stay per-event at commit time; ONLY
     live watcher delivery waits for the window."""
 
-    __slots__ = ("latest", "deadline", "txn", "folded")
+    __slots__ = ("latest", "deadline", "txn", "folded", "first_rev")
 
-    def __init__(self, deadline: float, txn: str):
+    def __init__(self, deadline: float, txn: str, first_rev: int):
         self.latest: "collections.OrderedDict[tuple, WatchEvent]" = (
             collections.OrderedDict())
         self.deadline = deadline
         self.txn = txn
         self.folded = 0  # deliveries superseded inside this window
+        # the first revision buffered: with the last one, the stretch of
+        # the log this window's flush frames stand for (_LogTxn)
+        self.first_rev = first_rev
+
+
+class _LogTxn(NamedTuple):
+    """Where one batch txn lies in the event log: rows ``first``..``last``
+    (consecutive revisions, one lock hold), the id its frames carry, and
+    for ``bind_many`` the prev-revision column (row ``r`` at index
+    ``r - first``).  ``fold`` marks a coalescing window: its frames hold
+    each key's latest row only, per kind.  A frames watcher that resumes
+    inside the log window gets these rows packed as the live watchers
+    did (``Store.watch``)."""
+
+    first: int
+    last: int
+    txn: Optional[str]
+    prev: Optional[list]
+    fold: bool
+
+
+def _fold_by_kind(events) -> dict:
+    """kind -> its events, each key's latest only, a key sorted by its
+    latest commit: what one coalescing window delivers."""
+    latest: dict = {}
+    for ev in events:
+        k = (ev.kind, ev.key)
+        latest.pop(k, None)
+        latest[k] = ev
+    by_kind: dict[str, list[WatchEvent]] = {}
+    for ev in latest.values():
+        by_kind.setdefault(ev.kind, []).append(ev)
+    return by_kind
+
+
+def _replayed_pieces(t: _LogTxn, rows: list) -> list:
+    """The rows of txn ``t`` that a resumed frames watcher has not seen
+    (a suffix of it, in log order) as the items the live watchers got:
+    frames of at most ``frames.FRAME_MAX_ROWS`` rows with the txn's id
+    and its prev-revision column sliced alike."""
+    if t.fold:
+        out: list = []
+        for kind, evs in _fold_by_kind(rows).items():
+            out.extend(frames_mod.pack_frames(kind, evs, txn=t.txn)
+                       if len(evs) > 1
+                       else evs)
+        return out
+    lo = rows[0].revision - t.first
+    prev = None if t.prev is None else t.prev[lo:lo + len(rows)]
+    return frames_mod.pack_frames(rows[0].kind, rows, prev_revisions=prev,
+                                  txn=t.txn)
 
 
 class Store:
@@ -176,6 +232,10 @@ class Store:
         # window costs more than the write itself.
         self._log: collections.deque[WatchEvent] = collections.deque(maxlen=event_log_window)
         self._log_window = event_log_window
+        # where each batch txn lies in _log, oldest first, for as long as
+        # one of its rows is in the window (_trim_log_txns): what lets a
+        # resumed frames watcher's replay leave as frames
+        self._log_txns: collections.deque[_LogTxn] = collections.deque()
         # (kind filter, queue, wants_frames): frame-aware watchers opted
         # in via watch(frames=True) receive a correlated batch txn as
         # WatchFrames (one when it fits frames.FRAME_MAX_ROWS); everyone
@@ -539,6 +599,7 @@ class Store:
             }
             self._rev = rev
             self._log.clear()  # watchers older than the snapshot must relist
+            self._log_txns.clear()
             if self._wal is not None:
                 # durability must follow the state jump: the old WAL holds
                 # pre-snapshot events that no longer compose with the new
@@ -595,8 +656,10 @@ class Store:
         a correlated batch txn (``create_many``/``bind_many``) arrives as
         :class:`~.frames.WatchFrame` pieces of at most
         ``frames.FRAME_MAX_ROWS`` rows — one frame when it fits — instead
-        of N events (the log replay below stays per-event — only live
-        batches frame)."""
+        of N events.  The log replay below frames alike: the rows a batch
+        txn committed leave as the pieces the live watchers got (from the
+        row after ``from_revision`` where that falls inside a txn), rows
+        committed one at a time as events, all in revision order."""
         with self._mu:
             # ordering barrier: flush the open coalescing window before
             # the log replay below — otherwise the replay (which reads
@@ -610,11 +673,55 @@ class Store:
                     raise ExpiredRevisionError(
                         f"revision {from_revision} too old (oldest {oldest})"
                     )
-                for ev in self._log:
-                    if ev.revision > from_revision and (kind is None or ev.kind == kind):
-                        q.put(ev)  # shared-immutable (see _emit)
+                self._replay(q, kind, from_revision,
+                             frames and frames_mod.ENABLED)
             self._watchers.append((kind, q, frames))
             return Watch(self, q)
+
+    def _replay(self, q, kind: Optional[str], from_revision: int,
+                framed: bool) -> None:
+        """Put the log's rows after ``from_revision`` on a new watcher's
+        queue (shared-immutable, see _emit): one by one, or for a
+        ``framed`` watcher each batch txn's rows as frames.  One span and
+        two counter bumps per resumed watch, nothing per row."""
+        tr = tracing.current()
+        n_events = n_frames = 0
+        txns = set()
+        with (tr.span("store.watch.replay", cat="store", kind=kind,
+                      from_revision=from_revision)
+              if tr is not None else tracing.NULL_SPAN) as sp:
+            for item in self._replay_items(kind, from_revision, framed):
+                q.put(item)
+                if item.type == frames_mod.FRAME:
+                    n_frames += 1
+                    n_events += len(item)
+                    txns.add(item.txn)
+                else:
+                    n_events += 1
+            sp.set(events=n_events, frames=n_frames, txns=len(txns))
+        DEFAULT_STORE_METRICS.watch_replay_events.inc(n_events)
+        DEFAULT_STORE_METRICS.watch_replay_frames.inc(n_frames)
+
+    def _replay_items(self, kind: Optional[str], from_revision: int,
+                      framed: bool) -> Iterator:
+        txns = iter(self._log_txns if framed else ())
+        cur = next(txns, None)
+        rows: list = []  # cur's rows so far
+        for ev in self._log:
+            rev = ev.revision
+            if rev <= from_revision or (kind is not None and ev.kind != kind):
+                continue
+            while cur is not None and cur.last < rev:
+                if rows:
+                    yield from _replayed_pieces(cur, rows)
+                    rows = []
+                cur = next(txns, None)
+            if cur is not None and cur.first <= rev:
+                rows.append(ev)
+            else:
+                yield ev
+        if rows:
+            yield from _replayed_pieces(cur, rows)
 
     def _remove_watch(self, q) -> None:
         with self._mu:
@@ -631,6 +738,13 @@ class Store:
                 self.compact()  # RLock: safe to re-enter from the write path
         self._log.append(ev)  # deque maxlen trims the window in C
 
+    def _trim_log_txns(self) -> None:
+        """Forget the batch txns whose last row has left the log window."""
+        txns = self._log_txns
+        oldest = self._log[0].revision if self._log else self._rev + 1
+        while txns and txns[0].last < oldest:
+            txns.popleft()
+
     def _replicate(self, ev: WatchEvent) -> None:
         """Per-event shipping hook (no-op here): ``ReplicatedStore``
         overrides it to ship to followers.  Called on BOTH the per-event
@@ -642,6 +756,8 @@ class Store:
         # not mutate it (the informer parses it into fresh typed objects;
         # the mutation detector catches violations in tests).
         self._append_log(ev)
+        if self._log_txns:
+            self._trim_log_txns()
         self._replicate(ev)
         if self._coalesce_window > 0.0:
             # durability and the replay window are already per-event
@@ -664,7 +780,7 @@ class Store:
         if p is None:
             p = self._pending = _PendingBatch(
                 time.monotonic() + self._coalesce_window,
-                tracing.next_txn("coalesce"))
+                tracing.next_txn("coalesce"), ev.revision)
             self._coalesce_wake.set()
         k = (ev.kind, ev.key)
         if k in p.latest:
@@ -695,15 +811,11 @@ class Store:
         events = list(p.latest.values())
         if not events:
             return
-        from . import frames as frames_mod
-
         m = DEFAULT_STORE_METRICS
         m.coalesce_flushes.inc()
         if p.folded:
             m.coalesced_events.inc(p.folded)
-        by_kind: dict[str, list[WatchEvent]] = {}
-        for ev in events:
-            by_kind.setdefault(ev.kind, []).append(ev)
+        by_kind = _fold_by_kind(events)  # (folded as they were buffered)
         # synthetic frames carry NO prev_revisions (fold hides the
         # intermediate transitions, so the pre-transition revision is
         # honestly unknown — consumers take the per-object fallback
@@ -729,6 +841,10 @@ class Store:
                 frames_by_kind = {}
                 m.coalesce_fallbacks.inc()
                 sp.set(fallback=True)
+            else:
+                self._log_txns.append(_LogTxn(
+                    p.first_rev, events[-1].revision, p.txn, None, True))
+                self._trim_log_txns()
             n_frames = sum(len(fs) for fs in frames_by_kind.values())
             m.watch_frames.inc(n_frames)
             sp.set(frames=n_frames)
@@ -784,9 +900,13 @@ class Store:
         for ev in events:
             self._append_log(ev)
             self._replicate(ev)
+        if len(events) > 1:  # (a txn of one row goes out as the event)
+            self._log_txns.append(_LogTxn(
+                events[0].revision, events[-1].revision, txn, prev_revisions,
+                False))
+        if self._log_txns:
+            self._trim_log_txns()
         pieces: list = []
-        from . import frames as frames_mod
-
         want_frame = len(events) > 1 and frames_mod.ENABLED
         kind = events[0].kind  # batch txns are single-kind by construction
         for wkind, q, wants_frames in self._watchers:

@@ -310,6 +310,14 @@ class StoreMetrics:
             "watch frames packed by batch txns and coalescing flushes "
             "(one per piece of at most frames.FRAME_MAX_ROWS rows, "
             "however many watchers share it)"))
+        self.watch_replay_events = r.register(Counter(
+            "store_watch_replay_events_total",
+            "log rows replayed to watchers that resumed from a revision "
+            "(inside frames or one by one)"))
+        self.watch_replay_frames = r.register(Counter(
+            "store_watch_replay_frames_total",
+            "watch frames packed from the log for resumed frames "
+            "watchers: a batch txn's rows leave as they did live"))
 
 
 # stores aggregate here (one broadcaster seam per process in practice);
@@ -413,6 +421,12 @@ class SchedulerMetrics:
             "scheduler_watch_frame_events_total",
             "events delivered inside watch frames (the per-event path "
             "they replaced)",
+        ))
+        self.watch_line_events = r.register(Counter(
+            "scheduler_watch_line_events_total",
+            "pods the informer handed to this scheduler one by one, not "
+            "as rows of a frame: a watch line of its own (a single "
+            "write) or an item of a LIST",
         ))
         self.assume_batched_pods = r.register(Counter(
             "scheduler_assume_batched_pods_total",

@@ -462,26 +462,45 @@ def api_server():
     server.stop()
 
 
-def test_remote_frames_end_to_end(api_server):
+def _stop_soon(watch):
+    """Stop a watch without waiting: closing a chunked response reads it
+    to its end, which an idle stream reaches at its ``timeoutSeconds``."""
+    threading.Thread(target=watch.stop, daemon=True).start()
+
+
+def _cut_watch(inf):
+    """The informer's stream ends here, as at ``timeoutSeconds``: what it
+    had not yet read is gone, and a new watch resumes from its bookmark."""
+    old = inf._watch
+    inf._watch = inf._watch_from(inf.last_revision)
+    _stop_soon(old)
+
+
+@pytest.mark.parametrize("batch_arrives", ["live", "replayed"])
+def test_remote_frames_end_to_end(api_server, batch_arrives):
     from kubernetes_tpu.client.remote import RemoteStore
 
     rs = RemoteStore(api_server.url, retry_backoff=0.005)
     cs = Clientset(api_server.store)
     inf = SharedInformer(Clientset(rs).pods, metrics=rs.metrics)
     inf.start_manual()
-    # wait for the live stream: a batch committed BEFORE the watch
-    # connects is replayed from the log per-event (by design)
     assert _wait(lambda: inf._watch._resp is not None)
     cs.pods.create_many([make_pod(f"p{i}", cpu="100m") for i in range(5)])
+    if batch_arrives == "replayed":
+        # the batch was committed before this watch connected: the log
+        # replays it as the frame it left in, not per event
+        _cut_watch(inf)
     assert _wait(lambda: (inf.pump(), len(inf.list()))[-1] == 5)
     # the batch crossed the wire as ONE frame line
-    assert inf.stats["frames"] >= 1
-    assert inf.stats["frame_events"] >= 5
+    assert inf.stats["frames"] == 1
+    assert inf.stats["frame_events"] == 5
     # a per-event client against the same server sees plain events
     plain = _per_event_informer(Clientset(RemoteStore(api_server.url)).pods)
     plain.start_manual()
     assert _wait(lambda: plain._watch._resp is not None)
     cs.pods.create_many([make_pod(f"q{i}", cpu="100m") for i in range(3)])
+    if batch_arrives == "replayed":
+        _cut_watch(plain)
     assert _wait(lambda: (plain.pump(), len(plain.list()))[-1] == 8)
     assert plain.stats["frames"] == 0
     assert _wait(lambda: (inf.pump(), len(inf.list()))[-1] == 8)
@@ -693,47 +712,210 @@ def test_remote_pieces_with_and_without_a_field_selector(
     we.stop()
 
 
-@pytest.mark.parametrize("transport", ["store", "http"])
-def test_watch_resumed_between_two_pieces_loses_and_repeats_nothing(
-        api_server, small_bound, transport):
-    """A watch that ends after piece k resumes from piece k's fence
-    (``frame.revision``): the rest of the txn replays from the log, per
-    event, with no row lost and none repeated."""
+# ---------------------------------------------------------------------------
+# a resumed frames watch gets frames (ISSUE 31)
+# ---------------------------------------------------------------------------
+
+
+def _open_watch(api_server, transport, from_rev, frames=True):
+    if transport == "store":
+        return api_server.store.watch("Pod", from_revision=from_rev,
+                                      frames=frames)
     from kubernetes_tpu.client.remote import RemoteStore
 
+    w = RemoteStore(api_server.url, retry_backoff=0.005).watch(
+        "Pod", from_revision=from_rev, frames=frames)
+    assert _wait(lambda: w._resp is not None)
+    return w
+
+
+def _frame_fields(item):
+    if item.type != FRAME:
+        return (item.type, item.key, item.revision, item.object)
+    return (item.kind, item.types, item.keys, item.revisions,
+            item.prev_revisions, item.objects, item.txn)
+
+
+# rows of the 10-row bind the first watch had delivered when it ended:
+# none (the resume is before the txn), a piece (between two pieces), a
+# piece and a half (inside one), all but one, all (after the txn)
+@pytest.mark.parametrize("seen,want", [
+    (0, ["MODIFIED", 4, 4, 2, "ADDED", 3]),
+    (4, [4, 2, "ADDED", 3]),
+    (6, [4, "ADDED", 3]),
+    (8, [2, "ADDED", 3]),
+    (9, [1, "ADDED", 3]),
+    (10, ["ADDED", 3]),
+])
+@pytest.mark.parametrize("transport", ["store", "http"])
+def test_resumed_frames_watch_replays_a_txn_as_its_frames(
+        api_server, small_bound, transport, seen, want):
+    """A frames watch that resumes from a revision gets the log's batch
+    txns as the pieces they left in (the rest of one from the row after
+    the revision, cut like the live pieces), with the txn's id and its
+    prev-revision column, single writes between them in their order; a
+    plain watch's replay is what it was."""
     store = api_server.store
     cs = Clientset(store)
-    rs = RemoteStore(api_server.url, retry_backoff=0.005)
-
-    def open_watch(from_rev):
-        if transport == "store":
-            return store.watch("Pod", from_revision=from_rev, frames=True)
-        w = rs.watch("Pod", from_revision=from_rev, frames=True)
-        assert _wait(lambda: w._resp is not None)
-        return w
-
     n = 10
+    pre = [c.meta.resource_version for c in cs.pods.create_many(
+        [make_pod(f"p{i:03d}", cpu="100m") for i in range(n)])]
     rev0 = store.list("Pod")[1]
-    first = open_watch(rev0)
-    plain = store.watch("Pod", from_revision=rev0)
-    cs.pods.create_many([make_pod(f"p{i:03d}", cpu="100m") for i in range(n)])
-    head = _drain(first, 2, timeout=10.0)[:2]  # pieces 1 and 2 of 3 ...
-    first.stop()                               # ... and the watch ends
-    assert [len(h) for h in head] == [4, 4]
-    resumed = open_watch(head[-1].revision)
-    tail = _drain(resumed, n - 8, timeout=10.0)
-    assert _flatten(head + tail) == _flatten(_drain(plain, n))
+    live = store.watch("Pod", from_revision=rev0, frames=True)
+    live_plain = store.watch("Pod", from_revision=rev0)
+
+    def _label(d):
+        d["metadata"].setdefault("labels", {})["x"] = "y"
+        return d
+    store.guaranteed_update("Pod", "default", "p000", _label)
+    pre[0] = rev0 + 1
+    cs.pods.bind_many([Binding(pod_namespace="default", pod_name=f"p{i:03d}",
+                               node_name=f"n{i % 2}") for i in range(n)])
+    cs.pods.create(make_pod("solo", cpu="100m"))
+    cs.pods.create_many([make_pod(f"q{i}", cpu="100m") for i in range(3)])
+    live_items = _take_now(live)
+    plain_rows = _flatten(_take_now(live_plain))
+    assert [len(g) if g.type == FRAME else g.type for g in live_items] == [
+        "MODIFIED", 4, 4, 2, "ADDED", 3]
+
+    # rev0 + 1 is the label write, the bind's rows are rev0 + 2 ... + 11
+    from_rev = rev0 if seen == 0 else rev0 + 1 + seen
+    resumed = _open_watch(api_server, transport, from_rev)
+    got = _drain(resumed, len(want), timeout=10.0)
+    assert [len(g) if g.type == FRAME else g.type for g in got] == want
+    # none lost, none repeated: the rows are the plain stream's
+    rows = _flatten(got)
+    assert rows == plain_rows[len(plain_rows) - len(rows):]
+    assert len(rows) == n + 5 - (0 if seen == 0 else seen + 1)
     assert resumed.get(timeout=0.2) is None
-    resumed.stop()
-    plain.stop()
+    bind = [g for g in got if g.type == FRAME
+            and g.txn.startswith("bind_many")]
+    assert [r for g in bind for r in g.prev_revisions] == pre[seen:]
+    assert {g.txn for g in bind} <= {live_items[1].txn}
+    assert got[-1].txn == live_items[-1].txn and got[-1].prev_revisions is None
+    if seen in (0, 4, 8, 10):
+        # resumed at a live piece's fence: field for field the live pieces
+        tail = live_items[len(live_items) - len(got):]
+        assert [_frame_fields(g) for g in got] == [_frame_fields(t)
+                                                   for t in tail]
+    # a watcher that asked for no frames: event for event what it was
+    plain = _open_watch(api_server, transport, from_rev, frames=False)
+    replayed = _drain(plain, len(rows), timeout=10.0)
+    assert all(ev.type != FRAME for ev in replayed)
+    assert _flatten(replayed) == rows
+    if transport == "store":  # the log's own shared events, as ever
+        assert all(a.object is b[3] for a, b in zip(
+            replayed, plain_rows[len(plain_rows) - len(replayed):]))
+    for w in (live, live_plain, resumed, plain):
+        _stop_soon(w)
 
 
-def test_stream_timeout_does_not_fall_between_two_queued_pieces(
+def test_a_coalescing_window_replays_as_its_flush_frames(small_bound):
+    """The rows one coalescing window delivered replay folded and packed
+    as its flush did: each key's latest, by kind, the window's id."""
+    store = Store(coalesce_window_s=30.0)
+    cs = Clientset(store)
+    live = store.watch(frames=True)   # every kind
+    rev0 = store.revision
+    for i in range(5):
+        cs.pods.create(make_pod(f"p{i}", cpu="100m"))
+    cs.nodes.create(make_node("n0", cpu="4", memory="8Gi"))
+    for i in (1, 3):
+        store.guaranteed_update("Pod", "default", f"p{i}", lambda d: d)
+    store.flush_coalesced()
+    live_items = _take_now(live)
+    assert [len(g) if g.type == FRAME else g.kind for g in live_items] == [
+        4, 1, "Node"]
+    replayed = _take_now(store.watch(from_revision=rev0, frames=True))
+    assert [_frame_fields(g) for g in replayed] == [
+        _frame_fields(g) for g in live_items]
+    # from inside the window: the rows after it, folded alike
+    part = _take_now(store.watch("Pod", from_revision=rev0 + 3, frames=True))
+    assert [g.keys for g in part] == [
+        ["default/p4", "default/p1", "default/p3"]]
+    plain = _take_now(store.watch("Pod", from_revision=rev0))
+    assert [ev.type for ev in plain] == ["ADDED"] * 5 + ["MODIFIED"] * 2
+    store.close()
+
+
+def test_the_txn_index_is_trimmed_with_the_log(small_bound):
+    store = Store(event_log_window=8)
+    cs = Clientset(store)
+    pods = [make_pod(f"p{i:03d}", cpu="100m") for i in range(10)]
+    pre = [c.meta.resource_version for c in cs.pods.create_many(pods)]
+    assert [(t.first, t.last) for t in store._log_txns] == [(1, 10)]
+    cs.pods.bind_many([Binding(pod_namespace="default", pod_name=p.meta.name,
+                               node_name="n0") for p in pods])
+    # the creates have left the window and their txn the index with them;
+    # the bind's first two rows have left too, and its column still lines
+    # up with the rows that stay
+    assert [(t.first, t.last) for t in store._log_txns] == [(11, 20)]
+    assert store._log[0].revision == 13
+    got = _take_now(store.watch("Pod", from_revision=13, frames=True))
+    assert [g.revisions for g in got] == [[14, 15, 16, 17], [18, 19, 20]]
+    assert [r for g in got for r in g.prev_revisions] == pre[3:]
+    with pytest.raises(Exception, match="too old"):
+        store.watch("Pod", from_revision=11, frames=True)
+    # single writes push the last rows out: nothing is left to remember
+    for i in range(8):
+        cs.pods.create(make_pod(f"solo{i}", cpu="100m"))
+    assert not store._log_txns
+    assert all(ev.type != FRAME for ev in _take_now(
+        store.watch("Pod", from_revision=21, frames=True)))
+
+
+@pytest.mark.parametrize("transport", ["store", "http"])
+@pytest.mark.parametrize("pieces_read", [0, 1, 3, 8])
+def test_resumed_confirm_frames_confirm_a_wave_without_fallbacks(
+        api_server, monkeypatch, transport, pieces_read):
+    """The scheduler's pod watch ends with some of a wave's confirm
+    pieces unread (all, some, none): the resumed watch brings the rest as
+    frames, ``confirm_many`` takes them by the prev-revision fence, and
+    cache and queue end as after a wave read live."""
+    from kubernetes_tpu.client.remote import RemoteStore
+
+    monkeypatch.setattr(frames_mod, "FRAME_MAX_ROWS", 7)
+    worlds = []
+    for cut in (False, True):
+        store = api_server.store if cut else Store()
+        cs, sched = _world(store=RemoteStore(api_server.url)
+                           if cut and transport == "http" else store)
+        inf = sched.informers.informer("Pod")
+        Clientset(store).pods.create_many(
+            [make_pod(f"w-{i:04d}", cpu="100m", memory="128Mi")
+             for i in range(50)])
+        assert _wait(lambda: (sched.pump(), len(sched.queue))[-1] == 50)
+        lines = sched.metrics.watch_line_events.value
+        assert sched.schedule_pending_batch() == (50, 0)
+        if cut:
+            if transport == "http":   # the 8 pieces are in: read some
+                assert _wait(lambda: inf._watch._queue.qsize() == 8)
+            for _ in range(pieces_read):
+                inf.pump(max_events=1)
+            _cut_watch(inf)
+        assert _wait(lambda: (sched.pump(), inf.last_revision)[-1]
+                     == store.revision)
+        assert sched.metrics.watch_line_events.value == lines
+        worlds.append((cs, sched))
+    (cs_a, sched_a), (cs_b, sched_b) = worlds
+    assert (_cache_fingerprint(sched_b.cache)
+            == _cache_fingerprint(sched_a.cache))
+    assert _queue_keys(sched_b) == _queue_keys(sched_a) == []
+    assert sched_b.metrics.confirm_fallbacks.value == 0
+    assert (sched_b.metrics.watch_frames.value
+            == sched_a.metrics.watch_frames.value == 2 * 8)
+    assert (sched_b.metrics.watch_frame_events.value
+            == sched_a.metrics.watch_frame_events.value == 2 * 50)
+    for _cs, sched in worlds:
+        threading.Thread(target=sched.informers.stop_all, daemon=True).start()
+
+
+def test_stream_past_its_deadline_writes_what_was_queued_then_ends(
         api_server, small_bound, monkeypatch):
-    """``timeoutSeconds`` runs out while a txn's pieces are going out: the
-    stream ends after the txn's last piece, not between two of them (the
-    resume would replay the rest per event), and takes nothing that
-    comes after the txn with it."""
+    """``timeoutSeconds`` runs out while frames are going out: the stream
+    still writes every item that was on its queue when the deadline
+    passed, whichever txn it is of, and ends; what is committed after
+    that is left for the resume, which gets it as a frame."""
     import urllib.request
 
     encode = WatchFrame.wire_bytes
@@ -748,6 +930,7 @@ def test_stream_timeout_does_not_fall_between_two_queued_pieces(
     cs.pods.create_many([make_pod(f"p{i:03d}", cpu="100m") for i in range(n)])
     rev = api_server.store.list("Pod")[1]
     watchers = len(api_server.store._watchers)
+    held0 = api_server.watch_held_frames.value
     resp = urllib.request.urlopen(
         f"{api_server.url}/api/v1/pods?watch=true&frames=1"
         f"&timeoutSeconds=1&resourceVersion={rev}", timeout=10.0)
@@ -755,16 +938,57 @@ def test_stream_timeout_does_not_fall_between_two_queued_pieces(
     t0 = _time.monotonic()
     cs.pods.bind_many([Binding(pod_namespace="default", pod_name=f"p{i:03d}",
                                node_name=f"n{i % 2}") for i in range(n)])
-    # a second txn, queued behind the first before the deadline: past the
-    # deadline the stream takes the first txn's pieces and nothing else
+    # a second txn, queued behind the first before the deadline: at the
+    # deadline (two pieces are out) the third piece and this frame wait
     cs.pods.create_many([make_pod(f"q{i}", cpu="100m") for i in range(2)])
+    # a third, committed past the deadline while those two go out
+    _time.sleep(max(0.0, t0 + 1.3 - _time.monotonic()))
+    cs.pods.create_many([make_pod(f"late{i}", cpu="100m") for i in range(2)])
     lines = [json.loads(raw) for raw in resp if raw.strip()]  # to a clean end
-    assert _time.monotonic() - t0 >= 1.0
+    assert 2.0 <= _time.monotonic() - t0 < 4.0
     got = [WatchFrame.from_wire(d) for d in lines]
-    assert [len(g) for g in got] == _piece_lens(n)
-    assert len({g.txn for g in got}) == 1 and got[0].txn.startswith("bind_many")
+    assert [len(g) for g in got] == _piece_lens(n) + [2]
+    assert [g.txn.split("-")[0] for g in got] == ["bind_many"] * 3 + [
+        "create_many"]
     assert [k for g in got for k in g.keys] == [
-        f"default/p{i:03d}" for i in range(n)]
+        f"default/p{i:03d}" for i in range(n)] + ["default/q0", "default/q1"]
+    assert api_server.watch_held_frames.value - held0 == 2
+    monkeypatch.undo()
+    resumed = _open_watch(api_server, "http", got[-1].revision)
+    late = _drain(resumed, 1, timeout=10.0)
+    assert [g.keys for g in late] == [["default/late0", "default/late1"]]
+    assert late[0].type == FRAME
+    _stop_soon(resumed)
+
+
+def test_a_resumed_watch_is_one_replay_span_counted_by_rows_and_frames(
+        small_bound):
+    """``store.watch.replay``: once per resumed watch, never per row, with
+    the rows and the frames it put; the two counters move alike."""
+    from kubernetes_tpu.utils import tracing
+    from kubernetes_tpu.utils.metrics import DEFAULT_STORE_METRICS as m
+
+    store = Store()
+    cs = Clientset(store)
+    cs.pods.create_many([make_pod(f"p{i}", cpu="100m") for i in range(9)])
+    cs.pods.create(make_pod("solo", cpu="100m"))
+    cs.nodes.create(make_node("n0", cpu="4", memory="8Gi"))
+    tr = tracing.enable()
+    try:
+        ev0, fr0 = m.watch_replay_events.value, m.watch_replay_frames.value
+        store.watch("Pod", frames=True)              # from now: no replay
+        store.watch("Pod", from_revision=2, frames=True)
+        store.watch("Pod", from_revision=2)
+        spans = [sp for sp in tr.background if sp.name == "store.watch.replay"]
+    finally:
+        tracing.disable()
+    assert [sp.attrs for sp in spans] == [
+        {"kind": "Pod", "from_revision": 2, "events": 8, "frames": 2,
+         "txns": 1},
+        {"kind": "Pod", "from_revision": 2, "events": 8, "frames": 0,
+         "txns": 0}]
+    assert m.watch_replay_events.value - ev0 == 16
+    assert m.watch_replay_frames.value - fr0 == 2
 
 
 # ---------------------------------------------------------------------------
