@@ -16,6 +16,7 @@ import sys
 from ..admission import default_chain
 from ..daemon import install_signal_stop, wait_forever
 from ..store.store import Store
+from .collector import Collector
 from .server import APIServer
 
 
@@ -122,10 +123,14 @@ def main(argv=None) -> int:
     server = APIServer(store, host=args.host, port=args.port, tokens=tokens,
                        authenticator=authenticator,
                        authorizer=authorizer, auditor=auditor, tls=tls)
+    # this process owns its collector: no oldest-generation pass but the
+    # daemon's own, after an answer or on an idle tick (collector.py)
+    server.collector = Collector(lambda: store.revision, server.registry)
+    server.collector.install()
     server.start()
     print(f"apiserver serving on {server.url}", flush=True)
     stop = install_signal_stop()
-    wait_forever(stop)
+    wait_forever(stop, tick=server.collector.tick)
     server.stop()
     return 0
 

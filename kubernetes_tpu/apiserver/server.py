@@ -179,6 +179,11 @@ class APIServer:
             "create requests answered 429 + Retry-After by the overload "
             "admission gate (priority tier below the protected floor)"))
         self._telemetry_mu = threading.Lock()
+        # the process's collector policy, where the daemon installed one
+        # (apiserver/collector.py): told when an answer has been written,
+        # and asked what its pauses cost a request.  None wherever this
+        # server is embedded, and then nothing is done.
+        self.collector = None
         handler = _make_handler(self)
         if tls is not None:
             # The handshake must run in the per-connection worker thread,
@@ -277,6 +282,10 @@ def _make_handler(server: APIServer):
                       f"{(time.perf_counter() - self._t_request) * 1e3:.3f}")
             if self._store_s is not None:
                 timing += f", store;dur={self._store_s * 1e3:.3f}"
+            if server.collector is not None:
+                # collector pauses that fell inside this request
+                timing += (f", gc;dur="
+                           f"{(server.collector.pause_s - self._gc_s0) * 1e3:.3f}")
             self.send_header("Server-Timing", timing)
             self.end_headers()
             self.wfile.write(data)
@@ -532,6 +541,8 @@ def _make_handler(server: APIServer):
         def _route(self, method: str) -> None:
             start = self._t_request = time.perf_counter()
             self._store_s = None
+            collector = server.collector
+            self._gc_s0 = collector.pause_s if collector is not None else 0.0
             server.request_count.inc()
             self._last_code = 0
             acquired = False
@@ -586,6 +597,10 @@ def _make_handler(server: APIServer):
                         audit_user,
                         verb, resource, ns, name, code=self._last_code,
                     )
+                if collector is not None:
+                    # the answer's bytes are out: the txn boundary at which
+                    # the daemon's collector may take its pass
+                    collector.after_request()
 
         def do_GET(self):
             self._route("GET")

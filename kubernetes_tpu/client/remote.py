@@ -110,15 +110,16 @@ def _parse_retry_after(headers) -> Optional[float]:
 
 
 def _server_timing(value: Optional[str]) -> dict:
-    """``Server-Timing: handle;dur=<ms>, store;dur=<ms>`` (the apiserver's
-    own account of one request) as the ``remote.request`` span's
-    ``server_s`` / ``store_s``.  A part the server did not send — an
-    older server, a verb that makes no store call — stays absent, never
-    0."""
+    """``Server-Timing: handle;dur=<ms>, store;dur=<ms>, gc;dur=<ms>`` (the
+    apiserver's own account of one request) as the ``remote.request``
+    span's ``server_s`` / ``store_s`` / ``gc_s``.  A part the server did
+    not send — an older server, a verb that makes no store call, a server
+    embedded where no daemon owns the collector — stays absent, never 0."""
     out = {}
     for part in (value or "").split(","):
         name, _, dur = part.strip().partition(";dur=")
-        attr = {"handle": "server_s", "store": "store_s"}.get(name)
+        attr = {"handle": "server_s", "store": "store_s",
+                "gc": "gc_s"}.get(name)
         if attr is not None:
             try:
                 out[attr] = float(dur) / 1e3
@@ -525,8 +526,9 @@ class RemoteStore:
         ``remote.request`` span: what went out and came back, how long
         this side spent encoding and decoding, and — from the server's
         ``Server-Timing`` header — how long the apiserver (``server_s``)
-        and the store inside it (``store_s``) took, so a child process's
-        time reaches this trace.  ``items``: how many objects a batch
+        and the store inside it (``store_s``) took, and what the daemon's
+        collector stalled of it (``gc_s``), so a child process's time
+        reaches this trace.  ``items``: how many objects a batch
         verb carries."""
         tr = tracing.current()
         with (tr.span("remote.request", cat="client", method=method,

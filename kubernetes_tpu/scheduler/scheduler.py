@@ -859,7 +859,8 @@ class Scheduler:
         # commit): at 150k pods a collection pass walks millions of live
         # objects and costs more than everything it frees (the Go
         # reference has a concurrent GC; Python's stop-the-world pass
-        # must not land inside the hot loop).
+        # must not land inside the hot loop).  The apiserver daemon's
+        # counterpart is apiserver/collector.py.
         import gc as _gc
 
         gc_was_enabled = _gc.isenabled()

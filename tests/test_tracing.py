@@ -509,6 +509,53 @@ def test_remote_request_span_carries_the_servers_own_time():
 
 
 @pytest.mark.timeout(60)
+def test_remote_request_span_carries_the_collectors_pause():
+    """Where the daemon's collector policy is installed (as
+    ``apiserver/__main__.py`` installs it) every answer's ``Server-Timing``
+    has a third field, and it lands on the ``remote.request`` span as
+    ``gc_s``: present, not negative, inside the server's own time.  So a
+    stall of the apiserver's collector shows in the scheduler's wave tree."""
+    import gc
+
+    from kubernetes_tpu.apiserver import APIServer
+    from kubernetes_tpu.apiserver.collector import Collector
+    from kubernetes_tpu.client.remote import RemoteStore
+
+    class StoreWithAPass(Store):
+        def bind_many(self, items):
+            gc.collect()  # a full pass begun inside the request
+            return super().bind_many(items)
+
+    store = StoreWithAPass()
+    server = APIServer(store)
+    server.collector = Collector(lambda: store.revision, server.registry)
+    server.collector.install()
+    server.start()
+    try:
+        remote = RemoteStore(server.url)
+        cs = Clientset(remote)
+        cs.nodes.create(make_node("n0", cpu="8", memory="16Gi"))
+        cs.pods.create_many([make_pod(f"p{i}", cpu="100m") for i in range(6)])
+        tr = tracing.enable()
+        assert remote.bind_many([("default", f"p{i}", "n0")
+                                 for i in range(6)]) == [None] * 6
+        sp, = (s for s in tr.background if s.name == "remote.request"
+               and s.attrs["path"] == "/api/v1/bindings:batch")
+        a = sp.attrs
+        assert 0 < a["gc_s"] <= a["store_s"] <= a["server_s"] <= sp.duration
+        cs.pods.list()
+        lists = [s for s in tr.background if s.name == "remote.request"
+                 and s.attrs["path"].startswith("/api/v1/pods")]
+        assert lists and all(s.attrs["gc_s"] == 0 for s in lists), (
+            "no pass fell inside a LIST")
+        assert server.registry.get("apiserver_gc_freezes_total").value >= 1
+    finally:
+        server.stop()
+        server.collector.uninstall()
+    assert gc.get_freeze_count() == 0
+
+
+@pytest.mark.timeout(60)
 def test_debug_endpoints_serve_traces_and_flightrecorder():
     """The daemon health server's ``/debug/traces`` (Chrome export) and
     ``/debug/flightrecorder`` endpoints — and their honest
