@@ -51,7 +51,8 @@ from ..store.store import (
     NotFoundError,
     Store,
 )
-from ..utils.metrics import Counter, Histogram, Registry
+from ..utils.metrics import (DEFAULT_STORE_METRICS, Counter, Histogram,
+                             Registry)
 
 logger = logging.getLogger("kubernetes_tpu.apiserver")
 
@@ -168,6 +169,10 @@ class APIServer:
         self.apiservice_status_failures = self.registry.register(Counter(
             "apiserver_apiservice_status_failures_total",
             "best-effort APIService availability updates that failed"))
+        # the store runs in this process: its counters (frames packed,
+        # replays, bind rows deferred, payloads built) leave by /metrics
+        for m in DEFAULT_STORE_METRICS.registry.snapshot():
+            self.registry.register(m)
         # overload control (ISSUE 17): an AdmissionThrottle (or anything
         # with .admit(resource, bodies) -> Optional[retry_after_s]) gates
         # the create paths at rung 3; None = always admit.  Distinct from

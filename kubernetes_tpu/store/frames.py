@@ -25,7 +25,12 @@ or, past :data:`FRAME_MAX_ROWS` rows, one piece of it in revision order
   collapses to one integer compare per column entry;
 - **shared raw-view payloads**: ``objects`` are the same shallow views /
   event copies the per-event path would have carried, shared-immutable
-  (the informer contract: consumers never mutate wire payloads).
+  (the informer contract: consumers never mutate wire payloads).  A
+  packed frame holds its events and builds this column on first touch
+  (encode, ``select``, an informer's decode): a ``bind_many`` row's
+  payload is derived only then (``store.BoundPodEvent``), and a consumer
+  of ``keys`` / ``revisions`` / ``prev_revisions`` / ``node_names``
+  alone never builds one.
 
 Consumers that predate frames are never broken: frames are **opt-in per
 watcher** (``Store.watch(..., frames=True)``), the apiserver serves them
@@ -83,15 +88,16 @@ class WatchFrame:
     """
 
     __slots__ = ("kind", "types", "keys", "revisions", "prev_revisions",
-                 "objects", "txn", "_node_names", "_wire_b")
+                 "txn", "_objects", "_events", "_node_names", "_wire_b")
 
     # duck-typed dispatch marker (``ev.type == FRAME``) for consumers
     # that pull mixed WatchEvent/WatchFrame items off one watch queue
     type = FRAME
 
     def __init__(self, kind: str, types: list, keys: list, revisions: list,
-                 objects: list, prev_revisions: Optional[list] = None,
-                 txn: Optional[str] = None):
+                 objects: Optional[list], prev_revisions: Optional[list] = None,
+                 txn: Optional[str] = None, events: Optional[list] = None,
+                 node_names: Optional[list] = None):
         self.kind = kind
         self.types = types
         self.keys = keys
@@ -99,13 +105,16 @@ class WatchFrame:
         # -1 = unknown (creates, deletes, plain updates); >= 0 only where
         # the emitting txn knew the pre-transition revision (bind_many)
         self.prev_revisions = prev_revisions
-        self.objects = objects
+        # the payload column, or None until first touch where the frame
+        # was packed from ``events`` (pack_frames)
+        self._objects = objects
+        self._events = events
         # correlation id minted by the emitting store txn (ISSUE 7):
         # the same id appears on the store's txn span, this frame, the
         # informer's frame-apply span, and the scheduler's confirm span,
         # so one trace shows the store→informer→confirm propagation
         self.txn = txn
-        self._node_names: Optional[list] = None
+        self._node_names = node_names  # a bind txn knows them at commit
         self._wire_b: Optional[bytes] = None
 
     def __len__(self) -> int:
@@ -116,6 +125,16 @@ class WatchFrame:
         """The frame's resourceVersion fence: a consumer that applied
         this frame has seen everything up to its LAST event."""
         return self.revisions[-1] if self.revisions else 0
+
+    @property
+    def objects(self) -> list:
+        got = self._objects
+        if got is None:
+            from .store import event_payloads
+
+            # benign race, like wire_bytes: equal columns, last one wins
+            got = self._objects = event_payloads(self._events)
+        return got
 
     @property
     def node_names(self) -> list:
@@ -171,6 +190,9 @@ class WatchFrame:
     def events(self) -> Iterator:
         """Expand back into the exact per-event sequence (order, content,
         revisions) — the compatibility path for per-event consumers."""
+        if self._events is not None:
+            yield from self._events  # the rows this frame was packed from
+            return
         from .store import WatchEvent
 
         for i in range(len(self.keys)):
@@ -232,26 +254,38 @@ class WatchFrame:
 
 def pack_frames(kind: str, events: list,
                 prev_revisions: Optional[list] = None,
-                txn: Optional[str] = None) -> list:
+                txn: Optional[str] = None,
+                bound: Optional[tuple] = None) -> list:
     """One correlated batch (revision order, single kind) as a list of
     :class:`WatchFrame` pieces of at most :data:`FRAME_MAX_ROWS` rows
     each, in order, ``prev_revisions`` sliced alike, every piece carrying
     the txn's id.  A batch at or under the bound is one frame; a one-row
     remainder is still a frame (its prev-revision fence column is kept).
     The pieces' fences (``frame.revision``) increase strictly, so a
-    consumer that applied some of them resumes after its last one."""
+    consumer that applied some of them resumes after its last one.
+
+    ``bound`` is a ``bind_many`` txn's own columns, (keys, revisions,
+    node names), one entry per event: the pieces slice them instead of
+    reading the events back, and know their ``node_names`` from the
+    start.  Every piece holds its events and builds ``objects`` on first
+    touch."""
     out = []
     for lo in range(0, len(events), FRAME_MAX_ROWS):
-        evs = events[lo:lo + FRAME_MAX_ROWS]
+        hi = lo + FRAME_MAX_ROWS
+        evs = events[lo:hi]
+        if bound is None:
+            types = [e.type for e in evs]
+            keys = [e.key for e in evs]
+            revisions = [e.revision for e in evs]
+            node_names = None
+        else:
+            types = [evs[0].type] * len(evs)
+            keys, revisions, node_names = (col[lo:hi] for col in bound)
         out.append(WatchFrame(
-            kind,
-            [e.type for e in evs],
-            [e.key for e in evs],
-            [e.revision for e in evs],
-            [e.object for e in evs],
+            kind, types, keys, revisions, None,
             prev_revisions=(None if prev_revisions is None
-                            else prev_revisions[lo:lo + FRAME_MAX_ROWS]),
-            txn=txn,
+                            else prev_revisions[lo:hi]),
+            txn=txn, events=evs, node_names=node_names,
         ))
     return out
 
@@ -260,9 +294,9 @@ def event_wire_bytes(ev) -> bytes:
     """Encoded watch line for one plain :class:`~.store.WatchEvent`
     (wire form + newline), computed once per event and shared across
     every streaming client while :data:`SHARED_ENCODE` is on.  The cache
-    rides the event object itself (``object.__setattr__`` through the
-    frozen dataclass): events are shared-immutable across all watcher
-    queues, so the first client to encode pays and the rest reuse.
+    rides the event object itself (its ``_wire_b`` slot): events are
+    shared-immutable across all watcher queues, so the first client to
+    encode pays and the rest reuse.
     Benign race: concurrent encoders produce identical bytes."""
     import json
 
@@ -278,5 +312,5 @@ def event_wire_bytes(ev) -> bytes:
         "object": ev.object,
     }).encode() + b"\n"
     if SHARED_ENCODE:
-        object.__setattr__(ev, "_wire_b", line)
+        ev._wire_b = line
     return line

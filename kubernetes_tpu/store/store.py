@@ -30,7 +30,7 @@ import collections
 import queue
 import threading
 import time
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Callable, Iterator, NamedTuple, Optional
 
 from .. import faults
@@ -95,13 +95,91 @@ class AlreadyExistsError(Exception):
     pass
 
 
-@dataclass(frozen=True)
 class WatchEvent:
-    type: str  # ADDED | MODIFIED | DELETED
-    kind: str
-    key: str  # namespace/name
-    revision: int
-    object: dict  # serialized object (deep-copied per consumer)
+    """One committed transition.  Shared-immutable by contract: one event
+    object goes to the log and to every watcher, and nobody changes it
+    (``frames.event_wire_bytes`` hangs the encoded line on ``_wire_b``)."""
+
+    __slots__ = ("type", "kind", "key", "revision", "object", "_wire_b")
+
+    def __init__(self, type: str, kind: str, key: str, revision: int,
+                 object: dict):
+        self.type = type  # ADDED | MODIFIED | DELETED
+        self.kind = kind
+        self.key = key  # namespace/name
+        self.revision = revision
+        self.object = object  # serialized object (deep-copied per consumer)
+
+    def __eq__(self, other):
+        if not isinstance(other, WatchEvent):
+            return NotImplemented
+        return ((self.type, self.kind, self.key, self.revision, self.object)
+                == (other.type, other.kind, other.key, other.revision,
+                    other.object))
+
+    def __repr__(self) -> str:
+        return (f"WatchEvent(type={self.type!r}, kind={self.kind!r}, "
+                f"key={self.key!r}, revision={self.revision!r}, "
+                f"object={self.object!r})")
+
+
+class BoundPodEvent(WatchEvent):
+    """The MODIFIED event of one ``bind_many`` row.  It carries no copy:
+    it holds the stored pod's dict, the node and the revision, and
+    ``object`` derives the payload when somebody reads it — a WAL or a
+    replica at commit, a watcher's thread when it encodes, nobody for a
+    row no one asks for.  By the store's invariant (see :class:`Store`)
+    the payload is, entry for entry, what a copy taken at commit held,
+    whenever it is built: the only values written in place after commit
+    are the two this event overrides with its own."""
+
+    __slots__ = ("node_name", "_data", "_payload")
+
+    def __init__(self, key: str, revision: int, node_name: str, data: dict):
+        self.type = MODIFIED
+        self.kind = "Pod"
+        self.key = key
+        self.revision = revision
+        self.node_name = node_name
+        self._data = data
+        self._payload = None
+
+    def _build(self) -> dict:
+        # {**data, "spec": {**spec, "nodeName": node}, "metadata": {**meta,
+        # "resourceVersion": revision}}, by dict.copy (a table copy)
+        got = self._data.copy()
+        spec = got["spec"] = got["spec"].copy()
+        spec["nodeName"] = self.node_name
+        meta = got["metadata"] = got["metadata"].copy()
+        meta["resourceVersion"] = self.revision
+        self._payload = got
+        return got
+
+    @property
+    def object(self) -> dict:
+        got = self._payload
+        if got is None:
+            got = self._build()
+            DEFAULT_STORE_METRICS.event_payloads_built.inc()
+        return got
+
+
+def event_payloads(events: list) -> list:
+    """``[ev.object for ev in events]``, the derived ones counted once."""
+    out = []
+    built = 0
+    for ev in events:
+        if type(ev) is BoundPodEvent:
+            got = ev._payload
+            if got is None:
+                got = ev._build()
+                built += 1
+            out.append(got)
+        else:
+            out.append(ev.object)
+    if built:
+        DEFAULT_STORE_METRICS.event_payloads_built.inc(built)
+    return out
 
 
 @dataclass
@@ -209,14 +287,31 @@ def _replayed_pieces(t: _LogTxn, rows: list) -> list:
                        if len(evs) > 1
                        else evs)
         return out
+    if t.prev is None:
+        return frames_mod.pack_frames(rows[0].kind, rows, txn=t.txn)
+    # a bind txn: its rows are BoundPodEvents, whose columns are read
+    # without building a payload
     lo = rows[0].revision - t.first
-    prev = None if t.prev is None else t.prev[lo:lo + len(rows)]
-    return frames_mod.pack_frames(rows[0].kind, rows, prev_revisions=prev,
-                                  txn=t.txn)
+    return frames_mod.pack_frames(
+        rows[0].kind, rows, prev_revisions=t.prev[lo:lo + len(rows)],
+        txn=t.txn,
+        bound=([ev.key for ev in rows], [ev.revision for ev in rows],
+               [ev.node_name for ev in rows]))
 
 
 class Store:
-    """In-process strongly-ordered object store (etcd3 + watch-cache analogue)."""
+    """In-process strongly-ordered object store (etcd3 + watch-cache analogue).
+
+    **Invariant: a dict the store holds is never changed in place, except
+    by** :meth:`bind_many`, **which sets** ``spec.nodeName`` **and**
+    ``metadata.resourceVersion`` (and creates a missing ``spec``).  Every
+    other write verb replaces the ``_Item`` and its dicts wholesale
+    (``update``, ``delete`` of an object with finalizers,
+    ``apply_replicated``, ``install_snapshot``).  That is what lets a
+    :class:`BoundPodEvent` hold the stored dict instead of a copy: it
+    overrides those two values with its own, so neither a later re-bind
+    nor any other write can reach an event already committed
+    (``tests/test_bind_columns.py`` walks every verb)."""
 
     def __init__(self, event_log_window: int = 100_000,
                  data_dir: Optional[str] = None, fsync: bool = False,
@@ -279,6 +374,10 @@ class Store:
                         revision=int(data.get("metadata", {}).get("resourceVersion", rev)),
                     )
             self._wal.open()
+        # a batch txn's rows reach the log in one extend where nothing is
+        # asked of each: no WAL record, no _replicate override
+        self._log_in_bulk = (self._wal is None and
+                             type(self)._replicate is Store._replicate)
 
     def compact(self) -> None:
         """Write a snapshot and truncate the WAL (etcd compaction).  No
@@ -452,10 +551,11 @@ class Store:
         batch path where hundreds of thousands of bindings land at once.
 
         Returns one entry per item: None on success, else an error string
-        ("not found" / "conflict: <node>").  Per-pod watch events are still
-        emitted (informers depend on them); their objects share the stored
-        containers/status structures and own fresh spec/metadata dicts —
-        the only fields this path ever mutates in place."""
+        ("not found" / "conflict: <node>").  The txn is committed by
+        columns: the loop does the CAS and appends to the txn's columns,
+        and a row's watch event (:class:`BoundPodEvent`) holds the stored
+        dict, not a copy — its payload is derived when somebody reads it,
+        which a watcher's frame does a piece at a time, after the answer."""
         faults.hit("store.commit", op="bind_many", kind="Pod")
         txn = tracing.next_txn("bind_many")
         tr = tracing.current()
@@ -465,49 +565,80 @@ class Store:
             return self._bind_many_locked(items, txn, sp)
 
     def _bind_many_locked(self, items, txn, sp) -> list[Optional[str]]:
-        results: list[Optional[str]] = []
+        results: list[Optional[str]] = [None] * len(items)
+        # the txn's columns, one entry per committed row
+        keys: list[str] = []
+        node_names: list[str] = []
+        prev_revs: list[int] = []
+        stored: list[dict] = []
+        errors = 0  # (an item's slot in results: len(keys) + errors)
+        # the per-item seam below is asked only of an armed plan
+        plan = faults.active_plan()
         with self._mu:
-            bucket = self._objects.setdefault("Pod", {})
-            events: list[WatchEvent] = []
-            prev_revs: list[int] = []
-            for namespace, name, node_name in items:
-                key = object_key(namespace, name)
-                # per-item seam: ONE pod's CAS fails while the rest of
-                # the batch commits (the real-world partial-bind shape) —
-                # surfaced as this item's error string, never an exception
-                if faults.hit("scheduler.bind", pod=key, node=node_name,
-                              via="bind_many") is not None:
-                    results.append("injected: bind fault")
-                    continue
-                item = bucket.get(key)
-                if item is None:
-                    results.append("not found")
-                    continue
-                spec = item.data.setdefault("spec", {})
-                cur = spec.get("nodeName", "")
-                if cur and cur != node_name:
-                    results.append(f"conflict: already bound to {cur}")
-                    continue
-                prev_rev = item.revision
-                rev = self._next_rev()
-                spec["nodeName"] = node_name
-                item.data["metadata"]["resourceVersion"] = rev
-                item.revision = rev
-                ev_obj = {
-                    **item.data,
-                    "spec": dict(spec),
-                    "metadata": dict(item.data["metadata"]),
-                }
-                events.append(WatchEvent(MODIFIED, "Pod", key, rev, ev_obj))
-                # the columnar-confirm fence: the revision this pod held
-                # BEFORE the bind CAS — a consumer that assumed the pod at
-                # exactly this revision knows nothing else changed
-                prev_revs.append(prev_rev)
-                results.append(None)
-            n_frames = self._emit_many(events, prev_revisions=prev_revs,
-                                       txn=txn)
-        sp.set(committed=len(events), frames=n_frames,
-               errors=sum(1 for r in results if r is not None))
+            get = self._objects.setdefault("Pod", {}).get
+            rev = 0  # the txn's last revision; 0 = none allocated yet
+            try:
+                for namespace, name, node_name in items:
+                    key = f"{namespace}/{name}" if namespace else name
+                    # per-item seam: ONE pod's CAS fails while the rest of
+                    # the batch commits (the real-world partial-bind
+                    # shape) — surfaced as this item's error string,
+                    # never an exception
+                    if plan is not None and faults.hit(
+                            "scheduler.bind", pod=key, node=node_name,
+                            via="bind_many") is not None:
+                        results[len(keys) + errors] = "injected: bind fault"
+                        errors += 1
+                        continue
+                    item = get(key)
+                    if item is None:
+                        results[len(keys) + errors] = "not found"
+                        errors += 1
+                        continue
+                    data = item.data
+                    spec = data.get("spec")
+                    if spec is None:
+                        spec = data["spec"] = {}
+                    cur = spec.get("nodeName", "")
+                    if cur and cur != node_name:
+                        results[len(keys) + errors] = (
+                            f"conflict: already bound to {cur}")
+                        errors += 1
+                        continue
+                    # the columnar-confirm fence: the revision this pod
+                    # held BEFORE the bind CAS — a consumer that assumed
+                    # the pod at exactly this revision knows nothing else
+                    # changed
+                    prev_revs.append(item.revision)
+                    if rev:
+                        rev += 1
+                    else:
+                        # the txn's first row asks (a replicated store
+                        # checks its quorum there); the rest count on
+                        rev = self._next_rev()
+                    spec["nodeName"] = node_name
+                    data["metadata"]["resourceVersion"] = rev
+                    item.revision = rev
+                    keys.append(key)
+                    node_names.append(node_name)
+                    stored.append(data)
+            finally:
+                if rev:
+                    self._rev = rev
+            n = len(keys)
+            revisions = list(range(rev - n + 1, rev + 1))
+            # the log's rows, made from the columns in one pass
+            events = list(map(BoundPodEvent, keys, revisions, node_names,
+                              stored))
+            n_frames = self._emit_many(
+                events, prev_revisions=prev_revs, txn=txn,
+                bound=(keys, revisions, node_names))
+            deferred = (n if self._log_in_bulk else
+                        sum(1 for ev in events if ev._payload is None))
+        if deferred:
+            DEFAULT_STORE_METRICS.bind_rows_deferred.inc(deferred)
+        sp.set(committed=n, frames=n_frames, errors=errors,
+               deferred=deferred)
         return results
 
     def guaranteed_update(
@@ -542,11 +673,15 @@ class Store:
             if expect_rev is not None and item.revision != expect_rev:
                 raise ConflictError(f"{kind} {key}")
             rev = self._next_rev()
-            if item.data["metadata"].get("finalizers"):
-                item.data["metadata"]["deletionRevision"] = rev
-                item.data["metadata"]["resourceVersion"] = rev
-                item.revision = rev
-                marked = _fast_deepcopy(item.data)
+            meta = item.data["metadata"]
+            if meta.get("finalizers"):
+                # a new item, not a mark in place (the class's invariant):
+                # a bind event may still hold the dicts this one replaces
+                data = {**item.data,
+                        "metadata": {**meta, "deletionRevision": rev,
+                                     "resourceVersion": rev}}
+                bucket[key] = _Item(data=data, revision=rev)
+                marked = _fast_deepcopy(data)
                 self._emit(WatchEvent(MODIFIED, kind, key, rev, marked))
                 return marked
             del bucket[key]
@@ -880,26 +1015,33 @@ class Store:
 
     def _emit_many(self, events: list[WatchEvent],
                    prev_revisions: Optional[list[int]] = None,
-                   txn: Optional[str] = None) -> int:
+                   txn: Optional[str] = None,
+                   bound: Optional[tuple] = None) -> int:
         """Fan one correlated batch out: WAL + log stay per-event (the
-        replay window and durability framing are unchanged), but every
-        frame-aware watcher receives the txn column-packed — as
+        replay window and durability framing are unchanged; where there is
+        neither a WAL nor a replica the log takes the rows in one call),
+        but every frame-aware watcher receives the txn column-packed — as
         :class:`~.frames.WatchFrame` pieces of at most
         ``frames.FRAME_MAX_ROWS`` rows, in revision order, all enqueued
         here under the caller's store-lock hold (one frame, one queue
         put, one informer lock hold when the txn fits the bound).
         Per-event watchers (kubectl -w, controllers, pre-frame clients)
         see the identical event sequence they always did.  Returns the
-        number of frames packed (0 when nobody wanted one)."""
+        number of frames packed (0 when nobody wanted one).  ``bound``
+        is a ``bind_many`` txn's own (keys, revisions, node names): its
+        frames slice them (``frames.pack_frames``)."""
         if not events:
             return 0
         # ordering barrier: a batch txn fans out at commit, so anything
         # buffered in an open coalescing window must reach the queues
         # first — watchers see revisions in order, no fence violations
         self._flush_pending_locked()
-        for ev in events:
-            self._append_log(ev)
-            self._replicate(ev)
+        if self._log_in_bulk:
+            self._log.extend(events)  # deque maxlen trims the window in C
+        else:
+            for ev in events:
+                self._append_log(ev)
+                self._replicate(ev)
         if len(events) > 1:  # (a txn of one row goes out as the event)
             self._log_txns.append(_LogTxn(
                 events[0].revision, events[-1].revision, txn, prev_revisions,
@@ -915,7 +1057,8 @@ class Store:
             if wants_frames and want_frame:
                 if not pieces:  # packed once, shared-immutable
                     pieces = frames_mod.pack_frames(
-                        kind, events, prev_revisions=prev_revisions, txn=txn)
+                        kind, events, prev_revisions=prev_revisions, txn=txn,
+                        bound=bound)
                     DEFAULT_STORE_METRICS.watch_frames.inc(len(pieces))
                 for frame in pieces:
                     q.put(frame)

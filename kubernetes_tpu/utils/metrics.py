@@ -318,6 +318,15 @@ class StoreMetrics:
             "store_watch_replay_frames_total",
             "watch frames packed from the log for resumed frames "
             "watchers: a batch txn's rows leave as they did live"))
+        self.bind_rows_deferred = r.register(Counter(
+            "store_bind_rows_deferred_total",
+            "rows bind_many committed without building their watch "
+            "payload (no WAL record or replica asked for it at commit)"))
+        self.event_payloads_built = r.register(Counter(
+            "store_event_payloads_built_total",
+            "watch payloads of bind_many rows derived on demand (a "
+            "frame's encode, a per-event reader, a WAL record): it "
+            "trails store_bind_rows_deferred_total by what nobody read"))
 
 
 # stores aggregate here (one broadcaster seam per process in practice);

@@ -77,6 +77,42 @@ def test_remote_bind_and_batch(remote):
     assert remote.pods.get("p2").spec.node_name == "n2"
 
 
+def _store_counters(url: str) -> dict:
+    with urllib.request.urlopen(url + "/metrics") as r:
+        lines = r.read().decode().splitlines()
+    return {ln.split()[0]: float(ln.split()[1]) for ln in lines
+            if ln.startswith("store_")}
+
+
+def test_metrics_serve_the_stores_bind_counters(server, remote):
+    """The store runs in the apiserver's process: `/metrics` carries its
+    counters.  A batch bind defers every row's watch payload; a frames
+    watcher's encode builds them, a piece at a time, after the answer."""
+    for i in range(5):
+        remote.pods.create(make_pod(f"p{i}"))
+    _, rev = remote.pods.list()
+    before = _store_counters(server.url)
+    assert {"store_bind_rows_deferred_total",
+            "store_event_payloads_built_total",
+            "store_watch_frames_total"} <= set(before)
+    errs = remote.pods.bind_many(
+        [Binding(pod_name=f"p{i}", node_name="n1") for i in range(5)])
+    assert errs == [None] * 5
+    after = _store_counters(server.url)
+    assert (after["store_bind_rows_deferred_total"]
+            - before["store_bind_rows_deferred_total"]) == 5
+    # nobody watched: no payload of the txn was built
+    assert (after["store_event_payloads_built_total"]
+            == before["store_event_payloads_built_total"])
+    w = remote.pods.watch(from_revision=rev)  # a per-event reader resumes
+    got = [w.get(timeout=5) for _ in range(5)]
+    w.stop()
+    assert [e.object["spec"]["nodeName"] for e in got] == ["n1"] * 5
+    read = _store_counters(server.url)
+    assert (read["store_event_payloads_built_total"]
+            - before["store_event_payloads_built_total"]) == 5
+
+
 def test_remote_watch_stream(remote):
     pods, rev = remote.pods.list()
     w = remote.pods.watch(from_revision=rev)

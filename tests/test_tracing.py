@@ -283,6 +283,8 @@ def test_store_txn_span_counts_its_frames(monkeypatch, op, n, frames):
         cs.pods.create_many(pods)
     tr = tracing.enable()
     c0 = DEFAULT_STORE_METRICS.watch_frames.value
+    d0 = DEFAULT_STORE_METRICS.bind_rows_deferred.value
+    b0 = DEFAULT_STORE_METRICS.event_payloads_built.value
     if op == "create_many":
         cs.pods.create_many(pods)
     elif op == "bind_many":
@@ -297,6 +299,20 @@ def test_store_txn_span_counts_its_frames(monkeypatch, op, n, frames):
              and sp.attrs.get("op") == op]
     assert len(spans) == 1 and spans[0].attrs["frames"] == frames
     assert DEFAULT_STORE_METRICS.watch_frames.value - c0 == frames
+    # a bind txn builds no watch payload at commit: its span says how
+    # many rows it deferred, and the frames' columns are read without one
+    assert spans[0].attrs.get("deferred") == (n if op == "bind_many" else None)
+    assert DEFAULT_STORE_METRICS.bind_rows_deferred.value - d0 == (
+        n if op == "bind_many" else 0)
+    got = []
+    while (item := watch.get(timeout=0)) is not None:
+        got.append(item)
+    if op == "bind_many" and frames:
+        assert sum(len(f.keys) + len(f.node_names) + len(f.revisions)
+                   for f in got[-frames:]) == 3 * n
+        assert DEFAULT_STORE_METRICS.event_payloads_built.value == b0
+        assert sum(len(f.objects) for f in got[-frames:]) == n
+        assert DEFAULT_STORE_METRICS.event_payloads_built.value - b0 == n
     watch.stop()
     store.close()
 
