@@ -166,6 +166,14 @@ class APIServer:
             "apiserver_watch_stream_held_frames_total",
             "watch frames written past their stream's deadline: they were "
             "queued when it passed, and the stream ended after them"))
+        self.watch_serve_s = self.registry.register(Counter(
+            "apiserver_watch_serve_seconds_total",
+            "seconds the watch streams spent encoding and writing their "
+            "frames and lines (a write waits for a slow reader)"))
+        self.watch_encode_s = self.registry.register(Counter(
+            "apiserver_watch_encode_seconds_total",
+            "of those, the seconds spent encoding: this process's "
+            "interpreter, which a request's handler waits for"))
         self.apiservice_status_failures = self.registry.register(Counter(
             "apiserver_apiservice_status_failures_total",
             "best-effort APIService availability updates that failed"))
@@ -256,6 +264,9 @@ class APIServer:
 def _make_handler(server: APIServer):
     class Handler(BaseHTTPRequestHandler):
         protocol_version = "HTTP/1.1"
+        # the open tracing.Account of the request under way, where its
+        # client asked for the parts (set per request by _route)
+        _acct = None
 
         # -- plumbing ------------------------------------------------------
         def log_message(self, *args):
@@ -263,6 +274,8 @@ def _make_handler(server: APIServer):
 
         def _send(self, code: int, obj) -> None:
             self._last_code = code
+            acct = self._acct
+            t_answer = time.perf_counter() if acct is not None else 0.0
             # content negotiation (reference protobuf negotiation via
             # Accept: application/vnd.kubernetes.protobuf)
             if BINARY_CONTENT_TYPE in self.headers.get("Accept", ""):
@@ -273,6 +286,8 @@ def _make_handler(server: APIServer):
             else:
                 data = json.dumps(obj).encode()
                 ctype = "application/json"
+            if acct is not None:
+                acct.add("server.answer", t_answer)
             self.send_response(code)
             self.send_header("Content-Type", ctype)
             self.send_header("Content-Length", str(len(data)))
@@ -282,7 +297,14 @@ def _make_handler(server: APIServer):
             # this server's own account of the request, for the client's
             # remote.request span: from the moment its headers were in (the
             # body is read inside) to this write, and within that the store
-            # call, where the verb made one
+            # call, where the verb made one.  A request that asked for its
+            # parts (tracing.PARTS_HEADER: a traced client) also gets each
+            # part where it ran, ``name;dur=<ms>;t=<perf_counter s at its
+            # start>`` (the clock every process of the host shares), then
+            # ``cpu``: this thread's CPU time in the request, ``watch``: the
+            # watch streams' encode and write that ended in the same time,
+            # and ``watch_encode``: the encode alone.  The rest get the
+            # plain header: nothing is read for them.
             timing = (f"handle;dur="
                       f"{(time.perf_counter() - self._t_request) * 1e3:.3f}")
             if self._store_s is not None:
@@ -291,18 +313,39 @@ def _make_handler(server: APIServer):
                 # collector pauses that fell inside this request
                 timing += (f", gc;dur="
                            f"{(server.collector.pause_s - self._gc_s0) * 1e3:.3f}")
+            if acct is not None:
+                cpu = time.thread_time() - acct.cpu0
+                watch = server.watch_serve_s.value - self._watch_s0[0]
+                encode = server.watch_encode_s.value - self._watch_s0[1]
+                timing += "".join(f", {name};dur={dur * 1e3:.6f};t={t0:.9f}"
+                                  for name, t0, dur in acct.parts)
+                timing += (f", cpu;dur={cpu * 1e3:.6f}"
+                           f", watch;dur={watch * 1e3:.6f}"
+                           f", watch_encode;dur={encode * 1e3:.6f}")
             self.send_header("Server-Timing", timing)
             self.end_headers()
             self.wfile.write(data)
 
         def _store(self, call, *args, **kwargs):
-            """One store call of this request, timed for ``Server-Timing``."""
+            """One store call of this request, timed for ``Server-Timing``.
+            Asked for its parts, it is ``server.store_lock`` up to the
+            moment the verb got hold of the store's lock (where the verb
+            says: ``bind_many``, ``create_many``, ``list``) and
+            ``server.store`` from then: the two sum to ``store_s``."""
             t0 = time.perf_counter()
+            acct = self._acct
+            if acct is not None:
+                acct.held = None
             try:
                 return call(*args, **kwargs)
             finally:
-                self._store_s = ((self._store_s or 0.0)
-                                 + time.perf_counter() - t0)
+                t1 = time.perf_counter()
+                self._store_s = (self._store_s or 0.0) + t1 - t0
+                if acct is not None:
+                    held = acct.held
+                    if held is not None:
+                        acct.add("server.store_lock", t0, held)
+                    acct.add("server.store", t0 if held is None else held, t1)
 
         def _error(self, code: int, reason: str, message: str,
                    retry_after: Optional[float] = None) -> None:
@@ -317,14 +360,21 @@ def _make_handler(server: APIServer):
             # cached: the auth filters peek at the body (namespace for
             # authorization) before dispatch consumes it
             if not hasattr(self, "_cached_body"):
+                acct = self._acct
+                t_body = time.perf_counter() if acct is not None else 0.0
                 length = int(self.headers.get("Content-Length", 0))
                 raw = self.rfile.read(length) if length else b""
+                if acct is not None:
+                    t_parse = time.perf_counter()
+                    acct.add("server.body", t_body, t_parse)
                 if raw and BINARY_CONTENT_TYPE in self.headers.get("Content-Type", ""):
                     from ..api import wire as binwire
 
                     self._cached_body = binwire.decode(raw)
                 else:
                     self._cached_body = json.loads(raw) if raw else {}
+                if acct is not None:
+                    acct.add("server.parse", t_parse)
             return self._cached_body
 
         def _admission_gate(self, resource: str, bodies: list) -> bool:
@@ -548,6 +598,7 @@ def _make_handler(server: APIServer):
             self._store_s = None
             collector = server.collector
             self._gc_s0 = collector.pause_s if collector is not None else 0.0
+            self._acct = None
             server.request_count.inc()
             self._last_code = 0
             acquired = False
@@ -565,6 +616,12 @@ def _make_handler(server: APIServer):
                     # overload just converts overload into latency
                     return self._error(429, "TooManyRequests",
                                        "server overloaded (max in flight)")
+            # the request's parts, only where the client asked for them
+            acct = None
+            if self.headers.get(tracing.PARTS_HEADER):
+                acct = self._acct = tracing.open_account()
+                self._watch_s0 = (server.watch_serve_s.value,
+                                  server.watch_encode_s.value)
             try:
                 if not self._auth_filters(method):
                     return
@@ -590,6 +647,8 @@ def _make_handler(server: APIServer):
                     # means the peer hung up — count it, don't re-panic
                     server.error_write_failures.inc()
             finally:
+                if acct is not None:
+                    tracing.close_account()
                 if acquired:
                     server._inflight.release()
                 server.request_latency.observe((time.perf_counter() - start) * 1e6)
@@ -1055,6 +1114,16 @@ def _make_handler(server: APIServer):
             self.wfile.write(f"{len(data):x}\r\n".encode() + data + b"\r\n")
             self.wfile.flush()
 
+        def _write_watched(self, encode, *args) -> None:
+            """One frame or line of a watch stream, its encode and its
+            write counted (per frame or line, never per row)."""
+            t0 = time.perf_counter()
+            data = encode(*args)
+            t1 = time.perf_counter()
+            server.watch_encode_s.inc(t1 - t0)
+            self._write_chunk(data)
+            server.watch_serve_s.inc(time.perf_counter() - t0)
+
         def _end_chunks(self) -> None:
             self.wfile.write(b"0\r\n\r\n")
 
@@ -1226,9 +1295,14 @@ def _make_handler(server: APIServer):
                 return self._send(200, {"version": __version__})
             if url.path == "/api/v1/bindings:batch" and method == "POST":
                 items = self._body().get("bindings", [])
-                errors = self._store(server.store.bind_many, [
-                    (b.get("podNamespace", "default"), b["podName"], b["nodeName"])
-                    for b in items])
+                acct = self._acct
+                t_rows = time.perf_counter() if acct is not None else 0.0
+                rows = [(b.get("podNamespace", "default"), b["podName"],
+                         b["nodeName"]) for b in items]
+                if acct is not None:
+                    # the body's rows as the verb takes them: still parsing
+                    acct.add("server.parse", t_rows)
+                errors = self._store(server.store.bind_many, rows)
                 return self._send(200, {"errors": errors})
             # batch create: POST /api/v1/{resource}:batch {"items": [...]}
             # — one store txn (Store.create_many: one lock/WAL/fanout
@@ -1244,11 +1318,15 @@ def _make_handler(server: APIServer):
                     return
                 from ..api.scheme import convert_to_internal
 
-                items = [convert_to_internal(d)
-                         for d in self._body().get("items", [])]
+                body = self._body().get("items", [])
+                acct = self._acct
+                t_items = time.perf_counter() if acct is not None else 0.0
+                items = [convert_to_internal(d) for d in body]
                 if kind in CLUSTER_SCOPED:
                     for d in items:
                         d.setdefault("metadata", {})["namespace"] = ""
+                if acct is not None:
+                    acct.add("server.parse", t_items)
                 created = self._store(server.store.create_many, kind, items)
                 return self._send(201, {"items": created})
 
@@ -1457,14 +1535,14 @@ def _make_handler(server: APIServer):
                         # encoded ONCE per frame per revision and shared
                         # across every streaming client (frames are
                         # shared-immutable across watcher queues)
-                        self._write_chunk(frame.wire_bytes())
+                        self._write_watched(frame.wire_bytes)
                         continue
                     if pred is not None and not pred(ev.object):
                         # a selector silently ignored on watch would
                         # re-create the full-cluster fan-out the
                         # selector exists to avoid
                         continue
-                    self._write_chunk(event_wire_bytes(ev))
+                    self._write_watched(event_wire_bytes, ev)
                 self._end_chunks()
             except (BrokenPipeError, ConnectionResetError):
                 pass

@@ -109,23 +109,39 @@ def _parse_retry_after(headers) -> Optional[float]:
         return None
 
 
-def _server_timing(value: Optional[str]) -> dict:
+_TIMING_ATTRS = {"handle": "server_s", "store": "store_s", "gc": "gc_s",
+                 "cpu": "server_cpu_s", "watch": "watch_s",
+                 "watch_encode": "watch_encode_s"}
+
+
+def _server_timing(value: Optional[str]) -> tuple[dict, list]:
     """``Server-Timing: handle;dur=<ms>, store;dur=<ms>, gc;dur=<ms>`` (the
     apiserver's own account of one request) as the ``remote.request``
     span's ``server_s`` / ``store_s`` / ``gc_s``.  A part the server did
     not send — an older server, a verb that makes no store call, a server
-    embedded where no daemon owns the collector — stays absent, never 0."""
-    out = {}
-    for part in (value or "").split(","):
-        name, _, dur = part.strip().partition(";dur=")
-        attr = {"handle": "server_s", "store": "store_s",
-                "gc": "gc_s"}.get(name)
-        if attr is not None:
-            try:
-                out[attr] = float(dur) / 1e3
-            except ValueError:
-                pass
-    return out
+    embedded where no daemon owns the collector — stays absent, never 0.
+
+    A traced client asks for the request's parts (``tracing.PARTS_HEADER``)
+    and gets, after those, ``server.<part>;dur=<ms>;t=<s>`` for each part
+    where it ran (``t``: ``time.perf_counter`` in the server's process, the
+    host's one monotonic clock), and ``cpu`` / ``watch`` /
+    ``watch_encode``: the handler thread's CPU time, and the watch streams'
+    encode and write, and encode alone, in the same stretch, as
+    ``server_cpu_s`` / ``watch_s`` / ``watch_encode_s``.  Returns (attrs,
+    [(part, t0, dur_s)])."""
+    out: dict = {}
+    parts: list = []
+    for field in (value or "").split(","):
+        name, _, rest = field.strip().partition(";dur=")
+        dur, _, t = rest.partition(";t=")
+        try:
+            if t:
+                parts.append((name, float(t), float(dur) / 1e3))
+            elif name in _TIMING_ATTRS:
+                out[_TIMING_ATTRS[name]] = float(dur) / 1e3
+        except ValueError:
+            pass
+    return out, parts
 
 
 class RemoteWatch:
@@ -523,12 +539,15 @@ class RemoteStore:
               content_type: Optional[str] = None,
               items: Optional[int] = None) -> dict:
         """One resource request.  With tracing on it is one
-        ``remote.request`` span: what went out and came back, how long
-        this side spent encoding and decoding, and — from the server's
-        ``Server-Timing`` header — how long the apiserver (``server_s``)
-        and the store inside it (``store_s``) took, and what the daemon's
-        collector stalled of it (``gc_s``), so a child process's time
-        reaches this trace.  ``items``: how many objects a batch
+        ``remote.request`` span: what went out and came back, this side's
+        encode and decode as the children ``client.encode`` /
+        ``client.decode``, and — from the server's ``Server-Timing``
+        header — how long the apiserver (``server_s``) and the store
+        inside it (``store_s``) took, and what the daemon's collector
+        stalled of it (``gc_s``), so a child process's time reaches this
+        trace.  It also asks for the server's parts, which become
+        ``cat="server"`` children at the times they ran (see
+        :func:`_server_timing`).  ``items``: how many objects a batch
         verb carries."""
         tr = tracing.current()
         with (tr.span("remote.request", cat="client", method=method,
@@ -556,8 +575,10 @@ class RemoteStore:
                 data = json.dumps(body).encode() if body is not None else None
                 headers = {"Content-Type": "application/json"}
             if tr is not None:
-                sp.set(encode_s=tr.clock() - t_encode,
-                       bytes_out=len(data) if data is not None else 0)
+                tr.record(sp, "client.encode", t_encode, tr.clock(),
+                          cat="client")
+                sp.set(bytes_out=len(data) if data is not None else 0)
+                headers[tracing.PARTS_HEADER] = "1"
             attempts = 0
 
             def send():
@@ -592,9 +613,14 @@ class RemoteStore:
         else:
             out = json.loads(raw.decode())
         if tr is not None:
-            sp.set(status=resp.status, bytes_in=len(raw),
-                   decode_s=tr.clock() - t_decode,
-                   **_server_timing(resp.headers.get("Server-Timing")))
+            t_done = tr.clock()
+            timing, parts = _server_timing(resp.headers.get("Server-Timing"))
+            sp.set(status=resp.status, bytes_in=len(raw), **timing)
+            if tr.clock is time.perf_counter:
+                # the server read the clock this tracer reads
+                for name, t0, dur in parts:
+                    tr.record(sp, name, t0, t0 + dur, cat="server")
+            tr.record(sp, "client.decode", t_decode, t_done, cat="client")
         return out
 
     def raw(self, method: str, path: str, body=None,

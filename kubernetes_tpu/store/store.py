@@ -467,6 +467,7 @@ class Store:
               if tr is not None else tracing.NULL_SPAN) as sp:
             results: list[Optional[dict]] = []
             with self._mu:
+                tracing.lock_held()
                 bucket = self._objects.setdefault(kind, {})
                 events: list[WatchEvent] = []
                 for obj in objs:
@@ -575,6 +576,7 @@ class Store:
         # the per-item seam below is asked only of an armed plan
         plan = faults.active_plan()
         with self._mu:
+            tracing.lock_held()
             get = self._objects.setdefault("Pod", {}).get
             rev = 0  # the txn's last revision; 0 = none allocated yet
             try:
@@ -746,6 +748,7 @@ class Store:
         from, exactly the reflector's LIST-then-WATCH contract
         (``tools/cache/reflector.go:239``)."""
         with self._mu:
+            tracing.lock_held()
             out = []
             for key, item in self._objects.get(kind, {}).items():
                 ns = item.data["metadata"].get("namespace", "")

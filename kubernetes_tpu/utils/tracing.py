@@ -27,7 +27,12 @@ PAPERS.md):
   bind requeues (:func:`notify_requeue`);
 - **Chrome trace-event export** (:meth:`Tracer.chrome_trace`): load the
   JSON from ``/debug/traces`` or a flight dump
-  into ``chrome://tracing`` / Perfetto.
+  into ``chrome://tracing`` / Perfetto;
+- a **per-request account** (:func:`open_account`, PR 37) on a server's
+  handler thread, open only where a traced client asked
+  (:data:`PARTS_HEADER`): the request's parts where they ran, on
+  ``time.perf_counter``, which the client files under its
+  ``remote.request`` with :meth:`Tracer.record`.
 
 Disabled (the default, and the only production state until enabled) the
 instrumented sites cost one module-global load and a ``None`` check —
@@ -387,6 +392,19 @@ class Tracer:
                 self.background.append(span)
         return span
 
+    def record(self, parent: Span, name: str, t0: float, t1: float,
+               cat: str = "", **attrs) -> Span:
+        """File a span timed elsewhere under ``parent``, as it is: no
+        adoption (:meth:`complete`'s), because the work ran beside this
+        thread, not on it — the apiserver's parts of a request, read on
+        the same clock in its own process."""
+        span = Span(name, cat=cat, t0=t0, tid=parent.tid, attrs=attrs,
+                    mu=self._mu)
+        span.t1 = t1
+        with self._mu:
+            parent.children.append(span)
+        return span
+
     def instant(self, name: str, **attrs) -> dict:
         ev = {"name": name, "t": self.clock(), "tid": self._tid(),
               "attrs": _jsonable(attrs)}
@@ -521,6 +539,58 @@ class Tracer:
         events.sort(key=lambda e: e["ts"])
         return {"traceEvents": events, "displayTimeUnit": "ms",
                 "otherData": {"source": "kubernetes_tpu.utils.tracing"}}
+
+
+# -- the per-request account (a server's parts of one request) -------------
+
+#: the request header a traced client sends to ask a server for the parts of
+#: its request; without it the answer's ``Server-Timing`` is the plain one
+PARTS_HEADER = "X-Server-Timing-Parts"
+
+_ACCOUNTS = threading.local()
+
+
+class Account:
+    """The parts of one request that its handler's thread was asked for:
+    ``(name, t0, dur)`` on ``time.perf_counter``, in the order they ran.
+    ``held``: when the store call under way got hold of the store's lock
+    (:func:`lock_held`, called by the store's verbs), read by the
+    handler's ``_store``."""
+
+    __slots__ = ("parts", "held", "cpu0")
+
+    def __init__(self):
+        self.parts: list[tuple[str, float, float]] = []
+        self.held: Optional[float] = None
+        self.cpu0 = time.thread_time()
+
+    def add(self, name: str, t0: float, t1: Optional[float] = None) -> None:
+        t1 = time.perf_counter() if t1 is None else t1
+        self.parts.append((name, t0, t1 - t0))
+
+
+def open_account() -> Account:
+    """Open this thread's account for the request it is handling."""
+    acct = _ACCOUNTS.open = Account()
+    return acct
+
+
+def close_account() -> None:
+    _ACCOUNTS.open = None
+
+
+def account() -> Optional[Account]:
+    """This thread's open account, or None: a site pays one thread-local
+    load and a None check per request or store call, never per row."""
+    return getattr(_ACCOUNTS, "open", None)
+
+
+def lock_held() -> None:
+    """Called by a store verb the moment it holds the store's lock: the
+    open account, if any, ends its ``server.store_lock`` there."""
+    acct = getattr(_ACCOUNTS, "open", None)
+    if acct is not None:
+        acct.held = time.perf_counter()
 
 
 # -- integration hooks (disabled path: one global load + None check) -------

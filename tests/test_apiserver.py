@@ -113,6 +113,34 @@ def test_metrics_serve_the_stores_bind_counters(server, remote):
             - before["store_event_payloads_built_total"]) == 5
 
 
+@pytest.mark.parametrize("frames", [False, True])
+def test_metrics_serve_the_watch_streams_time(server, remote, frames):
+    """`apiserver_watch_serve_seconds_total`: what the watch streams spent
+    encoding and writing their frames and lines, and of it
+    `apiserver_watch_encode_seconds_total`, the encode: for operators, and
+    the ``watch`` / ``watch_encode`` of a traced request's
+    `Server-Timing`."""
+    def served():
+        with urllib.request.urlopen(server.url + "/metrics") as r:
+            rows = dict(ln.split() for ln in r.read().decode().splitlines()
+                        if ln.startswith("apiserver_watch_"))
+        return (float(rows["apiserver_watch_serve_seconds_total"]),
+                float(rows["apiserver_watch_encode_seconds_total"]))
+
+    assert served() == (0.0, 0.0)
+    _, rev = remote.pods.list()
+    w = RemoteStore(server.url).watch("Pod", from_revision=rev, frames=frames)
+    remote.pods.create_many([make_pod(f"w{i}") for i in range(300)])
+    got = 0
+    while got < 300:
+        ev = w.get(timeout=5)
+        assert ev is not None
+        got += len(ev.keys) if frames else 1
+    w.stop()
+    serve, encode = served()
+    assert 0.0 < encode <= serve
+
+
 def test_remote_watch_stream(remote):
     pods, rev = remote.pods.list()
     w = remote.pods.watch(from_revision=rev)

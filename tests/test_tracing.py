@@ -21,6 +21,7 @@ from __future__ import annotations
 import json
 import os
 import threading
+import time
 
 import pytest
 
@@ -123,6 +124,31 @@ def test_spans_on_other_threads_are_separate_roots():
     assert w.children == []  # the other thread's span did not nest here
     assert tr.background[-1].name == "informer.frame.apply"
     assert tr.background[-1].tid != w.tid
+
+
+def test_the_request_account_is_per_thread_and_record_adopts_nothing():
+    assert tracing.account() is None
+    acct = tracing.open_account()
+    assert tracing.account() is acct
+    other = []
+    t = threading.Thread(target=lambda: other.append(tracing.account()))
+    t.start()
+    t.join()
+    assert other == [None], "another thread's store call opens nothing"
+    acct.add("server.body", 1.0, 1.5)
+    assert acct.parts == [("server.body", 1.0, 0.5)]
+    tracing.close_account()
+    assert tracing.account() is None
+    # parts of another process are filed as they are: two of one start
+    # stay siblings (complete() would nest the second's elders in it)
+    clk = FakeClock()
+    tr = tracing.enable(clock=clk)
+    with tr.span("remote.request", cat="client") as sp:
+        tr.record(sp, "server.store_lock", 0.0, 0.0, cat="server")
+        tr.record(sp, "server.store", 0.0, 0.0, cat="server")
+    assert [c.name for c in sp.children] == ["server.store_lock",
+                                             "server.store"]
+    assert all(c.children == [] and c.tid == sp.tid for c in sp.children)
 
 
 def test_flight_recorder_bounds_and_dump_dir(tmp_path):
@@ -501,7 +527,11 @@ def test_remote_request_span_carries_the_servers_own_time():
             "POST", 3, 200, 1)
         assert a["bytes_out"] > 0 and a["bytes_in"] > 0
         assert 0 < a["store_s"] <= a["server_s"] <= sp.duration
-        assert 0 <= a["encode_s"] + a["decode_s"] <= sp.duration
+        assert "encode_s" not in a and "decode_s" not in a
+        enc, dec = (c for c in sp.children if c.cat == "client")
+        assert (enc.name, dec.name) == ("client.encode", "client.decode")
+        assert sp.t0 <= enc.t0 <= enc.t1 <= dec.t0 <= dec.t1 <= sp.t1
+        assert 0 <= enc.duration + dec.duration <= sp.duration
 
         # a refused request: the span says so, and still has the header
         with pytest.raises(Exception):
@@ -569,6 +599,302 @@ def test_remote_request_span_carries_the_collectors_pause():
         server.stop()
         server.collector.uninstall()
     assert gc.get_freeze_count() == 0
+
+
+# -- the apiserver's parts of a request (PR 37) ------------------------------
+
+PARTS = ("server.body", "server.parse", "server.store_lock", "server.store",
+         "server.answer")
+
+
+def _parts(sp) -> list:
+    return [c for c in sp.children if c.cat == "server"]
+
+
+def _assert_parts_account_for_the_request(sp, within_span: bool) -> None:
+    """The server's parts are in order, do not overlap, sum to at most its
+    ``server_s``; the lock's wait and the hold are ``store_s``.  Where the
+    server is another process, each lies inside the client's round trip:
+    one clock across processes."""
+    a = sp.attrs
+    parts = _parts(sp)
+    assert parts and {p.name for p in parts} <= set(PARTS)
+    for prev, nxt in zip(parts, parts[1:]):
+        assert prev.t0 <= prev.t1 <= nxt.t0 + 1e-9, (prev.name, nxt.name)
+    assert sum(p.duration for p in parts) <= a["server_s"] + 1e-6
+    store = sum(p.duration for p in parts if p.name.startswith("server.store"))
+    assert store == pytest.approx(a["store_s"], abs=1e-6)
+    assert [p.name for p in parts][-1] == "server.answer"
+    assert 0 < a["server_cpu_s"] and a["watch_s"] >= 0
+    assert a["watch_encode_s"] >= 0
+    if within_span:
+        for p in parts:
+            assert sp.t0 <= p.t0 and p.t1 <= sp.t1, p.name
+        enc, dec = (c for c in sp.children if c.cat == "client")
+        assert enc.t1 <= parts[0].t0 and parts[-1].t1 <= dec.t0
+
+
+def _timing(url: str, path: str, data=None, headers=None) -> str:
+    import urllib.error
+    import urllib.request
+
+    req = urllib.request.Request(f"{url}{path}", data=data,
+                                 headers=headers or {},
+                                 method="POST" if data is not None else "GET")
+    try:
+        with urllib.request.urlopen(req, timeout=10) as r:
+            return r.headers["Server-Timing"]
+    except urllib.error.HTTPError as e:
+        return e.headers["Server-Timing"]
+
+
+@pytest.mark.timeout(60)
+@pytest.mark.parametrize("path,body,fields", [
+    ("/healthz", None, ["handle"]),
+    ("/api/v1/pods", None, ["handle", "store"]),
+    ("/api/v1/namespaces/default/pods/none", None, ["handle", "store"]),
+    ("/api/v1/bindings:batch",
+     {"bindings": [{"podName": "p0", "nodeName": "n0"}]}, ["handle", "store"]),
+    ("/api/v1/pods:batch", {"items": []}, ["handle", "store"]),
+])
+def test_a_request_that_does_not_ask_gets_the_plain_server_timing(
+        path, body, fields):
+    """No ``tracing.PARTS_HEADER``: the header is the parent's, field for
+    field and digit for digit, with tracing on in the process or off."""
+    import re
+
+    from kubernetes_tpu.apiserver import APIServer
+
+    store = Store()
+    store.create("Pod", make_pod("p0", cpu="100m").to_dict())
+    server = APIServer(store)
+    server.start()
+    try:
+        data = json.dumps(body).encode() if body is not None else None
+        plain = re.compile(r"^handle;dur=\d+\.\d{3}(, store;dur=\d+\.\d{3})?$")
+        for on in (False, True):
+            if on:
+                tracing.enable()
+            got = _timing(server.url, path, data,
+                          {"Content-Type": "application/json"})
+            assert plain.match(got), got
+            assert [f.partition(";")[0] for f in got.split(", ")] == fields
+        asked = _timing(server.url, path, data,
+                        {"Content-Type": "application/json",
+                         tracing.PARTS_HEADER: "1"})
+        names = [f.partition(";")[0] for f in asked.split(", ")]
+        assert names[:len(fields)] == fields
+        assert names[-4:] == ["server.answer", "cpu", "watch",
+                              "watch_encode"]
+    finally:
+        server.stop()
+
+
+@pytest.mark.timeout(120)
+@pytest.mark.parametrize("verb", ["bind_many", "create_many", "list"])
+def test_a_traced_request_carries_the_servers_parts_in_process(verb):
+    from kubernetes_tpu.apiserver import APIServer
+    from kubernetes_tpu.client.remote import RemoteStore
+
+    server = APIServer(Store())
+    server.start()
+    try:
+        remote = RemoteStore(server.url)
+        remote.create_many("Pod", [make_pod(f"p{i}", cpu="100m").to_dict()
+                                   for i in range(200)])
+        tr = tracing.enable()
+        if verb == "bind_many":
+            assert remote.bind_many([("default", f"p{i}", "n0")
+                                     for i in range(200)]) == [None] * 200
+        elif verb == "create_many":
+            assert None not in remote.create_many(
+                "Pod", [make_pod(f"q{i}").to_dict() for i in range(200)])
+        else:
+            assert len(remote.list("Pod")[0]) == 200
+        sp, = (s for s in tr.background if s.name == "remote.request")
+        _assert_parts_account_for_the_request(sp, within_span=True)
+        names = [p.name for p in _parts(sp)]
+        body = ["server.body", "server.parse"] if verb != "list" else []
+        rows = ["server.parse"] if verb != "list" else []
+        assert names == body + rows + ["server.store_lock", "server.store",
+                                       "server.answer"]
+    finally:
+        server.stop()
+
+
+@pytest.mark.timeout(60)
+def test_a_request_without_tracing_sends_no_ask_and_records_nothing():
+    """Tracing off in the client: no header goes out, so the server opens no
+    account, and nothing is recorded on either side."""
+    from kubernetes_tpu.apiserver import APIServer
+    from kubernetes_tpu.client.remote import RemoteStore
+
+    seen = []
+
+    class Spy(Store):
+        def bind_many(self, items):
+            seen.append(tracing.account())
+            return super().bind_many(items)
+
+    store = Spy()
+    store.create("Pod", make_pod("p0").to_dict())
+    server = APIServer(store)
+    server.start()
+    try:
+        assert RemoteStore(server.url).bind_many([("default", "p0", "n0")]) == [None]
+        tr = tracing.enable()
+        assert RemoteStore(server.url).bind_many([("default", "p0", "n0")]) == [None]
+        assert seen[0] is None and seen[1] is not None
+        assert tracing.account() is None, "the test's thread never had one"
+        sp, = (s for s in tr.background if s.name == "remote.request")
+        assert [p.name for p in _parts(sp)][-3:] == [
+            "server.store_lock", "server.store", "server.answer"]
+    finally:
+        server.stop()
+
+
+@pytest.mark.timeout(60)
+def test_a_planted_holder_of_the_stores_lock_shows_as_store_lock():
+    from kubernetes_tpu.apiserver import APIServer
+    from kubernetes_tpu.client.remote import RemoteStore
+
+    store = Store()
+    store.create_many("Pod", [make_pod(f"p{i}").to_dict() for i in range(50)])
+    server = APIServer(store)
+    server.start()
+    held = threading.Event()
+
+    def hold():
+        with store._mu:
+            held.set()
+            time.sleep(0.4)
+
+    holder = threading.Thread(target=hold)
+    try:
+        remote = RemoteStore(server.url)
+        tr = tracing.enable()
+        holder.start()
+        held.wait(5)
+        assert remote.bind_many([("default", f"p{i}", "n0")
+                                 for i in range(50)]) == [None] * 50
+        holder.join()
+        sp, = (s for s in tr.background if s.name == "remote.request")
+        _assert_parts_account_for_the_request(sp, within_span=True)
+        by = {p.name: p.duration for p in _parts(sp)}
+        assert by["server.store_lock"] >= 0.2
+        assert by["server.store"] < by["server.store_lock"]
+        # waiting for a lock is not running
+        assert sp.attrs["server_cpu_s"] < sp.attrs["server_s"] - 0.2
+    finally:
+        server.stop()
+
+
+@pytest.mark.timeout(180)
+def test_a_frames_watcher_encoding_a_bind_shows_as_watch_s_and_less_cpu():
+    """A frames watcher encodes a 20,000-row bind's frames while a second
+    request runs: its ``watch_s`` is that encode and write, and its thread
+    ran a smaller share of its time than the same request alone."""
+    from kubernetes_tpu.apiserver import APIServer
+    from kubernetes_tpu.client.remote import RemoteStore
+
+    n = 20_000
+    store = Store()
+    store.create_many("Pod", [make_pod(f"p{i}", cpu="100m").to_dict()
+                              for i in range(n)])
+    server = APIServer(store)
+    server.start()
+    remote = RemoteStore(server.url, timeout=60)
+    watch = remote.watch("Pod", from_revision=store.revision, frames=True)
+    try:
+        tr = tracing.enable()
+        alone = []
+        for _ in range(2):
+            remote.list("Pod")
+            sp = [s for s in tr.background if s.name == "remote.request"][-1]
+            assert sp.attrs["watch_s"] == 0
+            alone.append(sp.attrs["server_cpu_s"] / sp.attrs["server_s"])
+        served = server.registry.get("apiserver_watch_serve_seconds_total")
+        before = served.value
+        store.bind_many([("default", f"p{i}", "n0") for i in range(n)])
+        # requests back to back while the frames are encoded and written:
+        # a frame is counted by the request in which its write ended
+        beside = []
+        for _ in range(12):
+            remote.list("Pod")
+            sp = [s for s in tr.background if s.name == "remote.request"][-1]
+            _assert_parts_account_for_the_request(sp, within_span=True)
+            beside.append(sp)
+            if sp.attrs["watch_s"] > 0:
+                break
+        got = 0
+        while got < n:
+            frame = watch.get(timeout=30)
+            assert frame is not None
+            got += len(frame.keys)
+        sp = max(beside, key=lambda s: s.attrs["watch_s"])
+        assert sp.attrs["watch_s"] > 0
+        assert sp.attrs["server_cpu_s"] / sp.attrs["server_s"] < max(alone)
+        assert sum(s.attrs["watch_s"] for s in beside) <= (
+            served.value - before + 1e-6)
+        encoded = server.registry.get("apiserver_watch_encode_seconds_total")
+        assert sum(s.attrs["watch_encode_s"] for s in beside) <= (
+            encoded.value + 1e-6)
+        assert 0 < encoded.value <= served.value
+    finally:
+        watch.stop()
+        server.stop()
+
+
+@pytest.mark.timeout(90)
+def test_the_parts_of_a_child_apiserver_lie_inside_the_clients_round_trip():
+    """The apiserver as its own process (the daemon, as the benchmark starts
+    it): each part's ``[t, t + dur]``, read on the server's clock, lies
+    inside the client's ``remote.request``: the host has one clock."""
+    import socket
+    import subprocess
+    import sys
+    import urllib.request
+
+    from kubernetes_tpu.client.remote import RemoteStore
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        port = s.getsockname()[1]
+    url = f"http://127.0.0.1:{port}"
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=f"{root}{os.pathsep}{os.environ.get('PYTHONPATH', '')}")
+    child = subprocess.Popen(
+        [sys.executable, "-m", "kubernetes_tpu.apiserver", "--host",
+         "127.0.0.1", "--port", str(port)], env=env, cwd=root,
+        stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL)
+    try:
+        deadline = time.monotonic() + 30
+        while True:
+            try:
+                urllib.request.urlopen(f"{url}/healthz", timeout=1).read()
+                break
+            except OSError:
+                assert child.poll() is None and time.monotonic() < deadline
+                time.sleep(0.05)
+        plain = _timing(url, "/api/v1/pods")
+        assert [f.partition(";")[0] for f in plain.split(", ")] == [
+            "handle", "store", "gc"]
+        remote = RemoteStore(url)
+        tr = tracing.enable()
+        assert None not in remote.create_many(
+            "Pod", [make_pod(f"p{i}", cpu="100m").to_dict() for i in range(500)])
+        assert remote.bind_many([("default", f"p{i}", "n0")
+                                 for i in range(500)]) == [None] * 500
+        assert len(remote.list("Pod")[0]) == 500
+        spans = [s for s in tr.background if s.name == "remote.request"]
+        assert len(spans) == 3
+        for sp in spans:
+            _assert_parts_account_for_the_request(sp, within_span=True)
+            assert 0 <= sp.attrs["gc_s"] <= sp.attrs["server_s"]
+    finally:
+        child.terminate()
+        child.wait(timeout=10)
 
 
 @pytest.mark.timeout(60)
