@@ -858,6 +858,22 @@ class Binding:
         )
 
 
+@dataclass(frozen=True)
+class BindingColumns:
+    """A batch of bindings as the batch bind carries them from the
+    scheduler's commit to the store's txn: the pods' keys
+    (``namespace/name``, the store's own) and the node name beside each,
+    two lists of one length.  ``len()`` is the number of bindings, so
+    whatever wraps ``PodClient.bind_many`` (a tracer, a test's recorder)
+    sees one argument that says the batch's size."""
+
+    keys: list
+    node_names: list
+
+    def __len__(self) -> int:
+        return len(self.keys)
+
+
 # ---------------------------------------------------------------------------
 # Workload / grouping objects (controllers + SelectorSpreadPriority)
 # ---------------------------------------------------------------------------

@@ -22,7 +22,9 @@ Routes:
   PUT    /api/v1/namespaces/{ns}/{resource}/{name}[?cas=true]
   DELETE /api/v1/namespaces/{ns}/{resource}/{name}
   POST   /api/v1/namespaces/{ns}/pods/{name}/binding
-  POST   /api/v1/bindings:batch          (the TPU batch-bind txn)
+  POST   /api/v1/bindings:batch          (the TPU batch-bind txn; body
+         {"keys": ["ns/name", ...], "nodeNames": [...]}, two columns of
+         equal length; answer {"errors": [null | "reason", ...]})
   POST   /api/v1/{resource}:batch        (batch create: one store txn)
 Cluster-scoped objects use ns "-" in paths.
 """
@@ -99,6 +101,21 @@ from ..api.types import kind_for_plural as _kind_for  # noqa: E402
 # registers the Cluster kind; federation/__init__ is import-light (lazy
 # controller loading) so this does NOT pull in the controller tree
 from ..federation import types as _federation_types  # noqa: E402,F401
+
+
+def _bind_columns_fault(keys, node_names) -> Optional[str]:
+    """Why a ``bindings:batch`` body's two columns cannot be committed, or
+    None: each must be a list of strings, and the two of equal length."""
+    for field, col in (("keys", keys), ("nodeNames", node_names)):
+        if type(col) is not list:
+            return f"{field}: a list of strings is required"
+        # (a C-level pass over the column: no Python step per row)
+        if not all(map(str.__instancecheck__, col)):
+            return f"{field}: every entry must be a string"
+    if len(keys) != len(node_names):
+        return (f"{len(keys)} keys and {len(node_names)} nodeNames: "
+                f"the columns must be of equal length")
+    return None
 
 
 class APIServer:
@@ -1294,15 +1311,19 @@ def _make_handler(server: APIServer):
 
                 return self._send(200, {"version": __version__})
             if url.path == "/api/v1/bindings:batch" and method == "POST":
-                items = self._body().get("bindings", [])
+                body = self._body()
                 acct = self._acct
-                t_rows = time.perf_counter() if acct is not None else 0.0
-                rows = [(b.get("podNamespace", "default"), b["podName"],
-                         b["nodeName"]) for b in items]
+                t_check = time.perf_counter() if acct is not None else 0.0
+                keys, node_names = ((body.get("keys"), body.get("nodeNames"))
+                                    if type(body) is dict else (None, None))
+                bad = _bind_columns_fault(keys, node_names)
                 if acct is not None:
-                    # the body's rows as the verb takes them: still parsing
-                    acct.add("server.parse", t_rows)
-                errors = self._store(server.store.bind_many, rows)
+                    # the columns checked as the verb takes them: still
+                    # parsing
+                    acct.add("server.parse", t_check)
+                if bad is not None:
+                    return self._error(400, "BadRequest", bad)
+                errors = self._store(server.store.bind_many, keys, node_names)
                 return self._send(200, {"errors": errors})
             # batch create: POST /api/v1/{resource}:batch {"items": [...]}
             # — one store txn (Store.create_many: one lock/WAL/fanout
@@ -1422,7 +1443,8 @@ def _make_handler(server: APIServer):
                     if parts[4] == "binding" and kind == "Pod" and method == "POST":
                         body = self._body()
                         errors = self._store(server.store.bind_many,
-                                             [(ns, name, body["nodeName"])])
+                                             [f"{ns}/{name}" if ns else name],
+                                             [body["nodeName"]])
                         if errors[0] is not None:
                             return self._error(409, "Conflict", errors[0])
                         return self._send(201, {"status": "bound"})

@@ -232,15 +232,11 @@ class PodClient(TypedClient):
             "Pod", binding.pod_namespace, binding.pod_name, _assign
         )
 
-    def bind_many(self, bindings: list) -> list[Optional[str]]:
-        """Batch placement commit (one store txn); per-item error or None.
-        Items are ``api.Binding`` or the store's own ``(namespace, name,
-        node_name)`` triples, which pass through as they are."""
-        return self._store.bind_many(
-            [b if isinstance(b, tuple)
-             else (b.pod_namespace, b.pod_name, b.node_name)
-             for b in bindings]
-        )
+    def bind_many(self, bindings: api.BindingColumns) -> list[Optional[str]]:
+        """Batch placement commit (one store txn): the two columns of
+        ``bindings`` go to the store's ``bind_many`` as they are; per-row
+        error or None."""
+        return self._store.bind_many(bindings.keys, bindings.node_names)
 
     def evict(self, name: str, namespace: Optional[str] = None) -> None:
         """PDB-aware voluntary eviction — the ``pods/eviction`` subresource

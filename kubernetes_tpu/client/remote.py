@@ -783,18 +783,13 @@ class RemoteStore:
             f"/api/v1/namespaces/{self._ns_path(namespace)}/{self._resource(kind)}/{name}",
         )
 
-    def bind_many(self, items: list[tuple[str, str, str]]) -> list[Optional[str]]:
-        out = self._call(
-            "POST",
-            "/api/v1/bindings:batch",
-            {
-                "bindings": [
-                    {"podNamespace": ns, "podName": name, "nodeName": node}
-                    for ns, name, node in items
-                ]
-            },
-            items=len(items),
-        )
+    def bind_many(self, keys: list[str],
+                  node_names: list[str]) -> list[Optional[str]]:
+        """The store's ``bind_many`` over the wire: the two columns go out
+        as they are, ``{"keys": [...], "nodeNames": [...]}``."""
+        out = self._call("POST", "/api/v1/bindings:batch",
+                         {"keys": keys, "nodeNames": node_names},
+                         items=len(keys))
         return out["errors"]
 
     def watch(self, kind: Optional[str] = None, from_revision: Optional[int] = None,

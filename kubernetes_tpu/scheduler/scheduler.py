@@ -893,12 +893,10 @@ class Scheduler:
             # segment the grouping by node, which spare the cache assume a
             # per-pod quantity re-parse and a per-pod NodeInfo write
             placed = PlacedSegment.placed_of(entries) if unplaced else entries
-            # one walk for what every step below needs of a pod: its key,
-            # once, and the store's own (namespace, name, node) triple
-            metas = [e[0].meta for e in placed]
-            keys = [m.key for m in metas]
-            to_bind = [(m.namespace, m.name, e[1])
-                       for m, e in zip(metas, placed)]
+            # what every step below needs of a pod: its key, once (the
+            # store's own), and for the bind the node column beside it
+            keys = [e[0].meta.key for e in placed]
+            node_names = [e[1] for e in placed]
             self.backoff.forget_many(keys)
             with (tr.span("commit.assume", cat="phase", pods=len(placed))
                   if tr is not None else tracing.NULL_SPAN) as sp:
@@ -906,10 +904,11 @@ class Scheduler:
                 sp.set(nodes=nodes, batched=batched)
             self.metrics.assume_batched_pods.inc(batched)
             bind_start = self._clock()
-            with (tr.span("commit.bind", cat="phase", pods=len(to_bind))
+            with (tr.span("commit.bind", cat="phase", pods=len(keys))
                   if tr is not None else tracing.NULL_SPAN):
                 try:
-                    errors = self.clientset.pods.bind_many(to_bind)
+                    errors = self.clientset.pods.bind_many(
+                        api.BindingColumns(keys, node_names))
                 except Exception as e:
                     # the whole segment's commit failed before any CAS
                     # applied (store overload / transport outage / injected
@@ -917,8 +916,8 @@ class Scheduler:
                     # failure path below, which forgets the assumption and
                     # requeues
                     logger.warning("bind_many failed for %d pods: %s: %s",
-                                   len(to_bind), type(e).__name__, e)
-                    errors = [f"transient: {e}"] * len(to_bind)
+                                   len(keys), type(e).__name__, e)
+                    errors = [f"transient: {e}"] * len(keys)
             self.metrics.binding_latency.observe((self._clock() - bind_start) * 1e6)
             if self.emit_events:
                 ev_batch.extend([
@@ -946,14 +945,14 @@ class Scheduler:
                   if tr is not None else tracing.NULL_SPAN):
                 self.cache.finish_binding_many(finished)
             totals["committed"] += len(finished)
-            totals["attempted_binds"] += len(to_bind)
+            totals["attempted_binds"] += len(keys)
             # per-segment e2e SLI: pods committed in segment s of S were
             # bound NOW, at this point of the drain — not at batch end.
             # One observe_many per segment keeps p50/p99 distinct without
             # per-pod lock rounds (the reference's three SLIs are per-pod
             # for exactly this reason, metrics/metrics.go:26-50)
             self.metrics.e2e_scheduling_latency.observe_many(
-                (self._clock() - start) * 1e6, len(to_bind))
+                (self._clock() - start) * 1e6, len(keys))
             t_commit_end = time.perf_counter()
             totals["commit_s"] += t_commit_end - t_commit
             if tr is not None:

@@ -545,29 +545,39 @@ class Store:
             # read-only by contract, and the stored dict never escapes
             return ev_copy
 
-    def bind_many(self, items: list[tuple[str, str, str]]) -> list[Optional[str]]:
-        """Batch placement commit: for each (namespace, name, node_name),
-        CAS-set ``spec.nodeName`` under ONE lock acquisition — the etcd-txn
+    def bind_many(self, keys: list[str],
+                  node_names: list[str]) -> list[Optional[str]]:
+        """Batch placement commit: for each pod key (``namespace/name``, the
+        store's own key) and the node name beside it, CAS-set
+        ``spec.nodeName`` under ONE lock acquisition — the etcd-txn
         analogue of issuing one BindingREST call per pod, shaped for the TPU
         batch path where hundreds of thousands of bindings land at once.
+        The two columns are the verb's input end to end (the wire body of
+        ``POST /api/v1/bindings:batch`` holds the same two lists): no side
+        reshapes them per row.
 
-        Returns one entry per item: None on success, else an error string
+        Returns one entry per row: None on success, else an error string
         ("not found" / "conflict: <node>").  The txn is committed by
         columns: the loop does the CAS and appends to the txn's columns,
         and a row's watch event (:class:`BoundPodEvent`) holds the stored
         dict, not a copy — its payload is derived when somebody reads it,
         which a watcher's frame does a piece at a time, after the answer."""
+        if len(keys) != len(node_names):
+            raise ValueError(f"bind_many: {len(keys)} keys, "
+                             f"{len(node_names)} node names")
         faults.hit("store.commit", op="bind_many", kind="Pod")
         txn = tracing.next_txn("bind_many")
         tr = tracing.current()
         with (tr.span("store.txn", cat="store", op="bind_many", kind="Pod",
-                      txn=txn, n=len(items))
+                      txn=txn, n=len(keys))
               if tr is not None else tracing.NULL_SPAN) as sp:
-            return self._bind_many_locked(items, txn, sp)
+            return self._bind_many_locked(keys, node_names, txn, sp)
 
-    def _bind_many_locked(self, items, txn, sp) -> list[Optional[str]]:
-        results: list[Optional[str]] = [None] * len(items)
-        # the txn's columns, one entry per committed row
+    def _bind_many_locked(self, keys_in, nodes_in, txn,
+                          sp) -> list[Optional[str]]:
+        results: list[Optional[str]] = [None] * len(keys_in)
+        # the txn's columns, one entry per committed row (the input's,
+        # less the rows that failed)
         keys: list[str] = []
         node_names: list[str] = []
         prev_revs: list[int] = []
@@ -580,8 +590,7 @@ class Store:
             get = self._objects.setdefault("Pod", {}).get
             rev = 0  # the txn's last revision; 0 = none allocated yet
             try:
-                for namespace, name, node_name in items:
-                    key = f"{namespace}/{name}" if namespace else name
+                for key, node_name in zip(keys_in, nodes_in):
                     # per-item seam: ONE pod's CAS fails while the rest of
                     # the batch commits (the real-world partial-bind
                     # shape) — surfaced as this item's error string,

@@ -92,8 +92,10 @@ def tracked_objects(obj) -> int:
     return own
 
 
-def bindings(n: int, start: int = 0) -> list:
-    return [("default", f"p{i}", f"n{i % 50}") for i in range(start, start + n)]
+def bindings(n: int, start: int = 0) -> tuple:
+    """``bind_many``'s two columns: pod keys and the node names beside them."""
+    rows = range(start, start + n)
+    return [f"default/p{i}" for i in rows], [f"n{i % 50}" for i in rows]
 
 
 # -- (a) no full pass inside a batch verb ---------------------------------
@@ -125,7 +127,7 @@ def test_batch_verbs_meet_no_full_pass_under_the_policy(policy):
             gc.freeze()
     with FullPasses() as seen:
         created = store.create_many("Pod", more)
-        errors = store.bind_many(bindings(20_000, start=20_000))
+        errors = store.bind_many(*bindings(20_000, start=20_000))
     assert all(c is not None for c in created) and errors == [None] * 20_000
     if policy == "installed":
         assert seen.count == 0
@@ -226,7 +228,7 @@ def test_frozen_rows_die_by_reference_count():
     c.run_pass()
     frozen = gc.get_freeze_count()
     # binding past the window pushes every frozen create row out of the log
-    assert store.bind_many(bindings(2_000)) == [None] * 2_000
+    assert store.bind_many(*bindings(2_000)) == [None] * 2_000
     assert gc.get_freeze_count() < frozen - 1_000
 
 
@@ -265,7 +267,7 @@ def test_embedding_store_and_apiserver_leaves_the_collector_alone():
         remote = RemoteStore(server.url)
         created = remote.create_many("Pod", pod_dicts(200))
         assert len(created) == 200
-        assert remote.bind_many(bindings(200)) == [None] * 200
+        assert remote.bind_many(*bindings(200)) == [None] * 200
         with urllib.request.urlopen(f"{server.url}/api/v1/pods") as r:
             assert "gc;" not in r.headers["Server-Timing"]
         with urllib.request.urlopen(f"{server.url}/metrics") as r:
@@ -338,7 +340,7 @@ def test_daemon_serves_its_counters_and_gc_time_reaches_the_span():
         remote = RemoteStore(url)
         tr = tracing.enable()
         assert len(remote.create_many("Pod", pod_dicts(500))) == 500
-        assert remote.bind_many(bindings(500)) == [None] * 500
+        assert remote.bind_many(*bindings(500)) == [None] * 500
         batch = [sp for sp in tr.background if sp.name == "remote.request"
                  and sp.attrs["path"].endswith(":batch")]
         assert [sp.attrs["items"] for sp in batch] == [500, 500]
@@ -346,7 +348,7 @@ def test_daemon_serves_its_counters_and_gc_time_reaches_the_span():
             assert 0 <= sp.attrs["gc_s"] <= sp.attrs["server_s"]
         req = urllib.request.Request(
             f"{url}/api/v1/bindings:batch", method="POST",
-            data=json.dumps({"bindings": []}).encode(),
+            data=json.dumps({"keys": [], "nodeNames": []}).encode(),
             headers={"Content-Type": "application/json"})
         with urllib.request.urlopen(req, timeout=5) as r:
             fields = [part.partition(";dur=")

@@ -16,6 +16,8 @@ import json
 import pytest
 
 from kubernetes_tpu import faults
+from kubernetes_tpu.apiserver import APIServer
+from kubernetes_tpu.client.remote import RemoteStore
 from kubernetes_tpu.store import Store, WatchEvent, frames as frames_mod
 from kubernetes_tpu.store.replication import FollowerReplica, ReplicatedStore
 from kubernetes_tpu.store.store import (
@@ -56,13 +58,15 @@ def _world(store: Store) -> None:
     store.create_many("Pod", pods)
 
 
-# what a txn is made of; each is also run alone
+# what a txn is made of, as (pod key, node name) rows; each is also run
+# alone
 ITEMS = {
-    "plain": [("default", f"p{i}", f"n{i % 3}") for i in range(9)],
-    "rebound": [("default", "p9", "n9")],
-    "conflicting": [("default", "p10", "n-new")],
-    "missing": [("default", "ghost", "n1")],
-    "injected": [("default", "p3", "n0")],  # (the plan below drops p3)
+    "plain": [(f"default/p{i}", f"n{i % 3}") for i in range(9)],
+    "rebound": [("default/p9", "n9")],
+    "conflicting": [("default/p10", "n-new")],
+    "missing": [("default/ghost", "n1")],
+    "injected": [("default/p3", "n0")],  # (the plan below drops p3)
+    "unqualified": [("p4", "n1")],  # a key without a namespace: no pod
 }
 ITEMS["mixed"] = (ITEMS["plain"][:3] + ITEMS["missing"] + ITEMS["rebound"]
                   + ITEMS["plain"][3:6] + ITEMS["conflicting"]
@@ -81,8 +85,7 @@ def _reference(objs: dict, rev: int, items, injected=()):
     copies.  Returns (results, [(type, kind, key, revision, payload)],
     prev_revisions)."""
     results, events, prevs = [], [], []
-    for namespace, name, node in items:
-        key = f"{namespace}/{name}"
+    for key, node in items:
         if key in injected:
             results.append("injected: bind fault")
             continue
@@ -102,6 +105,12 @@ def _reference(objs: dict, rev: int, items, injected=()):
         events.append((MODIFIED, "Pod", key, rev, copy.deepcopy(obj)))
         results.append(None)
     return results, events, prevs
+
+
+def _bind(store, items):
+    """``bind_many`` of ``items`` as the verb takes them: two columns."""
+    return store.bind_many([key for key, _ in items],
+                           [node for _, node in items])
 
 
 def _reference_frames(events, prevs, txn, lo=0):
@@ -167,10 +176,19 @@ def _txn_of(items):
 
 # -- what a txn is made of -------------------------------------------------
 
+@pytest.mark.parametrize("via", ["store", "wire"])
 @pytest.mark.parametrize("case", list(ITEMS))
-def test_a_txns_events_and_frames_equal_the_eager_arm(case):
+def test_a_txns_events_and_frames_equal_the_eager_arm(case, via):
+    """``via="wire"``: the same two columns through ``RemoteStore`` and the
+    apiserver's ``bindings:batch`` handler commit the same txn."""
     store = Store()
     _world(store)
+    server = None
+    binder = store
+    if via == "wire":
+        server = APIServer(store)
+        server.start()
+        binder = RemoteStore(server.url)
     items = ITEMS[case]
     injected = {"default/p3"} if case in ("injected", "mixed") else set()
     objs, rev = _snapshot(store)
@@ -178,14 +196,18 @@ def test_a_txns_events_and_frames_equal_the_eager_arm(case):
         objs, rev, items, injected)
     framed = store.watch("Pod", frames=True)
     plain = store.watch("Pod")
-    if injected:
-        plan = faults.FaultPlan(seed=1).on(
-            "scheduler.bind", mode="drop",
-            match={"via": "bind_many", "pod": "default/p3"})
-        with plan.armed():
-            results = store.bind_many(items)
-    else:
-        results = store.bind_many(items)
+    try:
+        if injected:
+            plan = faults.FaultPlan(seed=1).on(
+                "scheduler.bind", mode="drop",
+                match={"via": "bind_many", "pod": "default/p3"})
+            with plan.armed():
+                results = _bind(binder, items)
+        else:
+            results = _bind(binder, items)
+    finally:
+        if server is not None:
+            server.stop()
     assert results == want_results
     assert store.revision == rev + len(want_rows)
     got = _drain(framed)
@@ -219,7 +241,7 @@ def _later_update(store):
 
 
 def _later_rebind(store):
-    assert store.bind_many(ITEMS["plain"][:6]) == [None] * 6
+    assert _bind(store, ITEMS["plain"][:6]) == [None] * 6
 
 
 def _later_delete(store):
@@ -258,7 +280,7 @@ def test_payloads_read_after_a_later_write_equal_the_eager_arm(
     items = ITEMS["mixed"]
     want_results, want_rows, want_prevs = _reference(objs, rev, items)
     live = store.watch("Pod", frames=reader != "live_events")
-    assert store.bind_many(items) == want_results
+    assert _bind(store, items) == want_results
     LATER[later](store)  # nobody has read a payload of the txn yet
     resumed = reader.startswith("resumed")
     skip = 5 if resumed else 0  # resume inside the txn's second piece
@@ -296,7 +318,7 @@ def test_a_wal_takes_every_payload_at_commit_and_replays_it(tmp_path):
     objs, rev = _snapshot(store)
     want_results, want_rows, _ = _reference(objs, rev, ITEMS["mixed"])
     d0, b0 = m.bind_rows_deferred.value, m.event_payloads_built.value
-    assert store.bind_many(ITEMS["mixed"]) == want_results
+    assert _bind(store, ITEMS["mixed"]) == want_results
     # durability before visibility: every record was written under the
     # lock, so every payload was built there and none deferred
     assert m.bind_rows_deferred.value - d0 == 0
@@ -323,7 +345,7 @@ def test_a_follower_gets_every_bind_event_as_the_eager_arm_builds_it():
         objs, rev, ITEMS["mixed"])
     theirs = follower.store.watch("Pod")
     ours = leader.watch("Pod", frames=True)
-    assert leader.bind_many(ITEMS["mixed"]) == want_results
+    assert _bind(leader, ITEMS["mixed"]) == want_results
     _later_rebind(leader)
     _later_delete_finalizers(leader)
     _assert_events(_drain(theirs)[:len(want_rows)], want_rows)
@@ -347,7 +369,7 @@ def test_no_quorum_refuses_the_txn_before_anything_is_written():
     for f in followers:
         f.fail()
     with pytest.raises(NoQuorumError):
-        leader.bind_many(ITEMS["plain"])
+        _bind(leader, ITEMS["plain"])
     assert _snapshot(leader) == before
     leader.close()
 
@@ -372,7 +394,7 @@ def _verb_guaranteed_update(store, key):
 
 def _verb_rebind(store, key):
     node = store.get("Pod", "default", key)["spec"]["nodeName"]
-    assert store.bind_many([("default", key, node)] * 2) == [None, None]
+    assert store.bind_many([f"default/{key}"] * 2, [node] * 2) == [None, None]
 
 
 def _verb_delete(store, key):
@@ -427,8 +449,8 @@ def test_no_write_verb_reaches_a_committed_bind_event(verb):
     store = Store()
     _world(store)
     watch = store.watch("Pod")
-    assert store.bind_many([("default", "p2", "n2"), ("default", "p7", "n7"),
-                            ("default", "p8", "n8")]) == [None] * 3
+    assert store.bind_many(["default/p2", "default/p7", "default/p8"],
+                           ["n2", "n7", "n8"]) == [None] * 3
     events = _drain(watch)
     assert [type(e) for e in events] == [BoundPodEvent] * 3
     assert all(e._payload is None for e in events)  # nothing built yet
@@ -448,10 +470,10 @@ def test_a_later_write_does_not_reach_a_payload_already_built():
     store = Store()
     _world(store)
     watch = store.watch("Pod")
-    store.bind_many([("default", "p7", "n7")])
+    store.bind_many(["default/p7"], ["n7"])
     (ev,) = _drain(watch)
     built = copy.deepcopy(ev.object)
-    store.bind_many([("default", "p7", "n7")])
+    store.bind_many(["default/p7"], ["n7"])
     store.delete("Pod", "default", "p7")
     assert ev.object == built
     store.close()
@@ -469,7 +491,7 @@ def test_a_reader_of_columns_alone_builds_no_payload():
     tr = tracing.enable()
     try:
         d0, b0 = m.bind_rows_deferred.value, m.event_payloads_built.value
-        store.bind_many(ITEMS["plain"])
+        _bind(store, ITEMS["plain"])
         spans = [sp for sp in tr.background if sp.name == "store.txn"
                  and sp.attrs.get("op") == "bind_many"]
     finally:
@@ -481,9 +503,9 @@ def test_a_reader_of_columns_alone_builds_no_payload():
     got = _drain(framed)
     assert [len(f) for f in got] == [4, 4, 1]
     assert [n for f in got for n in f.node_names] == [
-        it[2] for it in ITEMS["plain"]]
+        node for _, node in ITEMS["plain"]]
     assert [k for f in got for k in f.keys] == [
-        f"default/{it[1]}" for it in ITEMS["plain"]]
+        key for key, _ in ITEMS["plain"]]
     assert all(p >= 0 for f in got for p in f.prev_revisions)
     assert got[-1].revision == store.revision
     assert m.event_payloads_built.value - b0 == 0  # columns alone

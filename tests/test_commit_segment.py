@@ -10,7 +10,7 @@ equal to a run whose segments arrive stripped of their groups."""
 
 import pytest
 
-from kubernetes_tpu.api import Binding, Volume
+from kubernetes_tpu.api import BindingColumns, Volume
 from kubernetes_tpu.client import Clientset
 from kubernetes_tpu.faults import FaultPlan
 from kubernetes_tpu.ops import TPUBatchBackend
@@ -79,9 +79,9 @@ def _run(strip_groups: bool):
     bind_many = cs.pods.bind_many
     sent: list = []
 
-    def sending(items):
-        sent.append(list(items))
-        return bind_many(items)
+    def sending(bindings):
+        sent.append(bindings)
+        return bind_many(bindings)
 
     cs.pods.bind_many = sending
 
@@ -135,12 +135,18 @@ def test_the_wave_commits_in_two_calls_and_the_first_mixes_kernel_and_oracle(
     names = [f"p-{i:02d}" for i in range(19)]
     assert by_node["commits"] == [(PlacedSegment, 10, names[:11]),
                                   (PlacedSegment, 8, names[11:])]
-    # the transport's own triples, in pod order, nothing wrapped around them
-    flat = [item for call in by_node["sent"] for item in call]
-    assert [len(call) for call in by_node["sent"]] == [10, 8]
-    assert all(type(item) is tuple and len(item) == 3 for item in flat)
-    assert [item[:2] for item in flat] == [
-        ("default", n) for n in names if n != UNPLACED]
+    # the transport's own two columns, in pod order: plain lists of the
+    # store's keys and the node names beside them, nothing wrapped
+    sent = by_node["sent"]
+    assert all(type(b) is BindingColumns for b in sent)
+    assert [(len(b), len(b.keys), len(b.node_names)) for b in sent] == [
+        (10, 10, 10), (8, 8, 8)]
+    assert all(type(col) is list and all(type(v) is str for v in col)
+               for b in sent for col in (b.keys, b.node_names))
+    assert [key for b in sent for key in b.keys] == [
+        f"default/{n}" for n in names if n != UNPLACED]
+    assert {node for b in sent for node in b.node_names} <= {
+        f"n{j}" for j in range(6)}
 
 
 @pytest.mark.timeout(300)
@@ -197,15 +203,17 @@ def test_segments_stripped_of_their_groups_commit_to_the_same_state(by_node):
         assert per_pod[field] == by_node[field], field
 
 
-def test_pod_client_bind_many_takes_bindings_and_triples_alike():
+def test_pod_client_bind_many_takes_key_and_node_columns():
     cs = Clientset(Store())
     cs.nodes.create(make_node("n0"))
     for name in ("a", "b", "c"):
         cs.pods.create(make_pod(name, cpu="100m", memory="64Mi"))
-    assert cs.pods.bind_many([
-        Binding(pod_namespace="default", pod_name="a", node_name="n0"),
-        ("default", "b", "n0"), ("default", "missing", "n0")]) == [
-            None, None, "not found"]
+    assert cs.pods.bind_many(BindingColumns(
+        ["default/a", "default/b", "default/missing", "c"],
+        ["n0", "n0", "n0", "n0"])) == [None, None, "not found", "not found"]
     pods, _ = cs.pods.list()
     assert {p.meta.name: p.spec.node_name for p in pods} == {
         "a": "n0", "b": "n0", "c": ""}
+    with pytest.raises(ValueError):
+        cs.pods.bind_many(BindingColumns(["default/c"], []))
+    assert cs.pods.get("c").spec.node_name == ""

@@ -296,7 +296,7 @@ def test_store_txn_span_counts_its_frames(monkeypatch, op, n, frames):
     ``frames``: the pieces of at most ``FRAME_MAX_ROWS`` rows it packed
     (0 where nothing was framed: a one-event txn goes out as the event),
     and ``store_watch_frames_total`` moves by the same number."""
-    from kubernetes_tpu.api import Binding
+    from kubernetes_tpu.api import BindingColumns
     from kubernetes_tpu.store import frames as frames_mod
     from kubernetes_tpu.utils.metrics import DEFAULT_STORE_METRICS
 
@@ -314,9 +314,8 @@ def test_store_txn_span_counts_its_frames(monkeypatch, op, n, frames):
     if op == "create_many":
         cs.pods.create_many(pods)
     elif op == "bind_many":
-        cs.pods.bind_many([Binding(pod_namespace="default",
-                                   pod_name=p.meta.name, node_name="n0")
-                           for p in pods])
+        cs.pods.bind_many(BindingColumns([p.meta.key for p in pods],
+                                         ["n0"] * len(pods)))
     else:
         for p in pods:
             cs.pods.create(p)
@@ -509,11 +508,11 @@ def test_remote_request_span_carries_the_servers_own_time():
             assert 0 < float(store[10:]) <= float(handle[11:])
 
         assert tracing.current() is None
-        assert remote.bind_many([("default", f"p{i}", "n0")
-                                 for i in range(3)]) == [None] * 3
+        assert remote.bind_many([f"default/p{i}" for i in range(3)],
+                                ["n0"] * 3) == [None] * 3
         tr = tracing.enable()
-        assert remote.bind_many([("default", f"p{i}", "n0")
-                                 for i in range(3, 6)]) == [None] * 3
+        assert remote.bind_many([f"default/p{i}" for i in range(3, 6)],
+                                ["n0"] * 3) == [None] * 3
         pods, _ = cs.pods.list()
         assert {p.meta.name: p.spec.node_name for p in pods} == {
             f"p{i}": "n0" for i in range(6)}
@@ -568,9 +567,9 @@ def test_remote_request_span_carries_the_collectors_pause():
     from kubernetes_tpu.client.remote import RemoteStore
 
     class StoreWithAPass(Store):
-        def bind_many(self, items):
+        def bind_many(self, keys, node_names):
             gc.collect()  # a full pass begun inside the request
-            return super().bind_many(items)
+            return super().bind_many(keys, node_names)
 
     store = StoreWithAPass()
     server = APIServer(store)
@@ -583,8 +582,8 @@ def test_remote_request_span_carries_the_collectors_pause():
         cs.nodes.create(make_node("n0", cpu="8", memory="16Gi"))
         cs.pods.create_many([make_pod(f"p{i}", cpu="100m") for i in range(6)])
         tr = tracing.enable()
-        assert remote.bind_many([("default", f"p{i}", "n0")
-                                 for i in range(6)]) == [None] * 6
+        assert remote.bind_many([f"default/p{i}" for i in range(6)],
+                                ["n0"] * 6) == [None] * 6
         sp, = (s for s in tr.background if s.name == "remote.request"
                and s.attrs["path"] == "/api/v1/bindings:batch")
         a = sp.attrs
@@ -654,7 +653,7 @@ def _timing(url: str, path: str, data=None, headers=None) -> str:
     ("/api/v1/pods", None, ["handle", "store"]),
     ("/api/v1/namespaces/default/pods/none", None, ["handle", "store"]),
     ("/api/v1/bindings:batch",
-     {"bindings": [{"podName": "p0", "nodeName": "n0"}]}, ["handle", "store"]),
+     {"keys": ["default/p0"], "nodeNames": ["n0"]}, ["handle", "store"]),
     ("/api/v1/pods:batch", {"items": []}, ["handle", "store"]),
 ])
 def test_a_request_that_does_not_ask_gets_the_plain_server_timing(
@@ -704,8 +703,8 @@ def test_a_traced_request_carries_the_servers_parts_in_process(verb):
                                    for i in range(200)])
         tr = tracing.enable()
         if verb == "bind_many":
-            assert remote.bind_many([("default", f"p{i}", "n0")
-                                     for i in range(200)]) == [None] * 200
+            assert remote.bind_many([f"default/p{i}" for i in range(200)],
+                                    ["n0"] * 200) == [None] * 200
         elif verb == "create_many":
             assert None not in remote.create_many(
                 "Pod", [make_pod(f"q{i}").to_dict() for i in range(200)])
@@ -732,18 +731,18 @@ def test_a_request_without_tracing_sends_no_ask_and_records_nothing():
     seen = []
 
     class Spy(Store):
-        def bind_many(self, items):
+        def bind_many(self, keys, node_names):
             seen.append(tracing.account())
-            return super().bind_many(items)
+            return super().bind_many(keys, node_names)
 
     store = Spy()
     store.create("Pod", make_pod("p0").to_dict())
     server = APIServer(store)
     server.start()
     try:
-        assert RemoteStore(server.url).bind_many([("default", "p0", "n0")]) == [None]
+        assert RemoteStore(server.url).bind_many(["default/p0"], ["n0"]) == [None]
         tr = tracing.enable()
-        assert RemoteStore(server.url).bind_many([("default", "p0", "n0")]) == [None]
+        assert RemoteStore(server.url).bind_many(["default/p0"], ["n0"]) == [None]
         assert seen[0] is None and seen[1] is not None
         assert tracing.account() is None, "the test's thread never had one"
         sp, = (s for s in tr.background if s.name == "remote.request")
@@ -775,8 +774,8 @@ def test_a_planted_holder_of_the_stores_lock_shows_as_store_lock():
         tr = tracing.enable()
         holder.start()
         held.wait(5)
-        assert remote.bind_many([("default", f"p{i}", "n0")
-                                 for i in range(50)]) == [None] * 50
+        assert remote.bind_many([f"default/p{i}" for i in range(50)],
+                                ["n0"] * 50) == [None] * 50
         holder.join()
         sp, = (s for s in tr.background if s.name == "remote.request")
         _assert_parts_account_for_the_request(sp, within_span=True)
@@ -815,7 +814,7 @@ def test_a_frames_watcher_encoding_a_bind_shows_as_watch_s_and_less_cpu():
             alone.append(sp.attrs["server_cpu_s"] / sp.attrs["server_s"])
         served = server.registry.get("apiserver_watch_serve_seconds_total")
         before = served.value
-        store.bind_many([("default", f"p{i}", "n0") for i in range(n)])
+        store.bind_many([f"default/p{i}" for i in range(n)], ["n0"] * n)
         # requests back to back while the frames are encoded and written:
         # a frame is counted by the request in which its write ended
         beside = []
@@ -884,8 +883,8 @@ def test_the_parts_of_a_child_apiserver_lie_inside_the_clients_round_trip():
         tr = tracing.enable()
         assert None not in remote.create_many(
             "Pod", [make_pod(f"p{i}", cpu="100m").to_dict() for i in range(500)])
-        assert remote.bind_many([("default", f"p{i}", "n0")
-                                 for i in range(500)]) == [None] * 500
+        assert remote.bind_many([f"default/p{i}" for i in range(500)],
+                                ["n0"] * 500) == [None] * 500
         assert len(remote.list("Pod")[0]) == 500
         spans = [s for s in tr.background if s.name == "remote.request"]
         assert len(spans) == 3

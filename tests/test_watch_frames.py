@@ -33,7 +33,7 @@ import tracemalloc
 import pytest
 
 from kubernetes_tpu import faults
-from kubernetes_tpu.api import Binding, ObjectMeta
+from kubernetes_tpu.api import BindingColumns, ObjectMeta
 from kubernetes_tpu.api import lazy as lazy_mod
 from kubernetes_tpu.api import types as api
 from kubernetes_tpu.client import Clientset
@@ -80,8 +80,8 @@ def test_frame_roundtrip_equals_per_event_delivery():
     framed = cs.store.watch("Pod", frames=True)
     plain = cs.store.watch("Pod")
     cs.pods.create_many([make_pod(f"p{i}", cpu="100m") for i in range(4)])
-    cs.pods.bind_many([Binding(pod_namespace="default", pod_name=f"p{i}",
-                               node_name="n1") for i in range(3)])
+    cs.pods.bind_many(BindingColumns([f"default/p{i}" for i in range(3)],
+                                     ["n1"] * 3))
     cs.pods.create(make_pod("solo", cpu="100m"))  # single: never framed
 
     framed_items = _drain(framed, 3)
@@ -104,8 +104,8 @@ def test_bind_frame_carries_prev_revision_and_node_columns():
         [make_pod(f"p{i}", cpu="100m") for i in range(3)])
     pre_revs = [c.meta.resource_version for c in created]
     _drain(w, 1)  # the ADDED frame
-    cs.pods.bind_many([Binding(pod_namespace="default", pod_name=f"p{i}",
-                               node_name=f"n{i}") for i in range(3)])
+    cs.pods.bind_many(BindingColumns([f"default/p{i}" for i in range(3)],
+                                     [f"n{i}" for i in range(3)]))
     frame = _drain(w, 1)[0]
     assert frame.type == FRAME and frame.kind == "Pod"
     assert frame.types == ["MODIFIED"] * 3
@@ -188,8 +188,8 @@ def test_informer_batch_apply_matches_per_event():
     framed.start_manual()
     plain.start_manual()
     cs.pods.create_many([make_pod(f"p{i}", cpu="100m") for i in range(6)])
-    cs.pods.bind_many([Binding(pod_namespace="default", pod_name=f"p{i}",
-                               node_name="n1") for i in range(6)])
+    cs.pods.bind_many(BindingColumns([f"default/p{i}" for i in range(6)],
+                                     ["n1"] * 6))
     cs.pods.delete("p5")
     framed.pump()
     plain.pump()
@@ -399,8 +399,7 @@ def test_confirm_falls_back_per_pod_on_intervening_write():
         d.setdefault("metadata", {}).setdefault("labels", {})["x"] = "y"
         return d
     cs.store.guaranteed_update("Pod", "default", "a", _label)
-    cs.pods.bind_many([Binding(pod_namespace="default", pod_name=n,
-                               node_name="n0") for n in ("a", "b")])
+    cs.pods.bind_many(BindingColumns(["default/a", "default/b"], ["n0", "n0"]))
     sched.pump()
     # both confirmed bound either way — "a" through the per-pod compare
     states, _nodes = _cache_fingerprint(sched.cache)
@@ -605,10 +604,9 @@ def test_txn_leaves_in_pieces_of_at_most_the_bound(small_bound, op, n):
     if op == "create_many":
         cs.pods.create_many(pods)
     else:
-        assert cs.pods.bind_many([
-            Binding(pod_namespace="default", pod_name=p.meta.name,
-                    node_name=f"n{i % 2}")
-            for i, p in enumerate(pods)]) == [None] * n
+        assert cs.pods.bind_many(BindingColumns(
+            [p.meta.key for p in pods],
+            [f"n{i % 2}" for i in range(n)])) == [None] * n
     # every piece is on the queue when the txn's call has returned
     got = _take_now(framed)
     assert all(g.type == FRAME for g in got)
@@ -693,8 +691,8 @@ def test_remote_pieces_with_and_without_a_field_selector(
                   field_selector=field_selector)
     we = rs.watch("Pod", from_revision=rev, field_selector=field_selector)
     assert _wait(lambda: wf._resp is not None and we._resp is not None)
-    cs.pods.bind_many([Binding(pod_namespace="default", pod_name=f"p{i:03d}",
-                               node_name=f"n{i % 2}") for i in range(n)])
+    cs.pods.bind_many(BindingColumns([f"default/p{i:03d}" for i in range(n)],
+                                     [f"n{i % 2}" for i in range(n)]))
     # all ten rows, or the five on n1: pieces of 4, 4, 2 re-packed 2, 2, 1
     want_rows = n if field_selector is None else n // 2
     want_lens = [4, 4, 2] if field_selector is None else [2, 2, 1]
@@ -769,8 +767,8 @@ def test_resumed_frames_watch_replays_a_txn_as_its_frames(
         return d
     store.guaranteed_update("Pod", "default", "p000", _label)
     pre[0] = rev0 + 1
-    cs.pods.bind_many([Binding(pod_namespace="default", pod_name=f"p{i:03d}",
-                               node_name=f"n{i % 2}") for i in range(n)])
+    cs.pods.bind_many(BindingColumns([f"default/p{i:03d}" for i in range(n)],
+                                     [f"n{i % 2}" for i in range(n)]))
     cs.pods.create(make_pod("solo", cpu="100m"))
     cs.pods.create_many([make_pod(f"q{i}", cpu="100m") for i in range(3)])
     live_items = _take_now(live)
@@ -844,8 +842,8 @@ def test_the_txn_index_is_trimmed_with_the_log(small_bound):
     pods = [make_pod(f"p{i:03d}", cpu="100m") for i in range(10)]
     pre = [c.meta.resource_version for c in cs.pods.create_many(pods)]
     assert [(t.first, t.last) for t in store._log_txns] == [(1, 10)]
-    cs.pods.bind_many([Binding(pod_namespace="default", pod_name=p.meta.name,
-                               node_name="n0") for p in pods])
+    cs.pods.bind_many(BindingColumns([p.meta.key for p in pods],
+                                     ["n0"] * len(pods)))
     # the creates have left the window and their txn the index with them;
     # the bind's first two rows have left too, and its column still lines
     # up with the rows that stay
@@ -936,8 +934,8 @@ def test_stream_past_its_deadline_writes_what_was_queued_then_ends(
         f"&timeoutSeconds=1&resourceVersion={rev}", timeout=10.0)
     assert _wait(lambda: len(api_server.store._watchers) > watchers)
     t0 = _time.monotonic()
-    cs.pods.bind_many([Binding(pod_namespace="default", pod_name=f"p{i:03d}",
-                               node_name=f"n{i % 2}") for i in range(n)])
+    cs.pods.bind_many(BindingColumns([f"default/p{i:03d}" for i in range(n)],
+                                     [f"n{i % 2}" for i in range(n)]))
     # a second txn, queued behind the first before the deadline: at the
     # deadline (two pieces are out) the third piece and this frame wait
     cs.pods.create_many([make_pod(f"q{i}", cpu="100m") for i in range(2)])
@@ -1041,8 +1039,7 @@ def test_informer_compact_cache_sweeps_synced_caches():
         assert obj.raw is None and obj.to_dict() == d
     # the sweep is idempotent and later deltas re-pin fresh payloads
     assert inf.compact_cache() == 0
-    cs.pods.bind_many([Binding(pod_namespace="default", pod_name="p0",
-                               node_name="n1")])
+    cs.pods.bind_many(BindingColumns(["default/p0"], ["n1"]))
     inf.pump()
     assert inf.get("default/p0").raw is not None
     assert inf.compact_cache() == 1
