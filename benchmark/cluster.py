@@ -6,6 +6,9 @@ yardstick), with two differences: the objects are wire dicts built from
 templates, not ``testutil`` objects, so 150,000 of them cost a fraction of a
 second; and every share is an exact count that the seed only permutes, so
 each seed schedules the same multiset of nodes and pods in another order.
+A template may deal its pods into replica groups with their services and
+namespaces, as kubernetes ``test/e2e/scalability/load.go`` makes them
+(``_group_labels`` and below).
 
 Nothing here imports the program.
 """
@@ -110,12 +113,84 @@ def make_nodes(config: dict, rng: random.Random) -> list:
     return nodes
 
 
+def _service(name: str, namespace: str, labels: dict, selector: dict) -> dict:
+    return {"kind": "Service", "metadata": _meta(name, namespace, labels),
+            "spec": {"selector": selector, "ports": [], "clusterIP": "",
+                     "type": "ClusterIP", "sessionAffinity": "None"},
+            "status": {"loadBalancer": {"ingress": []}}}
+
+
 def make_services(config: dict) -> list:
-    return [{"kind": "Service", "metadata": _meta(app, "default", {}),
-             "spec": {"selector": {"app": app}, "ports": [], "clusterIP": "",
-                      "type": "ClusterIP", "sessionAffinity": "None"},
-             "status": {"loadBalancer": {"ingress": []}}}
-            for app in config.get("services") or []]
+    """``services`` (one per ``app`` label, in ``default``), then those of
+    the replica groups (``_group_services``)."""
+    return ([_service(app, "default", {}, {"app": app})
+             for app in config.get("services") or []]
+            + [svc for tpl in config["pods"]["templates"] if tpl.get("groups")
+               for svc in _group_services(config, tpl)])
+
+
+def make_namespaces(config: dict) -> list:
+    """The namespaces the replica groups are dealt over (``pods.namespaces``),
+    named ``ns-0`` ... ``ns-<n-1>``."""
+    return [{"kind": "Namespace", "metadata": _meta(f"ns-{k}", "", {}),
+             "spec": {"finalizers": ["kubernetes"]}, "status": {"phase": "Active"}}
+            for k in range(config["pods"].get("namespaces") or 0)]
+
+
+# Replica groups, after load.go's ``GenerateConfigsForGroup`` and
+# ``generateServicesForConfigs``: a template's ``groups`` is {"count": c,
+# "replicas": r} and optionally "groups_per_service": k.  Group i (1..c) is
+# named ``<prefix>-<i>``, holds r pods labelled ``name: <prefix>-<i>``, and
+# lives in namespace ``ns-<i mod n>``; with k, its pods also carry
+# ``svc-label: <prefix>-<ceil(i / k)>``, and each value has one service,
+# named after the first group that carries it and in that group's namespace,
+# selecting the label there (so with k = 2 and more than one namespace it
+# selects that first group only, as upstream's does).  Group i takes the
+# template's variant (i - 1) mod len(variants).
+SVC_LABEL = "svc-label"
+
+
+def _group_namespace(config: dict, i: int) -> str:
+    n = config["pods"].get("namespaces")
+    return f"ns-{i % n}" if n else "default"
+
+
+def _group_labels(tpl: dict, i: int) -> dict:
+    groups, labels = tpl["groups"], {"name": f"{tpl['prefix']}-{i}"}
+    k = groups.get("groups_per_service")
+    if k:
+        labels[SVC_LABEL] = f"{tpl['prefix']}-{(i + k - 1) // k}"
+    app = tpl["variants"][(i - 1) % len(tpl["variants"])].get("app")
+    if app is not None:
+        labels["app"] = app
+    return labels
+
+
+def _group_services(config: dict, tpl: dict) -> list:
+    k = tpl["groups"].get("groups_per_service")
+    if not k:
+        return []
+    out = []
+    for first in range(1, tpl["groups"]["count"] + 1, k):
+        labels = _group_labels(tpl, first)
+        out.append(_service(
+            f"{labels['name']}-svc", _group_namespace(config, first),
+            {"name": labels["name"], SVC_LABEL: labels[SVC_LABEL]},
+            {SVC_LABEL: labels[SVC_LABEL]}))
+    return out
+
+
+def _group_slots(tpl: dict, n: int, rng: random.Random) -> list:
+    """Which group each of the template's ``n`` pods joins: every group
+    holds its ``replicas`` (the first groups, where ``n`` is smaller, as in
+    a rehearsal or a warm-up wave), and the seed only permutes."""
+    groups = tpl["groups"]
+    if n > groups["count"] * groups["replicas"]:
+        raise ValueError(f"template {tpl['prefix']!r}: {n} pods, but its groups "
+                         f"hold {groups['count'] * groups['replicas']}")
+    slots = [i // groups["replicas"] + 1 for i in range(n)]
+    rng.shuffle(slots)
+    return slots
 
 
 _POD_STATUS = {"phase": "Pending", "conditions": [], "hostIP": "", "podIP": "",
@@ -175,10 +250,24 @@ def make_pods(config: dict, rng: random.Random, count: int, tag: str = "",
             rng.shuffle(disks[t])
             if collide_disks and len(disks[t]) >= 2:
                 disks[t][1] = disks[t][0]
+    # replica groups: each pod takes the next slot of its template's dealt
+    # list; a group's labels and namespace are one shared dict and string
+    slots = {t: iter(_group_slots(tpl, counts[t], rng))
+             for t, tpl in enumerate(templates) if tpl.get("groups")}
+    group_meta: dict = {}
     pods = []
     for i, t in enumerate(which):
         tpl = templates[t]
-        v = i % len(tpl["variants"])
+        namespace, labels = "default", None
+        if t in slots:
+            g = next(slots[t])
+            v = (g - 1) % len(tpl["variants"])
+            if (t, g) not in group_meta:
+                group_meta[(t, g)] = (_group_namespace(config, g),
+                                      _group_labels(tpl, g))
+            namespace, labels = group_meta[(t, g)]
+        else:
+            v = i % len(tpl["variants"])
         variant = tpl["variants"][v]
         spec = shared[(t, v)]
         if t in disks:
@@ -188,8 +277,8 @@ def make_pods(config: dict, rng: random.Random, count: int, tag: str = "",
                 "readOnly": False, "pvcName": "", "secretName": "",
                 "configMapName": ""}])
         pods.append({"kind": "Pod",
-                     "metadata": _meta(f"{tpl['prefix']}-{tag}{i:06d}", "default",
-                                       {"app": variant["app"]}),
+                     "metadata": _meta(f"{tpl['prefix']}-{tag}{i:06d}", namespace,
+                                       labels or {"app": variant["app"]}),
                      "spec": spec, "status": _POD_STATUS})
     return pods
 
@@ -208,6 +297,7 @@ class World:
         rng = random.Random(seed)
         self.config = config
         self.nodes = make_nodes(config, rng)
+        self.namespaces = make_namespaces(config)
         self.services = make_services(config)
         self.preload = make_pods(config, rng, plan["preload"])
         self.window = make_pods(config, rng, plan["window_pods"], tag="a")

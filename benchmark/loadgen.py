@@ -136,8 +136,8 @@ def main(argv=None) -> int:
         if cmd == "populate":
             t = time.monotonic()
             create("Node", world.nodes)
-            for svc in world.services:
-                remote.create("Service", svc)
+            create("Namespace", world.namespaces)
+            create("Service", world.services)
             create("Pod", world.preload)
             _, rev = remote.list("Pod", field_selector="spec.nodeName=no-such-node")
             # where the window has creates the watches time them, so they run

@@ -158,30 +158,63 @@ class PodClass:
         self.best_effort = not any_request
         self.node_selector = spec.get("nodeSelector") or {}
         self.tolerations = spec.get("tolerations") or []
+        # what the node masks and taint scores depend on: classes that share
+        # them share one memoised array
+        self.taint_key = json.dumps(self.tolerations, sort_keys=True)
+        self.static_key = json.dumps(
+            [self.taint_key, self.best_effort, self.node_selector], sort_keys=True)
         aff = spec.get("affinity") or {}
         if aff.get("nodeAffinityRequired") or aff.get("nodeAffinityPreferred"):
             raise Unsupported("node affinity")
-        self.aff_required = aff.get("podAffinityRequired") or []
-        self.anti_required = aff.get("podAntiAffinityRequired") or []
-        self.aff_preferred = [(w["weight"], w["podAffinityTerm"])
-                              for w in aff.get("podAffinityPreferred") or []]
-        self.anti_preferred = [(w["weight"], w["podAffinityTerm"])
-                               for w in aff.get("podAntiAffinityPreferred") or []]
-        self.has_affinity = bool(self.aff_required or self.anti_required
-                                 or self.aff_preferred or self.anti_preferred)
+        self.terms = (
+            [Term(self, t, AFF_REQUIRED, HARD_POD_AFFINITY_WEIGHT)
+             for t in aff.get("podAffinityRequired") or []]
+            + [Term(self, t, ANTI_REQUIRED, 0)
+               for t in aff.get("podAntiAffinityRequired") or []]
+            + [Term(self, w["podAffinityTerm"], AFF_PREFERRED, w["weight"])
+               for w in aff.get("podAffinityPreferred") or []]
+            + [Term(self, w["podAffinityTerm"], ANTI_PREFERRED, -w["weight"])
+               for w in aff.get("podAntiAffinityPreferred") or []])
+        # kept by the Reference as classes appear and pods are placed
+        self.services: tuple = ()       # indices of the services selecting it
+        self.selected_by: list = []     # every Term whose scope holds it
+        self.placed: list = []          # node of each placed pod
+        self.counted_in: list = []      # per-node counts that hold its pods
+        self.own: Optional[np.ndarray] = None   # its pods per node, if it owns a term
 
 
-def _term_selects(term: dict, owner_ns: str, cand: PodClass) -> bool:
-    """PodMatchesTermsNamespaceAndSelector: is ``cand`` in the term's scope?"""
-    namespaces = term.get("namespaces") or [owner_ns]
-    if cand.ns not in namespaces:
-        return False
-    return _selector_matches(term.get("labelSelector"), cand.labels)
+AFF_REQUIRED, ANTI_REQUIRED, AFF_PREFERRED, ANTI_PREFERRED = range(4)
+
+
+class Term:
+    """One inter-pod (anti-)affinity term of a class.  ``weight`` is signed
+    (anti-affinity negative; a required affinity term's is the symmetric
+    weight of priorities/interpod_affinity.go); ``count`` is the per-node
+    number of placed pods in the term's scope, kept by the Reference."""
+
+    def __init__(self, owner: PodClass, term: dict, kind: int, weight: int):
+        self.owner, self.term, self.kind, self.weight = owner, term, kind, weight
+        self.key = term.get("topologyKey", "")
+        self.count: Optional[np.ndarray] = None
+        self.selects_owner = False
+
+    def selects(self, cand: PodClass) -> bool:
+        """PodMatchesTermsNamespaceAndSelector: is ``cand`` in the scope?"""
+        if cand.ns not in (self.term.get("namespaces") or [self.owner.ns]):
+            return False
+        return _selector_matches(self.term.get("labelSelector"), cand.labels)
 
 
 class Reference:
     """Cluster state and one-pod decisions.  ``nodes`` and ``services`` are
-    wire objects; the node axis is sorted by name."""
+    wire objects; the node axis is sorted by name.
+
+    A decision reads per-node counts that ``place`` keeps current, so it
+    walks no list of classes or services: the count of the pods the class's
+    services select (made when a class with those services is first scored),
+    each term's count and each term owner's own.  A count made after pods were
+    placed sums their classes once; ``visits`` counts the classes so summed
+    or checked against a new term."""
 
     def __init__(self, nodes: list, services: list,
                  request_bits: Optional[int] = None):
@@ -231,14 +264,23 @@ class Reference:
                           (s.get("spec") or {}).get("selector") or {})
                          for s in services]
         self.services = [s for s in self.services if s[1]]
+        # a service is found through one (namespace, key, value) of its
+        # selector; its classes and the spread counts it takes part in are
+        # extended as classes appear
+        self._services_by_pair: dict = {}
+        for s, (ns, sel) in enumerate(self.services):
+            self._services_by_pair.setdefault((ns, *min(sel.items())), []).append(s)
+        self._classes_of_service = [[] for _ in self.services]
+        self._spreads_of_service = [[] for _ in self.services]
+        self._spreads: dict = {}            # services -> per-node count
+        self._terms: list = []
+        self.visits = 0
         self.round_robin = 0
         self._classes: dict = {}
         self._class_of_pod: dict = {}
         self.classes: list = []
-        self.class_count: list = []         # per class: pods per node
         self._static: dict = {}
         self._topo: dict = {}
-        self._spread_classes: dict = {}
         self._memo: dict = {}
         # disks: (kind, id) -> [(node, read_only)], kind -> ids per node
         self.disk_users: dict = {}
@@ -273,10 +315,49 @@ class Reference:
         if cls is None:
             cls = PodClass(len(self.classes), meta.get("namespace") or "default",
                            meta.get("labels") or {}, spec, self.request_bits)
+            self._add_class(cls)
             self._classes[key] = cls
-            self.classes.append(cls)
-            self.class_count.append(np.zeros(self.n, np.int64))
         return cls
+
+    def _add_class(self, cls: PodClass) -> None:
+        """Enter a new class (it has no pods placed yet) in the counts that
+        hold it, and make the counts of its own terms."""
+        found = [s for k, v in cls.labels.items()
+                 for s in self._services_by_pair.get((cls.ns, k, v), ())
+                 if all(cls.labels.get(a) == b
+                        for a, b in self.services[s][1].items())]
+        cls.services = tuple(sorted(found))
+        joined: set = set()
+        for s in cls.services:
+            self._classes_of_service[s].append(cls)
+            for count in self._spreads_of_service[s]:
+                if id(count) not in joined:
+                    joined.add(id(count))
+                    cls.counted_in.append(count)
+        for term in self._terms:
+            if term.selects(cls):
+                cls.selected_by.append(term)
+                cls.counted_in.append(term.count)
+        self.classes.append(cls)
+        for term in cls.terms:
+            self.visits += len(self.classes)
+            scope = [c for c in self.classes if term.selects(c)]
+            term.count = self._count(scope)
+            term.selects_owner = cls in scope
+            for c in scope:
+                c.selected_by.append(term)
+            self._terms.append(term)
+        if cls.terms:
+            cls.own = self._count([cls])
+
+    def _count(self, classes: list) -> np.ndarray:
+        """Per-node number of placed pods of ``classes``, kept by ``place``
+        from now on."""
+        count = np.zeros(self.n, np.int32)
+        for c in classes:
+            np.add.at(count, c.placed, 1)
+            c.counted_in.append(count)
+        return count
 
     def _topology(self, key: str) -> tuple:
         """(value id per node or -1, number of values) for a label key."""
@@ -293,7 +374,7 @@ class Reference:
     def _static_mask(self, cls: PodClass) -> np.ndarray:
         """Node conditions, taints and the node selector: what no placement
         changes."""
-        got = self._static.get(cls.cid)
+        got = self._static.get(cls.static_key)
         if got is None:
             set_ok = np.array([
                 all(any(_tolerates(t, taint) for t in cls.tolerations)
@@ -307,11 +388,11 @@ class Reference:
                 got = got & np.array([
                     all(lab.get(k) == v for k, v in cls.node_selector.items())
                     for lab in self.labels], bool)
-            self._static[cls.cid] = got
+            self._static[cls.static_key] = got
         return got
 
     def _taint_score_counts(self, cls: PodClass) -> np.ndarray:
-        key = ("taintscore", cls.cid)
+        key = ("taintscore", cls.taint_key)
         got = self._memo.get(key)
         if got is None:
             per_set = np.array([
@@ -320,13 +401,6 @@ class Reference:
                     and not any(_tolerates(t, taint) for t in cls.tolerations))
                 for taints in self.taint_sets], np.int64)
             got = self._memo[key] = per_set[self.taint_set_of]
-        return got
-
-    def _selects(self, term: dict, owner: PodClass, cand: PodClass) -> bool:
-        key = ("term", id(term), owner.cid, cand.cid)
-        got = self._memo.get(key)
-        if got is None:
-            got = self._memo[key] = _term_selects(term, owner.ns, cand)
         return got
 
     # -- feasibility ---------------------------------------------------------
@@ -341,40 +415,24 @@ class Reference:
             ok = ok & self._volumes_ok(volumes)
         # inter-pod affinity: symmetry first (existing pods' required
         # anti-affinity that selects this pod), then the pod's own terms
-        for other in self.classes:
-            if not other.anti_required:
+        for term in cls.selected_by:
+            if term.kind == ANTI_REQUIRED:
+                hosts = term.owner.own > 0
+                if hosts.any():
+                    ok = ok & ~self._same_domain(term.key, hosts,
+                                                 empty_key_everywhere=True)
+        for term in cls.terms:
+            if term.kind not in (AFF_REQUIRED, ANTI_REQUIRED):
                 continue
-            hosts = self.class_count[other.cid] > 0
-            if not hosts.any():
-                continue
-            for term in other.anti_required:
-                if self._selects(term, other, cls):
-                    ok = ok & ~self._same_domain(term.get("topologyKey", ""),
-                                                 hosts, empty_key_everywhere=True)
-        for term in cls.aff_required:
-            hosts, exists = self._hosts_matching(term, cls)
-            key = term.get("topologyKey", "")
-            if not key:
+            if not term.key:
                 return np.zeros(self.n, bool)
-            satisfied = self._same_domain(key, hosts)
-            if exists or not self._selects(term, cls, cls):
-                ok = ok & satisfied
-        for term in cls.anti_required:
-            hosts, _ = self._hosts_matching(term, cls)
-            key = term.get("topologyKey", "")
-            if not key:
-                return np.zeros(self.n, bool)
-            ok = ok & ~self._same_domain(key, hosts)
+            # nodes that hold a pod in the term's scope
+            hosts = term.count > 0
+            if term.kind == ANTI_REQUIRED:
+                ok = ok & ~self._same_domain(term.key, hosts)
+            elif hosts.any() or not term.selects_owner:
+                ok = ok & self._same_domain(term.key, hosts)
         return ok
-
-    def _hosts_matching(self, term: dict, owner: PodClass) -> tuple:
-        """Nodes that hold a pod the owner's term selects, and whether any
-        such pod exists."""
-        hosts = np.zeros(self.n, bool)
-        for cand in self.classes:
-            if self._selects(term, owner, cand):
-                hosts |= self.class_count[cand.cid] > 0
-        return hosts, bool(hosts.any())
 
     def _same_domain(self, key: str, hosts: np.ndarray,
                      empty_key_everywhere: bool = False) -> np.ndarray:
@@ -446,17 +504,21 @@ class Reference:
             total = total + (MAX_PRIORITY * (max_c - counts)) // max_c
         return total
 
+    def _spread_count(self, cls: PodClass) -> np.ndarray:
+        """Per node, the placed pods that any service selecting ``cls``
+        selects (in its namespace, since a service selects there only)."""
+        got = self._spreads.get(cls.services)
+        if got is None:
+            members = {c.cid: c for s in cls.services
+                       for c in self._classes_of_service[s]}
+            self.visits += len(members)
+            got = self._spreads[cls.services] = self._count(list(members.values()))
+            for s in cls.services:
+                self._spreads_of_service[s].append(got)
+        return got
+
     def _spread(self, cls: PodClass, feas: np.ndarray) -> np.ndarray:
-        sel_classes = self._spread_classes.get(cls.cid)
-        if sel_classes is None or sel_classes[0] != len(self.classes):
-            sels = [sel for ns, sel in self.services if ns == cls.ns
-                    and all(cls.labels.get(k) == v for k, v in sel.items())]
-            members = [c.cid for c in self.classes if c.ns == cls.ns and any(
-                all(c.labels.get(k) == v for k, v in sel.items()) for sel in sels)]
-            sel_classes = self._spread_classes[cls.cid] = (len(self.classes), members)
-        cnt = np.zeros(self.n, np.int64)
-        for cid in sel_classes[1]:
-            cnt += self.class_count[cid]
+        cnt = self._spread_count(cls).astype(np.int64)
         cnt_f = np.where(feas, cnt, 0)
         max_n = int(cnt_f.max())
         full = MAX_PRIORITY * FIXED
@@ -474,39 +536,25 @@ class Reference:
         return np.where(self.zone >= 0, blended, node_fp) // FIXED
 
     def _interpod(self, cls: PodClass, feas: np.ndarray) -> np.ndarray:
+        """The pod's preferred terms weigh the pods in their scope; the terms
+        of placed pods that hold this pod in theirs (required affinity with
+        the symmetric weight, both preferred kinds) weigh their owners."""
         counts = np.zeros(self.n, np.int64)
         touched = False
-        for other in self.classes:
-            here = self.class_count[other.cid]
-            weights = []     # (topology key, weight) for pods of ``other``
-            for w, term in cls.aff_preferred:
-                if self._selects(term, cls, other):
-                    weights.append((term.get("topologyKey", ""), w))
-            for w, term in cls.anti_preferred:
-                if self._selects(term, cls, other):
-                    weights.append((term.get("topologyKey", ""), -w))
-            if other.has_affinity:
-                for term in other.aff_required:
-                    if self._selects(term, other, cls):
-                        weights.append((term.get("topologyKey", ""),
-                                        HARD_POD_AFFINITY_WEIGHT))
-                for w, term in other.aff_preferred:
-                    if self._selects(term, other, cls):
-                        weights.append((term.get("topologyKey", ""), w))
-                for w, term in other.anti_preferred:
-                    if self._selects(term, other, cls):
-                        weights.append((term.get("topologyKey", ""), -w))
-            if not weights or not here.any():
+        weighed = [(t, t.count) for t in cls.terms
+                   if t.kind in (AFF_PREFERRED, ANTI_PREFERRED)]
+        weighed += [(t, t.owner.own) for t in cls.selected_by
+                    if t.kind != ANTI_REQUIRED]
+        for term, here in weighed:
+            if not term.key or not here.any():
                 continue
-            for key, w in weights:
-                if not key:
-                    continue
-                vals, n_vals = self._topology(key)
-                on = vals >= 0
-                per_value = np.bincount(vals[on], weights=here[on] * w,
-                                        minlength=n_vals).astype(np.int64)
-                counts += np.where(on, per_value[np.maximum(vals, 0)], 0)
-                touched = True
+            vals, n_vals = self._topology(term.key)
+            on = vals >= 0
+            weights = here[on].astype(np.int64) * term.weight
+            per_value = np.bincount(vals[on], weights=weights,
+                                    minlength=n_vals).astype(np.int64)
+            counts += np.where(on, per_value[np.maximum(vals, 0)], 0)
+            touched = True
         if not touched:
             return counts
         among = counts[feas]
@@ -537,7 +585,9 @@ class Reference:
         self.nz_used[0, node] += cls.exact_nz[0]
         self.nz_used[1, node] += cls.exact_nz[1]
         self.count[node] += 1
-        self.class_count[cls.cid][node] += 1
+        cls.placed.append(node)
+        for count in cls.counted_in:
+            count[node] += 1
         for vol in pod["spec"].get("volumes") or []:
             disk, kind = vol.get("diskID"), vol.get("diskKind", "")
             if not disk:

@@ -2,6 +2,8 @@
 their shares, odd in their request values, Poisson in shape."""
 
 import collections
+import hashlib
+import json
 import copy
 import random
 
@@ -128,3 +130,91 @@ def test_backlog_preloads_the_whole_deployment():
                     "warm_waves": []}
     with pytest.raises(ValueError):
         traffic.plan({"kind": "replay"}, config, 3, 20.0)
+
+
+# sha256 of each object's canonical JSON (nodes, services, preload, window),
+# read from the generator before replica groups existed: a configuration
+# that uses none of their keys builds the same world, byte for byte
+WORLD_DIGESTS = [
+    ("perf-2k", 1, "426163dc32e39207"), ("perf-2k", 2**31 + 5, "d2471dae8078613f"),
+    ("density-5k", 1, "cfd71e37683cdd0d"), ("density-5k", 2**31 + 5, "d0639097adcb1266"),
+    ("density-2k", 1, "f90a85ffceab1e48"), ("density-2k", 2**31 + 5, "e44de5368cbc6d85")]
+
+
+@pytest.mark.parametrize("name,seed,digest", WORLD_DIGESTS)
+def test_a_world_without_replica_groups_is_unchanged(name, seed, digest):
+    config = cluster.load_config(name)
+    plan = traffic.plan(cluster.load_traffic("backlog"), config, seed, 20.0)
+    world = cluster.World(config, seed, plan)
+    encode = json.JSONEncoder(sort_keys=True, separators=(",", ":")).encode
+    h = hashlib.sha256()
+    for group in (world.nodes, world.services, world.preload, world.window):
+        for obj in group:
+            h.update(encode(obj).encode())
+    assert h.hexdigest()[:16] == digest
+    assert world.namespaces == []
+
+
+def _grouped(namespaces: int = 4, groups_per_service: int = 2) -> dict:
+    """load.go's three group sizes at 2,000 pods: 2 x 250, 16 x 30, 204 x 5."""
+    config = _small("density-5k", nodes=70)
+    config["services"] = []
+    config["pods"] = {"count": 2_000, "namespaces": namespaces, "templates": [
+        {"prefix": f"load-{size}", "share": count * replicas / 2_000,
+         "variants": [{"cpu": "10m", "memory": "25Mi"},
+                      {"cpu": "257m", "memory": "513Mi"}],
+         "groups": {"count": count, "replicas": replicas,
+                    "groups_per_service": groups_per_service}}
+        for size, count, replicas in (("big", 2, 250), ("medium", 16, 30),
+                                      ("small", 204, 5))]}
+    return config
+
+
+@pytest.mark.parametrize("seed", [1, 2**31 + 7, 977])
+def test_replica_groups_services_and_namespaces_are_exact(seed):
+    config = _grouped()
+    world = cluster.World(config, seed, {"preload": 2_000, "window_pods": 0})
+    assert [n["metadata"]["name"] for n in world.namespaces] == [
+        "ns-0", "ns-1", "ns-2", "ns-3"]
+    want, services = {}, 0
+    for tpl in config["pods"]["templates"]:
+        groups = tpl["groups"]
+        for i in range(1, groups["count"] + 1):
+            want[(f"ns-{i % 4}", f"{tpl['prefix']}-{i}",
+                  f"{tpl['prefix']}-{(i + 1) // 2}",
+                  tpl["variants"][(i - 1) % 2]["cpu"])] = groups["replicas"]
+        services += (groups["count"] + 1) // 2
+    got = collections.Counter(
+        (p["metadata"]["namespace"], p["metadata"]["labels"]["name"],
+         p["metadata"]["labels"]["svc-label"],
+         p["spec"]["containers"][0]["resources"]["requests"]["cpu"])
+        for p in world.preload)
+    assert got == want
+    assert len(world.services) == services == 1 + 8 + 102
+    # a service selects, in its namespace, the first of its two groups
+    for svc in world.services:
+        ns, sel = svc["metadata"]["namespace"], svc["spec"]["selector"]
+        chosen = {p["metadata"]["labels"]["name"] for p in world.preload
+                  if p["metadata"]["namespace"] == ns
+                  and all(p["metadata"]["labels"].get(k) == v
+                          for k, v in sel.items())}
+        assert chosen == {svc["metadata"]["name"][:-len("-svc")]}
+    other = cluster.World(config, seed + 1, {"preload": 2_000, "window_pods": 0})
+    assert other.services == world.services and other.namespaces == world.namespaces
+    assert [p["metadata"]["labels"] for p in other.preload] != [
+        p["metadata"]["labels"] for p in world.preload]
+
+
+def test_replica_groups_fill_in_order_where_fewer_pods_are_made():
+    """A rehearsal or a warm-up wave makes fewer pods than the groups hold:
+    the first groups are whole, and more pods than they hold is an error."""
+    config = _grouped(namespaces=0, groups_per_service=1)
+    pods = cluster.make_pods(config, random.Random(5), 200)
+    got = collections.Counter(p["metadata"]["labels"]["name"] for p in pods)
+    assert got == {"load-big-1": 50, "load-medium-1": 30, "load-medium-2": 18,
+                   **{f"load-small-{i}": 5 for i in range(1, 21)}, "load-small-21": 2}
+    assert {p["metadata"]["namespace"] for p in pods} == {"default"}
+    assert all(p["metadata"]["labels"]["svc-label"] == p["metadata"]["labels"]["name"]
+               for p in pods)
+    with pytest.raises(ValueError):
+        cluster.make_pods(config, random.Random(5), 2_001)

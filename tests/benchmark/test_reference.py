@@ -9,11 +9,56 @@ import pytest
 from benchmark import check, cluster, reference
 
 
-def _world(name: str, nodes: int, pods: int, seed: int):
-    config = copy.deepcopy(cluster.load_config(name))
+def load_go(nodes: int, pods: int, namespaces: int, groups_per_service: int,
+            app_services: bool = False) -> dict:
+    """The objects of kubernetes test/e2e/scalability/load.go at ``nodes`` x
+    about ``pods``: computePodCounts' groups of 250, 30 and 5 (a quarter,
+    a quarter and the rest of the pods), one service per
+    ``groups_per_service`` groups, the groups dealt over ``namespaces``, on
+    density-5k's nodes.  load.go's pods all request 10m and 25Mi; groups
+    take turns with two odd requests too, so that a rounded gather shows.
+    ``app_services``: the groups live in ``default``, carry an ``app`` label
+    each, and density-5k's services select them beside their own."""
+    config = copy.deepcopy(cluster.load_config("density-5k"))
     config["nodes"]["count"] = nodes
-    config["pods"]["count"] = pods
-    return cluster.World(config, seed, {"preload": pods, "window_pods": 0})
+    big = pods // 4 // 250
+    medium = (pods - 250 * big) // 3 // 30
+    small = (pods - 250 * big - 30 * medium) // 5
+    total = 250 * big + 30 * medium + 5 * small
+    variants = [{"cpu": "10m", "memory": "25Mi", "app": "web"},
+                {"cpu": "257m", "memory": "513Mi", "app": "api"},
+                {"cpu": "1100m", "memory": "1131Mi", "app": "db"}]
+    if not app_services:
+        config["services"] = []
+        variants = [{k: v for k, v in var.items() if k != "app"} for var in variants]
+    config["pods"] = {"count": total, "per_node": 30, "templates": [
+        {"prefix": f"load-{size}", "share": count * replicas / total,
+         "variants": variants,
+         "groups": {"count": count, "replicas": replicas,
+                    "groups_per_service": groups_per_service}}
+        for size, count, replicas in (("big", big, 250), ("medium", medium, 30),
+                                      ("small", small, 5)) if count]}
+    if namespaces:
+        config["pods"]["namespaces"] = namespaces
+    return config
+
+
+# load.go's shapes: a service per group, per two groups (which, over more
+# than one namespace, selects the first only, as upstream's does), and per
+# two groups in one namespace beside services that select by ``app``
+LOAD = {"load.per-group": (3, 1, False), "load.per-two": (3, 2, False),
+        "load.shared": (0, 2, True)}
+
+
+def _world(name: str, nodes: int, pods: int, seed: int):
+    if name in LOAD:
+        config = load_go(nodes, pods, *LOAD[name])
+    else:
+        config = copy.deepcopy(cluster.load_config(name))
+        config["nodes"]["count"] = nodes
+        config["pods"]["count"] = pods
+    return cluster.World(config, seed, {"preload": config["pods"]["count"],
+                                        "window_pods": 0})
 
 
 def _oracle(world):
@@ -48,7 +93,9 @@ def _oracle(world):
 
 @pytest.mark.parametrize("name,nodes,pods,seed", [
     ("density-5k", 40, 1_200, 1), ("density-5k", 24, 1_500, 2**31 + 3),
-    ("perf-2k", 30, 1_500, 5), ("perf-2k", 12, 1_300, 6)])
+    ("perf-2k", 30, 1_500, 5), ("perf-2k", 12, 1_300, 6),
+    ("load.per-group", 40, 1_500, 7), ("load.per-two", 40, 1_500, 2**31 + 9),
+    ("load.shared", 40, 1_500, 11)])
 def test_reference_equals_the_programs_oracle(name, nodes, pods, seed):
     world = _world(name, nodes, pods, seed)
     order, bindings, tie_counter = _oracle(world)
@@ -69,7 +116,7 @@ def test_reference_equals_the_programs_oracle(name, nodes, pods, seed):
 
 
 @pytest.mark.parametrize("seed", [1, 2, 3])
-@pytest.mark.parametrize("name", ["density-5k", "perf-2k"])
+@pytest.mark.parametrize("name", ["density-5k", "perf-2k", "load.per-two"])
 def test_the_control_comes_out_as_not_correct(name, seed):
     """Gathering requests in eight bits of mantissa (257 -> 256, 1,001 ->
     1,000, 1,100 -> 1,104) moves scores and so bindings."""
@@ -108,3 +155,32 @@ def test_quantities_and_unsupported_features():
     pod["spec"]["nodeName"] = "node-00000"
     with pytest.raises(reference.Unsupported):
         ref.feasible(pod)
+
+
+def test_a_decision_visits_only_the_classes_its_services_select():
+    """With over 1,000 classes placed, a pod without inter-pod terms whose
+    service is new to the reference sums the one class that service selects
+    so far (its own), and its sibling group, which joins that service's
+    count as it appears, sums none."""
+    config = load_go(400, 12_000, 1, 2)
+    world = cluster.World(config, 3, {"preload": config["pods"]["count"],
+                                      "window_pods": 0})
+    svc = world.preload[-1]["metadata"]["labels"]["svc-label"]
+    held = [p for p in world.preload if p["metadata"]["labels"]["svc-label"] == svc]
+    assert len({p["metadata"]["labels"]["name"] for p in held}) == 2
+    ref = reference.Reference(world.nodes, world.services)
+    for pod in world.preload:
+        if pod["metadata"]["labels"]["svc-label"] != svc:
+            ref.schedule(pod)
+    assert len(ref.classes) >= 1_000
+    ref.visits = 0
+    feas = ref.feasible(held[0])
+    assert ref.visits == 0
+    node, advances = ref.choose(held[0], feas)
+    assert feas.sum() >= 2 and ref.visits == 1
+    ref.place(held[0], node, advances)
+    for pod in held[1:]:
+        assert ref.schedule(pod) is not None
+    assert ref.visits == 1
+    assert len(ref.classes) == len({p["metadata"]["labels"]["name"]
+                                    for p in world.preload})
