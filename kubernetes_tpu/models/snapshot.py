@@ -124,11 +124,11 @@ def pod_signature_key(pod: api.Pod) -> tuple:
     nested tuple, NOT a string: serializing to json cost more than every
     consumer's dict lookups combined at 150k-pod scale.
 
-    Memoized on the pod object: the backend's segmenter and build_static
-    both key every pod of every segment.  Safe because batch pods are
-    immutable while in flight (informer objects; mutation is a bug the
-    cache mutation detector exists to catch) — a spec patch produces a new
-    object and therefore a fresh key.
+    Memoized on the pod object: the scheduler's overlapped prep warms it
+    and the wave planner (``plan_segments``) reads it for every pod.  Safe
+    because batch pods are immutable while in flight (informer objects;
+    mutation is a bug the cache mutation detector exists to catch) — a
+    spec patch produces a new object and therefore a fresh key.
 
     Lazy pods whose spec is still undecoded key straight off the wire
     dict (``_raw_sig_spec_parts``): identical tuples for store
@@ -196,9 +196,9 @@ def pod_signature_key(pod: api.Pod) -> tuple:
 
 def count_affinity_terms(pod: api.Pod) -> int:
     """Number of (anti)affinity term rows this pod contributes to the [T, G]
-    tables (empty-topology-key terms never become rows).  Shared by the
-    build_static budget probe and the backend's segmenter so both always
-    agree on what fits.  The raw branch mirrors the ``from_dict``
+    tables (empty-topology-key terms never become rows), counted by
+    ``plan_segments`` once per signature of a segment: its sum is the
+    budget ``build_static`` checks.  The raw branch mirrors the ``from_dict``
     topology-key default (absent key → hostname → counts)."""
     spec_raw = lazy_mod.undecoded_spec(pod)
     if spec_raw is not None:
@@ -229,13 +229,16 @@ def count_affinity_terms(pod: api.Pod) -> int:
 
 def _disk_refs(pod: api.Pod) -> list:
     """(disk_kind, disk_id, read_only) per direct-disk volume reference,
-    raw-first: the [P] loops (build_static slot fill, host-state ingest)
-    must never decode a spec just to learn it has no volumes."""
+    raw-first: the [P] loops (the wave planner, host-state ingest) must
+    never decode a spec just to learn it has no volumes."""
     spec_raw = lazy_mod.undecoded_spec(pod)
     if spec_raw is not None:
+        vols = spec_raw.get("volumes")
+        if not vols:
+            return []
         return [(v.get("diskKind", ""), v.get("diskID", ""),
                  bool(v.get("readOnly", False)))
-                for v in spec_raw.get("volumes") or () if v.get("diskID")]
+                for v in vols if v.get("diskID")]
     if not pod.spec.volumes:
         return []
     return [(v.disk_kind, v.disk_id, v.read_only)
@@ -246,6 +249,126 @@ def pod_disk_vols(pod: api.Pod) -> set:
     """Distinct (disk_kind, disk_id) identities the pod references — the
     per-pod volume-slot budget unit (same sharing contract as above)."""
     return {(kind, disk_id) for kind, disk_id, _ in _disk_refs(pod)}
+
+
+@dataclass
+class SegmentColumns:
+    """A kernel segment's per-pod facts, read once by ``plan_segments``:
+    ``build_static`` indexes these columns and walks no pod.
+
+    ``group_of_pod`` numbers signatures by first appearance within the
+    segment, ``reps`` holds each signature's first pod and ``n_terms``
+    the (anti)affinity term rows they carry.  ``disk_rows`` are the
+    positions of the pods that reference a direct disk, each with its
+    ``_disk_refs`` in ``disk_refs``; a disk-free pod has no row."""
+
+    segment: list  # (wave index, pod)
+    pods: list
+    group_of_pod: np.ndarray
+    reps: list
+    n_terms: int
+    keys: list  # meta.key per pod
+    disk_rows: list
+    disk_refs: list
+
+
+# plan_segments' limits for pods a caller hands over as one segment: no
+# cut, and build_static itself rejects what exceeds its budgets
+_ONE_SEGMENT = (float("inf"),) * 5
+
+
+def plan_segments(pods: list, mounted, max_pods, max_groups, max_terms,
+                  max_vols, vols_per_pod) -> list:
+    """Cut the (ordered) pods into kernel segments that respect the tensor
+    budgets, reading each pod's facts once: ``[("kernel",
+    SegmentColumns) | ("oracle", [(i, pod)]), ...]``.  A cut falls where
+    the segment would exceed ``max_pods`` pods, ``max_groups`` signatures,
+    ``max_terms`` affinity term rows or ``max_vols`` conflict-capable
+    disks; a pod with more than ``vols_per_pod`` distinct disks, which no
+    kernel expresses, becomes an oracle singleton.  A kernel segment is a
+    run of consecutive pods.
+
+    The disk budget counts CONFLICT-CAPABLE disks only (already in
+    ``mounted`` or shared within the segment): ``build_static`` gives a
+    singleton unmounted disk no identity row.  It re-judges them against
+    the disks mounted when it runs, which segments placed after this
+    plan was made may have added to."""
+    out: list = []
+    start = n = 0  # the current segment is pods[start:start + n]
+    sig_ids: dict = {}
+    reps: list = []
+    groups: list = []
+    disk_rows: list = []
+    disk_refs: list = []
+    vols_once: set = set()
+    vols_conflict: set = set()
+    n_terms = 0
+    # pods the segment takes before a cut, for a pod that adds no
+    # signature, term or disk: 0 while a budget is already exceeded
+    room = max_pods
+
+    def flush(next_start: int) -> None:
+        nonlocal start, n, sig_ids, reps, groups, disk_rows, disk_refs
+        nonlocal vols_once, vols_conflict, n_terms, room
+        if n:
+            seg_pods = pods[start:start + n]
+            out.append(("kernel", SegmentColumns(
+                list(zip(range(start, start + n), seg_pods)), seg_pods,
+                np.array(groups, dtype=np.int32), reps, n_terms,
+                [pod.meta.key for pod in seg_pods], disk_rows, disk_refs)))
+        start, n = next_start, 0
+        sig_ids, reps, groups, disk_rows, disk_refs = {}, [], [], [], []
+        vols_once, vols_conflict, n_terms, room = set(), set(), 0, max_pods
+
+    undecoded_spec = lazy_mod.undecoded_spec
+    for i, pod in enumerate(pods):
+        key = pod_signature_key(pod)
+        gid = sig_ids.get(key)
+        spec_raw = undecoded_spec(pod)
+        # a lazy pod's raw spec answers "no volumes" without a call
+        refs = (_disk_refs(pod) if spec_raw is None or spec_raw.get("volumes")
+                else None)
+        if gid is not None and not refs and n < room:
+            groups.append(gid)  # the common case: nothing the budgets count
+            n += 1
+            continue
+        n_conflict = len(vols_conflict)
+        if refs:
+            # the pod's distinct disks, in reference order
+            pv = dict.fromkeys([(kind, disk_id) for kind, disk_id, _ in refs])
+            if len(pv) > vols_per_pod:
+                flush(i + 1)
+                out.append(("oracle", [(i, pod)]))
+                continue
+            pv_conflict = {d for d in pv if d in mounted or d in vols_once}
+            n_conflict += len(pv_conflict - vols_conflict)
+        t_new = count_affinity_terms(pod) if gid is None else 0
+        if n and (
+            n >= max_pods
+            or (gid is None and len(reps) >= max_groups)
+            or n_terms + t_new > max_terms
+            or n_conflict > max_vols
+        ):
+            flush(i)
+            gid = None
+            t_new = count_affinity_terms(pod)
+            if refs:
+                pv_conflict = {d for d in pv if d in mounted}
+        if gid is None:
+            gid = sig_ids[key] = len(reps)
+            reps.append(pod)
+        n_terms += t_new
+        if refs:
+            vols_conflict |= pv_conflict
+            vols_once.update(pv)
+            disk_rows.append(n)
+            disk_refs.append(refs)
+        groups.append(gid)
+        n += 1
+        room = (max_pods if n_terms <= max_terms
+                and len(vols_conflict) <= max_vols else 0)
+    flush(len(pods))
+    return out
 
 
 @dataclass
@@ -1151,7 +1274,11 @@ class Tensorizer:
         image_weight: int = 0,
         interpod_weight: int = 1,
         mounted_disks: Optional[set] = None,
+        columns: Optional[SegmentColumns] = None,
     ) -> Optional[BatchStatic]:
+        """``columns``: the wave plan's ``SegmentColumns`` of exactly
+        ``pods``; without it the pods are planned here, as one segment,
+        by the same ``plan_segments``."""
         node_names = sorted(n for n, i in node_info_map.items() if i.node is not None)
         n_real = len(node_names)
         if n_real == 0 or not pods:
@@ -1160,20 +1287,13 @@ class Tensorizer:
         infos = [node_info_map[n] for n in node_names]
 
         # signatures
-        sig_to_gid: dict[str, int] = {}
-        group_of_pod = np.empty(len(pods), dtype=np.int32)
-        reps: list[api.Pod] = []  # representative pod per group
-        for i, pod in enumerate(pods):
-            key = pod_signature_key(pod)
-            gid = sig_to_gid.get(key)
-            if gid is None:
-                gid = len(reps)
-                if gid >= self.max_groups:
-                    return None  # caller falls back to oracle for this segment
-                sig_to_gid[key] = gid
-                reps.append(pod)
-            group_of_pod[i] = gid
+        if columns is None:
+            ((_, columns),) = plan_segments(pods, (), *_ONE_SEGMENT)
+        group_of_pod = columns.group_of_pod
+        reps = columns.reps  # representative pod per group
         G = len(reps)
+        if G > self.max_groups:
+            return None  # caller falls back to oracle for this segment
 
         # cheap tensor-budget probes BEFORE the expensive [G, N] loops: the
         # backend's split fallback re-tensorizes each piece, so an
@@ -1185,7 +1305,7 @@ class Tensorizer:
         # the time a later segment references it again it is mounted and
         # re-enters the vocab there.  Everything else becomes a
         # "count-only" slot (MaxVolumeCount still sees it; see phase B).
-        n_terms = sum(count_affinity_terms(rep) for rep in reps)
+        n_terms = columns.n_terms
         if mounted_disks is None:
             mounted_disks = set()
             for info in infos:
@@ -1194,8 +1314,8 @@ class Tensorizer:
         seen_once: set[tuple[str, str]] = set()
         conflict_vols: set[tuple[str, str]] = set()
         w_used = 0  # max distinct disks any ONE pod carries (slot axis)
-        for pod in pods:
-            per_pod = pod_disk_vols(pod)
+        for refs in columns.disk_refs:
+            per_pod = {(kind, disk_id) for kind, disk_id, _ in refs}
             if len(per_pod) > self.vols_per_pod:
                 return None  # caller falls back to oracle for this pod
             if len(per_pod) > w_used:
@@ -1568,10 +1688,7 @@ class Tensorizer:
         pod_vol_ro_ok = np.zeros((P, W), dtype=bool)
         pod_vol_kind = np.zeros((P, W), dtype=np.int32)
         any_count_only = False
-        for i, pod in enumerate(pods):
-            vol_refs = _disk_refs(pod)  # raw-first: no [P]-wide spec decode
-            if not vol_refs:
-                continue
+        for i, vol_refs in zip(columns.disk_rows, columns.disk_refs):
             per_pod: dict[tuple[str, str], bool] = {}  # all-refs-read-only
             for kind, disk_id, read_only in vol_refs:
                 key = (kind, disk_id)
@@ -1687,7 +1804,7 @@ class Tensorizer:
             node_zone=node_zone,
             num_zones=num_zones,
             group_of_pod=group_of_pod,
-            pod_names=[p.meta.key for p in pods],
+            pod_names=columns.keys,
             static_ok=static_ok,
             node_aff_raw=node_aff_raw,
             taint_intol_raw=taint_intol_raw,

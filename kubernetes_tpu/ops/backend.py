@@ -46,13 +46,7 @@ from ..scheduler.priorities import (
     TaintTolerationPriority,
 )
 from ..scheduler.units import CPU_MILLI, MEM_MIB, ResourceVec
-from ..models.snapshot import (
-    HostBatchState,
-    Tensorizer,
-    count_affinity_terms,
-    pod_disk_vols,
-    pod_signature_key,
-)
+from ..models.snapshot import HostBatchState, Tensorizer, plan_segments
 from .batch_kernel import schedule_batch_arrays
 from .breaker import LEVELS, KernelCircuitBreaker
 
@@ -240,6 +234,9 @@ class TPUBatchBackend:
         self.last_frontier: list = []
         self.stats = {"kernel_pods": 0, "place_batched_pods": 0,
                       "oracle_pods": 0, "segments": 0,
+                      # kernel pods whose columns came from the wave's plan
+                      # (a half of the split path is planned on its own)
+                      "planned_pods": 0,
                       "pallas_segments": 0, "pallas_fallbacks": 0,
                       "interpret_fallbacks": 0, "oracle_segments": 0,
                       "breaker_transitions": 0,
@@ -516,58 +513,20 @@ class TPUBatchBackend:
             return None
 
     # -- greedy segmentation ------------------------------------------------
-    def _segments(
-        self, pods: list[api.Pod], mounted_disks: Optional[set] = None
-    ) -> list[tuple[str, list[tuple[int, api.Pod]]]]:
+    def _segments(self, pods: list[api.Pod],
+                  mounted_disks: Optional[set] = None) -> list[tuple]:
         """Split the (ordered) batch into kernel segments that respect the
-        tensor budgets, walking pod order once — every cut point preserves
-        sequential-greedy parity because each segment re-tensorizes against
-        the state left by its predecessors.  Pods no kernel can express
-        (> vols_per_pod distinct disks) become singleton oracle segments.
-
-        The volume budget counts CONFLICT-CAPABLE disks only (shared
-        within the segment or already mounted) — build_static gives
-        singleton unmounted disks no identity row, so they cost nothing."""
+        tensor budgets (``plan_segments``): every cut point preserves
+        sequential-greedy parity because each segment re-tensorizes
+        against the state left by its predecessors.  A kernel segment
+        comes as its ``SegmentColumns``, which ``build_static`` reads;
+        pods no kernel can express (> vols_per_pod distinct disks) become
+        singleton oracle segments."""
         tz = self.tensorizer
-        mounted = mounted_disks if mounted_disks is not None else set()
-        out: list[tuple[str, list[tuple[int, api.Pod]]]] = []
-        cur: list[tuple[int, api.Pod]] = []
-        sigs: set[str] = set()
-        vols_once: set = set()
-        vols_conflict: set = set()
-        n_terms = 0
-
-        def flush() -> None:
-            nonlocal cur, sigs, vols_once, vols_conflict, n_terms
-            if cur:
-                out.append(("kernel", cur))
-            cur, sigs, vols_once, vols_conflict, n_terms = [], set(), set(), set(), 0
-
-        for i, pod in enumerate(pods):
-            pv = pod_disk_vols(pod)
-            if len(pv) > tz.vols_per_pod:
-                flush()
-                out.append(("oracle", [(i, pod)]))
-                continue
-            pv_conflict = {d for d in pv if d in mounted or d in vols_once}
-            key = pod_signature_key(pod)
-            t_new = count_affinity_terms(pod) if key not in sigs else 0
-            if cur and (
-                len(cur) >= self.max_segment_pods
-                or (key not in sigs and len(sigs) >= tz.max_groups)
-                or n_terms + t_new > tz.max_terms
-                or len(vols_conflict | pv_conflict) > tz.max_vols
-            ):
-                flush()
-                t_new = count_affinity_terms(pod)
-                pv_conflict = {d for d in pv if d in mounted}
-            sigs.add(key)
-            n_terms += t_new
-            vols_conflict |= pv_conflict
-            vols_once |= pv
-            cur.append((i, pod))
-        flush()
-        return out
+        return plan_segments(
+            pods, mounted_disks if mounted_disks is not None else set(),
+            self.max_segment_pods, tz.max_groups, tz.max_terms, tz.max_vols,
+            tz.vols_per_pod)
 
     # -- config support check ---------------------------------------------
     def _kernel_weights(self) -> Optional[dict]:
@@ -810,12 +769,21 @@ class TPUBatchBackend:
                 return
             finish()
 
-        def dispatch_kernel_segment(segment: list[tuple[int, api.Pod]]):
+        def dispatch_kernel_segment(segment: list[tuple[int, api.Pod]],
+                                    columns=None):
             """Async half of run_kernel_segment: tensorize + dispatch and
             return a finisher closure that materializes, applies, and
             returns the segment's commit entries.  Returns None when the
-            segment needs the sync split path (budget reject)."""
-            seg_pods = [p for _, p in segment]
+            segment needs the sync split path (budget reject).
+            ``columns``: the wave plan's ``SegmentColumns`` of the segment;
+            a half of the split path has none, and build_static plans it."""
+            if columns is not None:
+                seg_pods = columns.pods
+                planned = len(seg_pods)
+            else:
+                seg_pods = [p for _, p in segment]
+                planned = 0
+            self.stats["planned_pods"] += planned
             tr = tracing.current()
             t_tensorize = self._clock_wall()
             with (tr.span("tensorize.build_static", cat="phase")
@@ -834,13 +802,15 @@ class TPUBatchBackend:
                     image_weight=weights["image"],
                     interpod_weight=weights["interpod"],
                     mounted_disks=mounted_disks,
+                    columns=columns,
                 )
             if static is None:
                 t_end = self._clock_wall()
                 self.stats["tensorize_s"] += t_end - t_tensorize
                 if tr is not None:
                     tr.complete("tensorize", t_tensorize, t_end, cat="phase",
-                                pods=len(seg_pods), rejected=True)
+                                pods=len(seg_pods), planned=planned,
+                                rejected=True)
                 return None
             with (tr.span("tensorize.initial_state", cat="phase")
                   if tr is not None else tracing.NULL_SPAN):
@@ -856,7 +826,8 @@ class TPUBatchBackend:
                 # tensorize_s IS this measurement (it adopts the two
                 # children recorded between them)
                 tr.complete("tensorize", t_tensorize, t_end, cat="phase",
-                            pods=len(seg_pods), groups=len(static.g_request),
+                            pods=len(seg_pods), planned=planned,
+                            groups=len(static.g_request),
                             n_pad=int(static.n_pad))
             from .pallas_kernel import shape_key
 
@@ -1104,14 +1075,18 @@ class TPUBatchBackend:
             with (tr.span("segment_plan", cat="phase", pods=len(pods))
                   if tr is not None else tracing.NULL_SPAN) as sp:
                 segments = self._segments(pods, mounted_disks=mounted_disks)
-                sp.set(segments=len(segments))
-            for si, (kind, segment) in enumerate(segments):
+                sp.set(segments=len(segments),
+                       disk_pods=sum(len(plan.disk_rows)
+                                     for kind, plan in segments
+                                     if kind == "kernel"))
+            for si, (kind, plan) in enumerate(segments):
                 if kind == "oracle":
-                    for i, pod in segment:
+                    for i, pod in plan:
                         run_oracle(pod, i)
-                    pending.extend((pod, assignments[i], None, None) for i, pod in segment)
+                    pending.extend((pod, assignments[i], None, None) for i, pod in plan)
                     continue
-                finish = dispatch_kernel_segment(segment)
+                segment = plan.segment
+                finish = dispatch_kernel_segment(segment, plan)
                 if finish is None:
                     # budget reject (rare): sync safety-net split path
                     flush_pending()

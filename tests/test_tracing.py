@@ -435,7 +435,11 @@ def test_the_wave_covers_the_loop_iteration_in_named_children():
             "pods": 12, "nodes": 4, "batched": 12}
         assert by["queue.drain"].attrs == {"pods": 12}
         assert by["snapshot"].attrs == {"nodes": 4}
-        assert by["segment_plan"].attrs == {"pods": 12, "segments": 1}
+        assert by["segment_plan"].attrs == {"pods": 12, "segments": 1,
+                                            "disk_pods": 0}
+        # the one segment's columns came from the wave's plan
+        assert by["tensorize"].attrs["pods"] == 12
+        assert by["tensorize"].attrs["planned"] == 12
         assert by["place"].attrs["pods"] == 12
         assert by["device_wait"].t1 == by["place"].t0
     by_first = {c.name: c for c in first.children}
