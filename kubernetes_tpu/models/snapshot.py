@@ -56,6 +56,7 @@ from ..scheduler.units import (
     pod_nonzero_request_vec,
     pod_request_vec,
 )
+from ..utils import tracing
 
 _MIN_IMG_MIB = 23
 _MAX_IMG_MIB = 1000
@@ -1760,18 +1761,23 @@ class Tensorizer:
                         static_ok[g, j] = False
 
         # spreading: selectors per signature; inc matrix between signatures
-        ssp = SelectorSpreadPriority()
-        g_selectors = [ssp._selectors_for_pod(rep, pctx) for rep in reps]
-        g_has_spread = np.array([len(s) > 0 for s in g_selectors], dtype=bool)
-        spread_inc = np.zeros((G, G), dtype=np.int32)
-        for g in range(G):
-            if not g_has_spread[g]:
-                continue
-            for h in range(G):
-                if reps[h].meta.namespace != reps[g].meta.namespace:
+        tr = tracing.current()
+        with (tr.span("tensorize.signature_rows", cat="phase", groups=G,
+                      services=len(pctx.services))
+              if tr is not None else tracing.NULL_SPAN) as sp:
+            ssp = SelectorSpreadPriority()
+            g_selectors = [ssp._selectors_for_pod(rep, pctx) for rep in reps]
+            g_has_spread = np.array([len(s) > 0 for s in g_selectors], dtype=bool)
+            spread_inc = np.zeros((G, G), dtype=np.int32)
+            for g in range(G):
+                if not g_has_spread[g]:
                     continue
-                if ssp._matches_any(g_selectors[g], reps[h]):
-                    spread_inc[g, h] = 1
+                for h in range(G):
+                    if reps[h].meta.namespace != reps[g].meta.namespace:
+                        continue
+                    if ssp._matches_any(g_selectors[g], reps[h]):
+                        spread_inc[g, h] = 1
+            sp.set(selectors=int(g_has_spread.sum()))
 
         # -- bucket-pad the signature axis ----------------------------------
         # Padded rows are never referenced (group_of_pod < G) but keep the
@@ -1870,13 +1876,6 @@ class Tensorizer:
         port_idx = {p: i for i, p in enumerate(static.port_vocab)}
         spread_counts = np.zeros((G, n_pad), dtype=np.int32)
 
-        ssp = SelectorSpreadPriority()
-        # representative pod per group for selector extraction
-        reps: dict[int, api.Pod] = {}
-        for i, gid in enumerate(static.group_of_pod):
-            reps.setdefault(int(gid), pods[i])
-        g_selectors = {g: ssp._selectors_for_pod(rep, pctx) for g, rep in reps.items()}
-
         for j, name in enumerate(static.node_names):
             info = node_info_map[name]
             requested[j] = info.requested.units
@@ -1887,92 +1886,107 @@ class Tensorizer:
                 if port in port_idx:
                     ports_used[j, port_idx[port]] = True
 
-        # existing matching-pod counts per spread group and per affinity
-        # term (zone sums are recomputed in-step from these, over the
-        # feasible mask).  This is (groups + terms) x existing-pods selector
-        # matching — tens of millions of probes on a loaded 150k-pod cluster
-        # — so it runs in the native engine (csrc/labelmatch.cpp); namespace
-        # scoping rides along as a reserved pseudo-label.
-        groups_with_sels = {g: sels for g, sels in g_selectors.items() if sels}
-        T = static.term_matches_sig.shape[0]
-        # per-term flat domain counts, expanded to [T, N] after the fill
-        # (trash id = node_domain.max() where the key is absent — its counts
-        # vanish in the expansion because dom_valid masks them)
-        n_dom = int(static.node_domain.max()) + 1 if static.terms else 1
-        dom_match = np.zeros(n_dom, dtype=np.int32)
-        total_match = np.zeros(T, dtype=np.int32)
-        matchable_terms = [
-            (t, at) for t, at in enumerate(static.terms) if at.term.selector is not None
-        ]
-        if groups_with_sels or matchable_terms:
-            # the engine + labelmap corpus: batch-persistent when a
-            # HostBatchState is supplied (selectors are per-segment either
-            # way); scratch-built and torn down otherwise
-            if host_state is not None:
-                eng = host_state.eng
-                add_selector = host_state.selector_id
-            else:
-                eng = MatchEngine()
-                add_selector = eng.add_selector
-            NS_KEY = _NS_KEY
-            sel_ids: dict[int, list[int]] = {}
-            for g, sels in groups_with_sels.items():
-                ns_req = (NS_KEY, "Eq", [reps[g].meta.namespace])
-                ids = []
-                for kind, sel in sels:
-                    if kind == "simple":
-                        reqs = [ns_req] + [(k, "Eq", [str(v)]) for k, v in sel.items()]
-                    else:
-                        reqs = (
-                            [ns_req]
-                            + [(k, "Eq", [str(v)]) for k, v in sel.match_labels.items()]
-                            + [(r.key, r.operator, list(r.values)) for r in sel.match_expressions]
-                        )
-                    ids.append(add_selector(reqs))
-                sel_ids[g] = ids
-            # one selector per affinity term: namespace-scope ∈ term
-            # namespaces (empty → owner's namespace) AND the term selector
-            term_sids: list[int] = []
-            for t, at in matchable_terms:
-                namespaces = at.term.namespaces or [reps[at.owner].meta.namespace]
-                sel = at.term.selector
-                reqs = (
-                    [(NS_KEY, "In", [str(n) for n in namespaces])]
-                    + [(k, "Eq", [str(v)]) for k, v in sel.match_labels.items()]
-                    + [(r.key, r.operator, list(r.values)) for r in sel.match_expressions]
-                )
-                term_sids.append(add_selector(reqs))
-            if host_state is not None:
-                pod_lids = host_state.pod_lids
-                node_j = host_state.node_j_array()
-            else:
-                pod_lids = []
-                pod_node_j: list[int] = []
-                for j, name in enumerate(static.node_names):
-                    for q in node_info_map[name].pods:
-                        pod_lids.append(
-                            eng.add_labelmap({**q.meta.labels, NS_KEY: q.meta.namespace})
-                        )
-                        pod_node_j.append(j)
-                node_j = np.asarray(pod_node_j, dtype=np.int64)
-            if pod_lids:
-                # content-interned lids repeat heavily (template-stamped
-                # pods share one labelmap), so match each DISTINCT lid
-                # once and broadcast: native probes go from O(L × sels)
-                # to O(distinct × sels) + numpy O(L)
-                lids_arr = np.asarray(pod_lids, dtype=np.int64)
-                uniq, inverse = np.unique(lids_arr, return_inverse=True)
-                for g, ids in sel_ids.items():
-                    hits = eng.match_any(ids, uniq)[inverse]
-                    np.add.at(spread_counts[g], node_j[hits], 1)
-                if matchable_terms:
-                    tm = eng.match_matrix(term_sids, uniq)  # [T_real, U]
-                    for row, (t, _at) in enumerate(matchable_terms):
-                        hits = tm[row][inverse]
-                        total_match[t] = int(hits.sum())
-                        np.add.at(dom_match, static.node_domain[t, node_j[hits]], 1)
-            if host_state is None:
-                eng.close()
+        # existing pods matched by each signature's spread selectors and
+        # each affinity term: the services walked per signature again
+        tr = tracing.current()
+        with (tr.span("tensorize.spread_counts", cat="phase")
+              if tr is not None else tracing.NULL_SPAN) as sp:
+            ssp = SelectorSpreadPriority()
+            # representative pod per group for selector extraction
+            reps: dict[int, api.Pod] = {}
+            for i, gid in enumerate(static.group_of_pod):
+                reps.setdefault(int(gid), pods[i])
+            g_selectors = {g: ssp._selectors_for_pod(rep, pctx) for g, rep in reps.items()}
+
+            # existing matching-pod counts per spread group and per affinity
+            # term (zone sums are recomputed in-step from these, over the
+            # feasible mask).  This is (groups + terms) x existing-pods selector
+            # matching — tens of millions of probes on a loaded 150k-pod cluster
+            # — so it runs in the native engine (csrc/labelmatch.cpp); namespace
+            # scoping rides along as a reserved pseudo-label.
+            groups_with_sels = {g: sels for g, sels in g_selectors.items() if sels}
+            T = static.term_matches_sig.shape[0]
+            # per-term flat domain counts, expanded to [T, N] after the fill
+            # (trash id = node_domain.max() where the key is absent — its counts
+            # vanish in the expansion because dom_valid masks them)
+            n_dom = int(static.node_domain.max()) + 1 if static.terms else 1
+            dom_match = np.zeros(n_dom, dtype=np.int32)
+            total_match = np.zeros(T, dtype=np.int32)
+            matchable_terms = [
+                (t, at) for t, at in enumerate(static.terms) if at.term.selector is not None
+            ]
+            n_labelmaps = 0  # distinct labelmaps of placed pods matched
+            if groups_with_sels or matchable_terms:
+                # the engine + labelmap corpus: batch-persistent when a
+                # HostBatchState is supplied (selectors are per-segment either
+                # way); scratch-built and torn down otherwise
+                if host_state is not None:
+                    eng = host_state.eng
+                    add_selector = host_state.selector_id
+                else:
+                    eng = MatchEngine()
+                    add_selector = eng.add_selector
+                NS_KEY = _NS_KEY
+                sel_ids: dict[int, list[int]] = {}
+                for g, sels in groups_with_sels.items():
+                    ns_req = (NS_KEY, "Eq", [reps[g].meta.namespace])
+                    ids = []
+                    for kind, sel in sels:
+                        if kind == "simple":
+                            reqs = [ns_req] + [(k, "Eq", [str(v)]) for k, v in sel.items()]
+                        else:
+                            reqs = (
+                                [ns_req]
+                                + [(k, "Eq", [str(v)]) for k, v in sel.match_labels.items()]
+                                + [(r.key, r.operator, list(r.values)) for r in sel.match_expressions]
+                            )
+                        ids.append(add_selector(reqs))
+                    sel_ids[g] = ids
+                # one selector per affinity term: namespace-scope ∈ term
+                # namespaces (empty → owner's namespace) AND the term selector
+                term_sids: list[int] = []
+                for t, at in matchable_terms:
+                    namespaces = at.term.namespaces or [reps[at.owner].meta.namespace]
+                    sel = at.term.selector
+                    reqs = (
+                        [(NS_KEY, "In", [str(n) for n in namespaces])]
+                        + [(k, "Eq", [str(v)]) for k, v in sel.match_labels.items()]
+                        + [(r.key, r.operator, list(r.values)) for r in sel.match_expressions]
+                    )
+                    term_sids.append(add_selector(reqs))
+                if host_state is not None:
+                    pod_lids = host_state.pod_lids
+                    node_j = host_state.node_j_array()
+                else:
+                    pod_lids = []
+                    pod_node_j: list[int] = []
+                    for j, name in enumerate(static.node_names):
+                        for q in node_info_map[name].pods:
+                            pod_lids.append(
+                                eng.add_labelmap({**q.meta.labels, NS_KEY: q.meta.namespace})
+                            )
+                            pod_node_j.append(j)
+                    node_j = np.asarray(pod_node_j, dtype=np.int64)
+                if pod_lids:
+                    # content-interned lids repeat heavily (template-stamped
+                    # pods share one labelmap), so match each DISTINCT lid
+                    # once and broadcast: native probes go from O(L × sels)
+                    # to O(distinct × sels) + numpy O(L)
+                    lids_arr = np.asarray(pod_lids, dtype=np.int64)
+                    uniq, inverse = np.unique(lids_arr, return_inverse=True)
+                    n_labelmaps = len(uniq)
+                    for g, ids in sel_ids.items():
+                        hits = eng.match_any(ids, uniq)[inverse]
+                        np.add.at(spread_counts[g], node_j[hits], 1)
+                    if matchable_terms:
+                        tm = eng.match_matrix(term_sids, uniq)  # [T_real, U]
+                        for row, (t, _at) in enumerate(matchable_terms):
+                            hits = tm[row][inverse]
+                            total_match[t] = int(hits.sum())
+                            np.add.at(dom_match, static.node_domain[t, node_j[hits]], 1)
+                if host_state is None:
+                    eng.close()
+            sp.set(groups=len(reps), labelmaps=n_labelmaps)
         dm = (dom_match[static.node_domain] * static.dom_valid).astype(np.int32)
 
         # volume occupancy from existing pods: instance presence and

@@ -475,6 +475,53 @@ def test_the_wave_covers_the_loop_iteration_in_named_children():
 
 
 @pytest.mark.timeout(120)
+def test_the_signature_rows_and_spread_counts_are_named_under_tensorize():
+    """``tensorize.signature_rows`` lies in ``tensorize.build_static`` and
+    ``tensorize.spread_counts`` in ``tensorize.initial_state``, each with
+    the segment's real signatures; the services the selectors read are
+    the priority context's; untraced, no span is kept."""
+    from kubernetes_tpu.api import ObjectMeta, Service
+
+    cs, sched, _ = _mini_world()
+    # one service selects a signature of the wave; one selects in another
+    # namespace, one selects nothing that exists
+    for name, ns, app in (("web", "default", "web"), ("api", "other", "api"),
+                          ("idle", "default", "nothing")):
+        cs.services.create(Service(meta=ObjectMeta(name=name, namespace=ns),
+                                   selector={"app": app}))
+    tr = tracing.enable()
+
+    def wave(tag):
+        cs.pods.create_many([make_pod(f"{tag}{i}", cpu="100m",
+                                      labels={"app": ("web", "api", "batch")[i % 3]})
+                             for i in range(12)])
+        sched.pump()
+        assert sched.schedule_pending_batch() == (12, 0)
+
+    for waves, tag in enumerate("ab", start=1):
+        wave(tag)
+        root = tr.ring[-1]
+        tensorize = next(c for c in root.children if c.name == "tensorize")
+        static, init = tensorize.children
+        (rows,) = static.children
+        (counts,) = init.children
+        assert (rows.name, counts.name) == ("tensorize.signature_rows",
+                                            "tensorize.spread_counts")
+        for parent, child in ((static, rows), (init, counts)):
+            assert parent.t0 <= child.t0 <= child.t1 <= parent.t1
+            assert child.cat == "phase"
+        # 3 real signatures on a padded axis of 32; 3 services, 1 selecting
+        assert tensorize.attrs["groups"] == 32
+        assert rows.attrs == {"groups": 3, "services": 3, "selectors": 1}
+        # the placed pods' distinct labelmaps: none before the first wave
+        assert counts.attrs == {"groups": 3, "labelmaps": 3 * (waves - 1)}
+
+    tracing.disable()
+    wave("c")
+    assert len(tr.ring) == 2
+
+
+@pytest.mark.timeout(120)
 def test_remote_request_span_carries_the_servers_own_time():
     """Every JSON response of the apiserver carries ``Server-Timing``,
     tracing on or off; with tracing on a ``bind_many`` leaves ONE
